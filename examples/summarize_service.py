@@ -23,10 +23,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt import CheckpointStore
+from repro.compat import use_compile_cache
 from repro.core import (SessionSpec, make, rbf_lengthscale_batch,
                         rbf_lengthscale_stream)
 from repro.data import MixtureSpec, session_stream
 from repro.serve import SummarizerPod
+
+use_compile_cache()
 
 S, K_MAX, D, CHUNK = 8, 16, 32, 64
 ROUNDS = 30

@@ -1,29 +1,28 @@
-"""Version-compat shims for the JAX APIs that moved between releases,
-plus tiny cross-layer jit utilities.
-
-``shard_map`` graduated from ``jax.experimental.shard_map`` (keyword
-``check_rep``) to ``jax.shard_map`` (keyword ``check_vma``).  Call sites in
-this repo use the new-style keyword; the shim translates for older JAX.
-"""
+"""Tiny cross-layer jit utilities."""
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
 
-try:  # jax >= 0.6: top-level export, `check_vma` keyword
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental module, `check_rep` keyword
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` with the replication-check keyword normalized."""
-    kw = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and
+    wins untouched.  Otherwise the cache lives in ``.jax_cache/`` at the
+    root of the checkout: a fixed path, because the path is part of what
+    a later run must find again.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
 
 
 def hashable_lru(maxsize: int = 64):
@@ -49,4 +48,4 @@ def hashable_lru(maxsize: int = 64):
     return deco
 
 
-__all__ = ["shard_map", "hashable_lru"]
+__all__ = ["CACHE_DIR", "hashable_lru", "use_compile_cache"]

@@ -50,7 +50,7 @@ Array = jax.Array
 # Traced-kernel math lives in the cycle-free ``repro.kernelmath`` (shared
 # with the Pallas kernel bodies); re-exported here as the core API.
 from repro.kernelmath import (  # noqa: E402  (re-export)
-    KERNEL_KIND_IDS, KernelParams, pairwise_traced, traced_gain_rows)
+    KERNEL_KIND_IDS, KernelParams, matmul, pairwise_traced, traced_gain_rows)
 
 __all__ = [
     "KERNEL_KIND_IDS", "KernelConfig", "KernelParams", "LogDet",
@@ -101,6 +101,11 @@ def rbf_lengthscale_stream(d: int) -> float:
 # ---------------------------------------------------------------------------
 # Incremental log-det state
 # ---------------------------------------------------------------------------
+
+
+def _set_row(a: Array, at: Array, row: Array) -> Array:
+    """``a`` with row ``row`` where the (K,) mask ``at`` is set."""
+    return jnp.where(at[:, None], row[None, :], a)
 
 
 @jax.tree_util.register_dataclass
@@ -217,20 +222,22 @@ class LogDet:
         dd = jnp.sqrt(dd2)
         gain = 0.5 * jnp.log(dd2)
 
-        n = state.n
+        # Row n is written by a select on the row index, not a scatter:
+        # under the pod's vmap over sessions and the sieves' vmap over
+        # instances, XLA:TPU (jaxlib 0.9.0) dropped the scatters, and
+        # summaries kept zero rows on the chip.  The values are the same.
+        at = jnp.arange(state.K) == state.n  # (K,) one-hot of row n
         # L row n := [c, dd] ; padded diag was 1 -> overwrite.
-        Lrow = c.at[n].set(dd)
-        L = state.L.at[n].set(Lrow)
+        L = _set_row(state.L, at, jnp.where(at, dd, c))
         # Linv row n := [-(c @ Linv)/dd, 1/dd]
-        r = -(c @ state.Linv) / dd
-        Linv_row = r.at[n].set(1.0 / dd)
-        Linv = state.Linv.at[n].set(Linv_row)
-        feats = state.feats.at[n].set(x)
+        r = -matmul(c, state.Linv) / dd
+        Linv = _set_row(state.Linv, at, jnp.where(at, 1.0 / dd, r))
+        feats = _set_row(state.feats, at, x)
         return LogDetState(
             feats=feats,
             L=L,
             Linv=Linv,
-            n=n + 1,
+            n=state.n + 1,
             fval=state.fval + gain,
             n_queries=state.n_queries,
         )

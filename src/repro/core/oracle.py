@@ -23,14 +23,12 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
 
 from repro.constants import GAIN_EPS
 from repro.kernels.rbf_gain import DEFAULT_BLOCK_B, fused_gains
-from repro.obs import record_backend_fallback
 
 from .functions import KernelConfig, KernelParams, traced_gain_rows
 
@@ -39,25 +37,6 @@ Array = jax.Array
 BACKENDS = ("auto", "jnp", "pallas", "pallas-interpret")
 
 _ENV_VAR = "REPRO_ORACLE_BACKEND"
-
-_warned_no_tpu = False
-
-
-def _warn_once_no_tpu(what: str) -> None:
-    """One process-wide warning when an explicit ``pallas`` request falls
-    back to ``jnp`` off-TPU — a silent fallback turns a missing/misdetected
-    TPU into an undiagnosable perf regression."""
-    global _warned_no_tpu
-    if _warned_no_tpu:
-        return
-    _warned_no_tpu = True
-    warnings.warn(
-        f"{what}: backend 'pallas' requested but jax.default_backend() is "
-        f"{jax.default_backend()!r}, not 'tpu' — falling back to the 'jnp' "
-        "path. The compiled Pallas kernel needs real TPU hardware; use "
-        "'pallas-interpret' to exercise the kernel logic anywhere.",
-        RuntimeWarning, stacklevel=3)
-
 
 def default_backend() -> str:
     """Process-wide default: ``REPRO_ORACLE_BACKEND`` env var, else auto."""
@@ -72,23 +51,21 @@ def resolve_backend(backend: str) -> str:
     """Map a requested backend to the one that will actually run.
 
     ``auto`` picks the fused Pallas kernel on TPU and the jnp path
-    elsewhere; an explicit ``pallas`` request also falls back to ``jnp``
-    off-TPU (the compiled kernel needs real hardware — use
-    ``pallas-interpret`` to exercise the kernel logic anywhere), but that
-    fallback emits one ``RuntimeWarning`` per process: a pallas request
-    quietly running jnp is a perf regression waiting to be mis-blamed.
+    elsewhere.  An explicit ``pallas`` request off the TPU raises: the
+    compiled kernel needs the chip, and quietly running jnp instead would
+    hide a missing device (``pallas-interpret`` runs the kernel logic
+    anywhere).
     """
     if backend not in BACKENDS:
         raise ValueError(f"backend {backend!r} invalid; choose from {BACKENDS}")
-    on_tpu = jax.default_backend() == "tpu"
+    platform = jax.default_backend()
     if backend == "auto":
-        return "pallas" if on_tpu else "jnp"
-    if backend == "pallas" and not on_tpu:
-        # warn once, count always: the fallback counter is the durable
-        # record of which oracle path a run actually used
-        record_backend_fallback("oracle", backend, "jnp")
-        _warn_once_no_tpu("repro.core.oracle.resolve_backend")
-        return "jnp"
+        return "pallas" if platform == "tpu" else "jnp"
+    if backend == "pallas" and platform != "tpu":
+        raise RuntimeError(
+            "repro.core.oracle: backend 'pallas' needs a TPU, but "
+            f"jax.default_backend() is {platform!r}; use 'auto', 'jnp' or "
+            "'pallas-interpret'.")
     return backend
 
 

@@ -46,6 +46,39 @@ class KernelParams:
         )
 
 
+def matmul(x: Array, y: Array) -> Array:
+    """``x @ y`` at full precision, accumulated in float32, returned in
+    ``x``'s dtype.
+
+    The TPU compiler takes only 32-bit matmul accumulators, so a bf16
+    objective's matmuls accumulate in f32 and round once.  ``HIGHEST``
+    keeps f32 operands f32 on the TPU, where XLA's default multiplies
+    them in one bf16 pass (on a v5e at d=256, ``x @ y.T`` came out 0.15
+    off where ``|x.y|`` reaches 71), an error the expanded-square
+    distance ``|x|^2 + |y|^2 - 2 x.y`` carries straight into the kernel
+    values.  bf16 operands are exact in that one pass (and
+    the kernel compiler refuses ``HIGHEST`` for them); the CPU ignores
+    the setting.  Every matmul of the shared gain/append math goes
+    through here, so the kernels and the jnp path keep one op sequence.
+    """
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else None)
+    return jnp.matmul(x, y, precision=precision,
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def in_f32(fn, *xs):
+    """``fn(*xs)`` evaluated in float32 and returned in the operands' dtype.
+
+    For the transcendental and divide ops: the TPU's unit for them has no
+    bf16, and XLA evaluates a bf16 ``exp``/``log``/``sqrt``/``/`` this
+    way anyway, so the result is the same; for f32 the casts vanish.
+    """
+    dt = jnp.result_type(*xs)
+    return fn(*(x.astype(jnp.float32) if hasattr(x, "astype") else x
+                for x in xs)).astype(dt)
+
+
 def pairwise_traced(x: Array, y: Array, kern: KernelParams) -> Array:
     """k(x_i, y_j) for x (N, d), y (M, d) -> (N, M), kernel from arrays.
 
@@ -55,14 +88,17 @@ def pairwise_traced(x: Array, y: Array, kern: KernelParams) -> Array:
     host-rounded constant), the normalized-linear kernel normalizes the
     Gram entries *after* the matmul — both read the one matmul.
     """
-    g = x @ y.T  # (N, M)
+    g = matmul(x, y.T)  # (N, M)
     xn2 = jnp.sum(x * x, axis=-1, keepdims=True)  # (N, 1)
     yn2 = jnp.sum(y * y, axis=-1, keepdims=True).T  # (1, M)
     d2 = jnp.maximum(xn2 + yn2 - 2.0 * g, 0.0)
-    rbf = jnp.exp(-kern.inv2l2.astype(x.dtype) * d2)
-    nx = jnp.maximum(jnp.sqrt(xn2), NORM_EPS)
-    ny = jnp.maximum(jnp.sqrt(yn2), NORM_EPS)
-    lin = 0.5 * (g / (nx * ny) + 1.0)
+    # the () constant is widened before the cast: a Pallas TPU kernel
+    # has no bf16 scalar arithmetic
+    inv2l2 = jnp.full((1, 1), kern.inv2l2, jnp.float32).astype(x.dtype)
+    rbf = in_f32(jnp.exp, -inv2l2 * d2)
+    nx = jnp.maximum(in_f32(jnp.sqrt, xn2), NORM_EPS)
+    ny = jnp.maximum(in_f32(jnp.sqrt, yn2), NORM_EPS)
+    lin = 0.5 * (in_f32(jnp.divide, g, nx * ny) + 1.0)
     return jnp.where(kern.kind_id == 0, rbf, lin)
 
 
@@ -81,6 +117,6 @@ def traced_gain_rows(x: Array, feats: Array, linv: Array, mask: Array, *,
     f32 bit-equality pin between them rests on this single definition.
     """
     km = a * pairwise_traced(x, feats, kern) * mask  # (B, K)
-    c = km @ linv.T  # (B, K)
+    c = matmul(km, linv.T)  # (B, K)
     cn2 = jnp.sum(c * c, axis=-1, keepdims=True)  # (B, 1)
-    return 0.5 * jnp.log(jnp.maximum((1.0 + a) - cn2, GAIN_EPS))
+    return 0.5 * in_f32(jnp.log, jnp.maximum((1.0 + a) - cn2, GAIN_EPS))

@@ -10,19 +10,21 @@ or an explicit argument):
     pallas            the fused kernel: ONE grid launch per chunk, grid
                       (S,), whole sessions resident in VMEM.  TPU only.
     pallas-interpret  the same kernel under the Pallas interpreter —
-                      slow, portable, bit-pinned against jnp in CI.
+                      slow, portable, bit-pinned in CI against
+                      run_batched run one session at a time.
     auto              pallas on TPU when the algorithm is fusable,
                       else jnp.
 
 Only ``ThreeSieves`` is fusable today (the stacked sieves carry a
 rung-instance axis the (S,)-grid kernel does not model); non-fusable
 algorithms fall back to jnp — with one ``RuntimeWarning`` per process
-if the fused path was requested explicitly.
+if the fused path was requested explicitly.  An explicit ``pallas``
+request off the TPU raises.
 
 Bit-safety contract: the interpret path runs UNPADDED — hardware padding
 (lanes to 128, sublanes to 8) is applied only when the compiled TPU
 kernel will consume it, so CI's bit-equality pin covers the exact op
-sequence the jnp path runs.
+sequence ``run_batched`` runs.
 """
 from __future__ import annotations
 
@@ -73,18 +75,19 @@ def fusable(algo) -> bool:
 def resolve(backend: str | None, algo) -> str:
     """Map a requested backend to the one that will actually run.
 
-    Same fallback discipline as ``oracle.resolve_backend``: explicit
-    fused requests that cannot be honored (off-TPU ``pallas``, or an
-    algorithm without a fused kernel) degrade to ``jnp`` with one
-    ``RuntimeWarning`` per process per cause — never silently.
+    An explicit fused request for an algorithm without a fused kernel
+    degrades to ``jnp`` with one ``RuntimeWarning`` per process, counted
+    in ``backend_fallback_total`` — an algorithm choice, not a device
+    one.  An explicit ``pallas`` request off the TPU raises, like
+    ``oracle.resolve_backend``.
     """
     backend = default_backend() if backend is None else backend
     if backend not in BACKENDS:
         raise ValueError(
             f"backend {backend!r} invalid; choose from {BACKENDS}")
-    on_tpu = jax.default_backend() == "tpu"
+    platform = jax.default_backend()
     if backend == "auto":
-        return "pallas" if (on_tpu and fusable(algo)) else "jnp"
+        return "pallas" if (platform == "tpu" and fusable(algo)) else "jnp"
     if backend in ("pallas", "pallas-interpret") and not fusable(algo):
         # warn once per process, but COUNT every degrade: the CI metrics
         # artifact shows which path actually ran, run after run
@@ -96,16 +99,11 @@ def resolve(backend: str | None, algo) -> str:
             "ThreeSieves does) — falling back to the 'jnp' "
             "vmap(run_batched) path.")
         return "jnp"
-    if backend == "pallas" and not on_tpu:
-        record_backend_fallback("pod_step", backend, "jnp")
-        _warn_once(
-            "no-tpu",
-            "repro.kernels.pod_step: backend 'pallas' requested but "
-            f"jax.default_backend() is {jax.default_backend()!r}, not "
-            "'tpu' — falling back to the 'jnp' path. The compiled kernel "
-            "needs real TPU hardware; use 'pallas-interpret' to exercise "
-            "the kernel logic anywhere.")
-        return "jnp"
+    if backend == "pallas" and platform != "tpu":
+        raise RuntimeError(
+            "repro.kernels.pod_step: backend 'pallas' needs a TPU, but "
+            f"jax.default_backend() is {platform!r}; use 'auto', 'jnp' or "
+            "'pallas-interpret'.")
     return backend
 
 
@@ -140,7 +138,7 @@ def _pod_step_fused(algo, state: TSState, chunks: Array, counts: Array, *,
     feats, L, Linv = ld.feats, ld.L, ld.Linv
     if use_pallas:
         # hardware alignment only on the compiled path — the interpret
-        # path stays unpadded so the CI bit-pin covers the jnp op sequence
+        # path stays unpadded so the CI bit-pin covers run_batched's ops
         chunks = _pad_axis(_pad_axis(chunks, 128, 2), 8, 1)
         feats = _pad_axis(_pad_axis(feats, 128, 2), 128, 1)
         L = _pad_axis(_pad_axis(L, 128, 2), 128, 1)
@@ -171,8 +169,8 @@ def pod_step(algo, state, chunks: Array, counts: Array, *,
     algo: the pod's (static) sieve algorithm; state: stacked per-slot
     algorithm state; chunks (S, C, d); counts (S,) valid prefixes;
     backend: one of ``BACKENDS`` or None for the process default.
-    Returns the stepped stacked state — identical pytree structure, and
-    (for f32) bit-identical leaves across backends.
+    Returns the stepped stacked state — identical pytree structure and,
+    for f32, the same accept decisions across backends.
     """
     resolved = resolve(backend, algo)
     # C = 1 chunks hit XLA's GEMV path, whose reduction order differs from

@@ -240,7 +240,7 @@ def _measure(arch, shape, mesh, n_chips, *, n_layers=None,
     t0 = time.time()
     fn, args, meta = build_cell(arch, shape, mesh, n_layers=n_layers,
                                 cost_faithful=cost_faithful, **kw)
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -333,7 +333,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: Path,
                                     remat=remat, microbatches=microbatches,
                                     seq_shard=seq_shard,
                                     remat_policy=remat_policy)
-        with mesh:
+        with jax.sharding.set_mesh(mesh):
             lowered = fn.lower(*args)
             t_lower = time.time() - t0
             compiled = lowered.compile()
@@ -440,7 +440,7 @@ def run_summarizer_pod_cell(multi_pod: bool, out_dir: Path, *,
                 "dropped_overflow": data_sh}
 
     try:
-        with mesh:
+        with jax.sharding.set_mesh(mesh):
             upd = jax.jit(pod.make_sharded_update(mesh, axis=axes),
                           in_shardings=(st_sh, data_sh, data_sh),
                           out_shardings=(st_sh, stats_sh))
@@ -591,7 +591,7 @@ def run_handoff_cell(multi_pod: bool, out_dir: Path, *,
         for leaf in jax.tree_util.tree_leaves(state))
 
     try:
-        with mesh:
+        with jax.sharding.set_mesh(mesh):
             ev = jax.jit(pod_global.evict_sids,
                          in_shardings=(st_sh, None), out_shardings=st_sh)
             vict_abs = jax.ShapeDtypeStruct((victims,), jnp.int32)

@@ -15,15 +15,23 @@ Mesh axes:
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules here
+    constrain with ``with_sharding_constraint`` and let GSPMD propagate,
+    which ``make_mesh``'s default Explicit axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
     """Degenerate 1x1 mesh over the real local device (smoke tests, examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))

@@ -53,7 +53,7 @@ def main(argv=None):
     param_sh = shd.shardings(model.spec(), rules, mesh)
 
     key = jax.random.PRNGKey(0)
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         params = jax.jit(model.init, out_shardings=param_sh)(key)
         opt_cfg = AdamWConfig(lr=args.lr, total_steps=args.steps)
         opt_state = init_opt_state(params, opt_cfg)

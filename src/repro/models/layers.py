@@ -94,9 +94,8 @@ def stack_spec(spec: Dict[str, Any], n: int) -> Dict[str, Any]:
 
 
 def _ambient_mesh():
-    from jax._src import mesh as mesh_lib
-
-    m = mesh_lib.thread_resources.env.physical_mesh
+    """The mesh set by ``jax.sharding.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 

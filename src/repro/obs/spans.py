@@ -40,15 +40,11 @@ import time
 from pathlib import Path
 from typing import Dict, List, Optional
 
+from jax.core import trace_ctx as _trace_ctx  # the "inside a trace?" probe
+
 from repro.concurrency import make_lock
 
 from .registry import get_registry
-
-try:  # the runtime "am I inside a trace?" probe; absent on exotic jax
-    from jax.core import trace_state_clean as _trace_state_clean
-except Exception:  # pragma: no cover - depends on jax version
-    def _trace_state_clean() -> bool:
-        return True
 
 MAX_BUFFERED_EVENTS = 10_000  # ring bound: telemetry must not be a leak
 
@@ -133,7 +129,7 @@ class SpanRecorder:
     # ----------------------------------------------------------------- span
     @contextlib.contextmanager
     def span(self, name: str, **attrs: object):
-        if not _trace_state_clean():  # inside a jit/vmap trace: no-op
+        if not _trace_ctx.is_top_level():  # inside a jit/vmap trace: no-op
             yield Span(name, -1, None, -1, dict(attrs))
             return
         stack = self._stack()

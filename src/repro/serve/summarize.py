@@ -484,7 +484,7 @@ class SummarizerPod:
         """
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
 
         spec = P(axis)
         stats_spec = {"counts": spec, "dropped_unknown": spec,

@@ -33,7 +33,7 @@ def test_real_lowered_psum():
     mesh = jax.make_mesh((1,), ("x",))
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def f(a):
         return jax.lax.psum(a, "x")

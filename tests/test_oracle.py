@@ -76,8 +76,12 @@ def test_resolution_rules():
     assert resolve_backend("jnp") == "jnp"
     assert resolve_backend("pallas-interpret") == "pallas-interpret"
     assert resolve_backend("auto") == ("pallas" if on_tpu else "jnp")
-    # explicit pallas request falls back to jnp off-TPU
-    assert resolve_backend("pallas") == ("pallas" if on_tpu else "jnp")
+    # an explicit pallas request off the TPU raises, naming the platform
+    if on_tpu:
+        assert resolve_backend("pallas") == "pallas"
+    else:
+        with pytest.raises(RuntimeError, match=jax.default_backend()):
+            resolve_backend("pallas")
     with pytest.raises(ValueError):
         resolve_backend("cuda")
 

@@ -20,7 +20,7 @@ def test_shard_act_noop_without_mesh():
 
 def test_shard_act_applies_in_mesh():
     mesh = make_host_mesh()
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         y = jax.jit(lambda x: shard_act(x * 1.0, "batch", "tp"))(
             jnp.ones((4, 8)))
     assert y.sharding.is_fully_replicated or True  # 1x1 mesh: trivial
@@ -37,7 +37,7 @@ def test_seq_shard_attention_is_numerically_identical():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab,
                               jnp.int32)
     mesh = make_host_mesh()
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         l0, _ = jax.jit(m0.train_logits)(params, {"tokens": toks})
         l1, _ = jax.jit(m1.train_logits)(params, {"tokens": toks})
     np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
@@ -94,7 +94,7 @@ def test_moe_impl_equivalence_under_host_mesh():
     p = init_tree(moe_spec(cfg), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
     mesh = make_host_mesh()
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         y_dense, _ = jax.jit(
             lambda p, x: apply_moe(p, x, cfg))(p, x)
         cfg_d = dataclasses.replace(
