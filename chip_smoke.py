@@ -240,7 +240,7 @@ def served_phase(name, algo_name, size, S, n_batches, drift_every, n_sample,
     pipe = IngestPipeline(pod=pod, buffer=buf, batch=B, min_fill=B,
                           pod_id=name)
     pipe.feed_from(src)
-    checks0 = len(obs.get_recorder().find("drift_reset"))
+    checks0 = len(obs.get_recorder().find("drift_check"))
     # the drift check runs on the chip; its window minimum is above what
     # any session receives, so no session resets and the per-session
     # reference below replays each session's stream exactly
@@ -248,7 +248,7 @@ def served_phase(name, algo_name, size, S, n_batches, drift_every, n_sample,
                              drift_every=drift_every,
                              min_items=S * size.C * n_batches,
                              min_rate=0.5)
-    checks = len(obs.get_recorder().find("drift_reset")) - checks0
+    checks = len(obs.get_recorder().find("drift_check")) - checks0
     ro = pod.readout(state)
     pod.drain_metrics(state, pod=name)
     ro = jax.tree_util.tree_map(np.asarray, ro)

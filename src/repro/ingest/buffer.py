@@ -48,6 +48,16 @@ another pod's buffer, FIFO intact).  The drop-oldest policy spares
 quiesced queues while any other queue can pay instead: clipping a
 session mid-migration would silently violate the handoff's
 zero-drop contract.
+
+Waits are timed where they happen: a contended lock
+(``buffer_put_wait_lock`` / ``buffer_get_wait_lock``), a ``put`` on a
+full ``block`` buffer (``buffer_put_wait_room``) and a ``get`` below
+``min_items`` (``buffer_get_wait_items``) are ``repro.obs`` stages,
+opened only when the code really waits, and their seconds add up per
+side in ``wait_seconds()`` — the ``buffer_wait_seconds_total`` drain
+source, which says whether the pod or its producers set the pace.
+Nothing is emitted to the span recorder or the registry while the lock
+is held.
 """
 from __future__ import annotations
 
@@ -58,6 +68,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.concurrency import make_lock
 
 from .shedding import RateLimit, ShedPolicy, TokenBucket
@@ -106,6 +117,8 @@ class TaggedBuffer:
         self._rung_changes = 0
         self._buckets: Dict[int, TokenBucket] = {}
         self._rate_overrides: Dict[int, RateLimit] = {}
+        self._wait_s = {"put": 0.0, "get": 0.0}  # side -> seconds waited
+        self._admitted = 0  # items put admitted, lifetime
 
     # ------------------------------------------------------------- properties
     @property
@@ -177,6 +190,32 @@ class TaggedBuffer:
         with self._lock:
             self._rate_overrides[int(sid)] = limit
             self._buckets.pop(int(sid), None)  # re-built at next put
+
+    def wait_seconds(self) -> Dict[str, float]:
+        """Lifetime seconds spent waiting in ``put`` (for the lock or for
+        room) and in ``get`` (for the lock or for ``min_items``)."""
+        with self._lock:
+            return dict(self._wait_s)
+
+    def admitted(self) -> int:
+        """Lifetime items ``put`` admitted (``inject`` not included)."""
+        with self._lock:
+            return self._admitted
+
+    def _lock_wait(self, side: str) -> Optional[obs.Stage]:
+        """An open ``buffer_<side>_wait_lock`` stage when another thread
+        holds the lock, else None; ``_lock_waited`` closes it once the
+        lock is held.  A probe of ``locked()``, not a trylock, so the
+        ``with self._lock`` that follows stays a blocking acquire that
+        lockdep checks every time."""
+        if not self._lock.locked():
+            return None
+        return obs.stage(f"buffer_{side}_wait_lock").__enter__()
+
+    def _lock_waited(self, side: str, st: Optional[obs.Stage]) -> None:
+        if st is not None:
+            st.__exit__(None, None, None)
+            self._wait_s[side] += st.seconds
 
     def depths(self) -> Dict[int, int]:
         """Per-session queue depth — the autoscaler's load signal (and
@@ -286,7 +325,9 @@ class TaggedBuffer:
         dropped = 0
         now = self._clock() if self.rate_limit or self._rate_overrides \
             else 0.0
+        waiting = self._lock_wait("put")
         with self._lock:
+            self._lock_waited("put", waiting)
             for sid, row in zip(sids.tolist(), X):
                 if self._closed:
                     raise ValueError("put() on a closed TaggedBuffer")
@@ -299,9 +340,12 @@ class TaggedBuffer:
                     continue
                 if self._size >= self.capacity:
                     if self.policy == "block":
-                        if not self._not_full.wait_for(
+                        with obs.stage("buffer_put_wait_room") as st:
+                            room = self._not_full.wait_for(
                                 lambda: self._size < self.capacity
-                                or self._closed, timeout):
+                                or self._closed, timeout)
+                        self._wait_s["put"] += st.seconds
+                        if not room:
                             raise TimeoutError(
                                 f"TaggedBuffer full ({self.capacity}) for "
                                 f"{timeout}s")
@@ -326,6 +370,7 @@ class TaggedBuffer:
                         dropped += 1
                 self._q.setdefault(sid, collections.deque()).append(row)
                 self._size += 1
+                self._admitted += 1
                 self._not_empty.notify_all()  # waiters may need min_items
         return dropped
 
@@ -356,14 +401,21 @@ class TaggedBuffer:
         itself is empty).
         """
         need = max(1, min(min_items, max_items))
+        waiting = self._lock_wait("get")
         with self._lock:
+            self._lock_waited("get", waiting)
             # quiesced backlogs are invisible here: they neither satisfy
             # the fill threshold nor drain (they belong to a migrating
             # session and leave via extract/release)
-            if not self._not_empty.wait_for(
-                    lambda: self._avail() >= need or self._closed, timeout):
-                raise TimeoutError(
-                    f"TaggedBuffer below {need} items for {timeout}s")
+            if not (self._avail() >= need or self._closed):
+                with obs.stage("buffer_get_wait_items") as st:
+                    filled = self._not_empty.wait_for(
+                        lambda: self._avail() >= need or self._closed,
+                        timeout)
+                self._wait_s["get"] += st.seconds
+                if not filled:
+                    raise TimeoutError(
+                        f"TaggedBuffer below {need} items for {timeout}s")
             if self._avail() == 0:  # closed and drained (of drainables)
                 return None
             out_s, out_x = [], []

@@ -129,6 +129,7 @@ class IngestPipeline:
         self._advance = None
         self._feeders = []
         self._feed_exc: Optional[BaseException] = None
+        self._put_seen = (0, 0.0)  # buffer (admitted, put wait) at last run
         self.exhausted = False
 
     # ------------------------------------------------------------------ feed
@@ -214,50 +215,79 @@ class IngestPipeline:
         loud here, not just in the device-side ledgers.  A producer
         failure recorded by a ``feed_from`` thread re-raises from here:
         a broken wire must never look like a clean end-of-stream.
+
+        Each call leaves one ``ingest_run`` span (batches, items,
+        padded, bytes sent to the device and, in buffer mode, the items
+        producers put and the seconds they waited in ``put`` since the
+        last run: ``buffer_put_items``, ``buffer_put_wait_s``) split into
+        the stages ``ingest_slot_table``, ``ingest_get`` (with the
+        buffer's ``buffer_get_wait_*`` inside it), ``ingest_route``,
+        ``ingest_device_put``, ``ingest_dispatch`` and ``ingest_sync``,
+        each an ``<stage>_s`` attribute and a profiler TraceMe.
         """
         advance = self._advance_fn()
-        sid_table = np.asarray(state.sid)
-        active = np.asarray(state.active)
         C = self.pod.chunk
         if self._gen is None:
             self._gen = self._fixed_batches()
-        batches = items = padded = 0
+        batches = items = padded = sent = 0
         drop_unknown = drop_overflow = 0
         t0 = time.perf_counter()
-        while max_batches is None or batches < max_batches:
-            try:
-                sids, X = next(self._gen)
-            except StopIteration:
-                self.exhausted = True
-                if self.buffer is not None:
-                    # buffer mode: a later run() must re-check the buffer
-                    # — a pod handoff may inject relocated backlog AFTER
-                    # the stream closed, and it must still drain (source
-                    # mode keeps the spent generator: re-creating it
-                    # would replay the source from the start)
-                    self._gen = None
-                break
-            chunks, counts, unknown, overflow = host_route(
-                sid_table, active, sids, X, C)
-            state, _ = advance(state, jax.device_put(chunks),
-                               jax.device_put(counts),
-                               jax.device_put(unknown),
-                               jax.device_put(overflow))
-            # while the device runs this step, the loop's next iteration
-            # produces + routes the following batch on host — the overlap
-            batches += 1
-            n_pad = int((sids == PAD_SID).sum())
-            items += len(sids) - n_pad
-            padded += n_pad
-            drop_unknown += int(unknown)
-            drop_overflow += int(overflow.sum())
-        jax.block_until_ready(state.items)
+        # one span per run; the stages split it without an event per
+        # batch, and none of them syncs the device
+        with obs.span("ingest_run", pod=str(self.pod_id)) as sp:
+            with obs.stage("ingest_slot_table"):  # waits for the last step
+                sid_table = np.asarray(state.sid)
+                active = np.asarray(state.active)
+            while max_batches is None or batches < max_batches:
+                try:
+                    with obs.stage("ingest_get"):
+                        sids, X = next(self._gen)
+                except StopIteration:
+                    self.exhausted = True
+                    if self.buffer is not None:
+                        # buffer mode: a later run() must re-check the
+                        # buffer — a pod handoff may inject relocated
+                        # backlog AFTER the stream closed, and it must
+                        # still drain (source mode keeps the spent
+                        # generator: re-creating it would replay the
+                        # source from the start)
+                        self._gen = None
+                    break
+                with obs.stage("ingest_route"):
+                    routed = host_route(sid_table, active, sids, X, C)
+                with obs.stage("ingest_device_put"):
+                    args = [jax.device_put(a) for a in routed]
+                with obs.stage("ingest_dispatch"):
+                    state, _ = advance(state, *args)
+                # while the device runs this step, the loop's next
+                # iteration produces + routes the following batch on
+                # host — the overlap
+                _, _, unknown, overflow = routed
+                batches += 1
+                n_pad = int((sids == PAD_SID).sum())
+                items += len(sids) - n_pad
+                padded += n_pad
+                sent += sum(a.nbytes for a in routed)
+                drop_unknown += int(unknown)
+                drop_overflow += int(overflow.sum())
+            with obs.stage("ingest_sync"):
+                jax.block_until_ready(state.items)
+            sp.set(batches=batches, items=items, padded=padded,
+                   bytes=sent)
+            if self.buffer is not None:
+                # the producers' side since the last run: items admitted
+                # and seconds waited
+                put = (self.buffer.admitted(),
+                       self.buffer.wait_seconds()["put"])
+                sp.set(buffer_put_items=put[0] - self._put_seen[0],
+                       buffer_put_wait_s=put[1] - self._put_seen[1])
+                self._put_seen = put
         wall = time.perf_counter() - t0
         # telemetry happens HERE and only here: block_until_ready above is
         # the run's host-sync boundary, so draining the device ledgers now
         # costs a few already-materialized (S,) transfers and zero hot-path
         # work (DESIGN.md §13 "record at sync boundaries only")
-        self._record_run(state, batches, items, padded, wall)
+        self._record_run(state, batches, items, padded)
         stats = {"batches": batches, "items": items,
                  "padded": padded, "wall_s": wall,
                  "dropped_unknown": drop_unknown,
@@ -274,7 +304,7 @@ class IngestPipeline:
                 "are in the pod state)") from exc
         return state, stats
 
-    def _record_run(self, state, batches, items, padded, wall) -> None:
+    def _record_run(self, state, batches, items, padded) -> None:
         """Flush one run()'s host-local tallies + the device ledgers into
         the metrics registry.  Host-only, post-sync; never traced."""
         reg = obs.get_registry(self.metrics)
@@ -288,8 +318,6 @@ class IngestPipeline:
         reg.counter("ingest_padding_total",
                     "PAD_SID filler rows burned in partial batches",
                     ("pod",)).labels(pod=pod).inc(padded)
-        reg.histogram("ingest_run_seconds", "wall time of run() calls",
-                      ("pod",)).labels(pod=pod).observe(wall)
         obs.drain.drain_pod(state, pod=pod, registry=reg)
         if self.buffer is not None:
             obs.drain.drain_buffer(self.buffer, pod=pod, registry=reg)

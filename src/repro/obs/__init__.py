@@ -5,11 +5,12 @@ Four pieces, one rule:
   * :mod:`~repro.obs.registry` — counters / gauges / histograms with
     labels; lock-free snapshot reads; JSON snapshot + Prometheus text
     exposition; ``NULL`` (a no-op registry) switches a component off;
-  * :mod:`~repro.obs.spans` — structured spans for control-plane
-    operations (admission, eviction, handoff phases, checkpoint
-    save/restore, drift resets), emitted as JSONL with durations,
+  * :mod:`~repro.obs.spans` — structured spans for host operations
+    (admission, eviction, handoff phases, checkpoint save/restore,
+    drift checks, ingest runs), emitted as JSONL with durations,
     nesting and outcomes (``ok`` / ``error`` / domain outcomes like
-    ``refused``);
+    ``refused``), and stages that split a span into timed parts; both
+    also sit on the profiler's host timeline;
   * :mod:`~repro.obs.jaxbridge` — always-on retrace accounting: XLA
     compile events from ``jax.monitoring`` become ``xla_compile_total``
     / ``xla_compile_seconds`` (installed once, below, at import);
@@ -28,12 +29,12 @@ from .jaxbridge import install as install_jax_bridge
 from .registry import (DEFAULT_BUCKETS, MetricFamily, MetricsRegistry,
                        MetricsSnapshot, NULL, NullRegistry, get_registry,
                        reset_default_registry)
-from .spans import Span, SpanRecorder, get_recorder, span
+from .spans import Span, SpanRecorder, Stage, get_recorder, span, stage
 
 __all__ = [
     "DEFAULT_BUCKETS", "MetricFamily", "MetricsRegistry", "MetricsSnapshot",
     "NULL", "NullRegistry", "get_registry", "reset_default_registry",
-    "Span", "SpanRecorder", "get_recorder", "span",
+    "Span", "SpanRecorder", "Stage", "get_recorder", "span", "stage",
     "drain", "install_jax_bridge", "record_backend_fallback",
 ]
 

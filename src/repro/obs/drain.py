@@ -123,6 +123,9 @@ SHED_HELP = ("items shed by the buffer's watermark ladder, by rung "
              "clip = two-threshold clipping) — deliberate policy losses, "
              "kept OUT of drops_total so overflow stays an accident signal")
 THROTTLE_HELP = "items refused by per-session token-bucket rate limits"
+WAIT_HELP = ("seconds spent waiting in the ingest buffer, by side: put "
+             "(producers, for the lock or for room) or get (the pod's "
+             "pipeline, for the lock or for min_items)")
 
 #: every ladder rung that sheds — registered at zero on each drain so a
 #: dashboard shows shed_total{policy=...} = 0, not a hole until overload
@@ -138,6 +141,9 @@ def drain_buffer(buffer, *, pod: str, registry=None) -> None:
     ``ratelimit_throttled_total{pod}``) so the PR 8 unification stays
     truthful — a rising drops_total still means something went wrong,
     a rising shed_total means the ladder is doing its job.
+    ``buffer_wait_seconds_total{side,pod}`` says who waits on whom: a
+    rising ``put`` side means the pod sets the pace, a rising ``get``
+    side means the producers do.
     """
     reg = get_registry(registry)
     if not reg.enabled:
@@ -154,6 +160,9 @@ def drain_buffer(buffer, *, pod: str, registry=None) -> None:
     observe_total("ratelimit_throttled_total", {"pod": pod},
                   buffer.total_throttled(), help=THROTTLE_HELP,
                   registry=reg)
+    for side, seconds in buffer.wait_seconds().items():
+        observe_total("buffer_wait_seconds_total", {"side": side, "pod": pod},
+                      seconds, help=WAIT_HELP, registry=reg)
     reg.gauge("buffer_shed_rung",
               "current ladder rung (0 admit / 1 subsample / 2 clip)",
               ("pod",)).labels(pod=pod).set(

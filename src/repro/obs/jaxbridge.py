@@ -8,14 +8,12 @@ budgets; this bridge generalizes that counter into *always-on retrace
 accounting* — every fresh compile increments ``xla_compile_total`` and
 lands its duration in ``xla_compile_seconds``, so a CI bench artifact
 (or a production scrape) shows exactly how many programs a run built
-and how long they took.  Other monitored durations and plain events are
-counted generically under ``jax_event_duration_count`` /
-``jax_events_total`` by event name.
+and how long they took.  Other monitored events are not recorded.
 
-``jax.monitoring`` has no unregister API, so exactly ONE pair of
-module-level listeners is installed, the first time :func:`install`
+``jax.monitoring`` has no unregister API, so exactly ONE module-level
+listener is installed, the first time :func:`install`
 runs (``repro.obs`` calls it at import); repeat calls are no-ops.  The
-listeners resolve the *current* default registry at event time (late
+listener resolves the *current* default registry at event time (late
 binding), so ``reset_default_registry()`` — the test/bench isolation
 hook — takes effect without re-subscription.  This is the same
 single-listener discipline the retrace_guard uses; the two coexist as
@@ -36,30 +34,19 @@ _registrations = 0  # how many times listeners were REGISTERED (tests: == 1)
 
 
 def _metric_on_duration(event: str, duration: float, **kwargs) -> None:
+    if event != COMPILE_EVENT:
+        return
     reg = get_registry()
     if not reg.enabled:
         return
-    if event == COMPILE_EVENT:
-        reg.counter("xla_compile_total",
-                    "fresh XLA compiles (cache hits do not count)").inc()
-        reg.histogram("xla_compile_seconds",
-                      "backend_compile durations").observe(duration)
-    else:
-        reg.counter("jax_event_duration_count",
-                    "non-compile jax.monitoring duration events",
-                    ("event",)).labels(event=event).inc()
-
-
-def _metric_on_event(event: str, **kwargs) -> None:
-    reg = get_registry()
-    if not reg.enabled:
-        return
-    reg.counter("jax_events_total", "jax.monitoring point events",
-                ("event",)).labels(event=event).inc()
+    reg.counter("xla_compile_total",
+                "fresh XLA compiles (cache hits do not count)").inc()
+    reg.histogram("xla_compile_seconds",
+                  "backend_compile durations").observe(duration)
 
 
 def install() -> bool:
-    """Subscribe the bridge listeners exactly once; returns True when
+    """Subscribe the bridge listener exactly once; returns True when
     this call performed the subscription (False: already installed)."""
     global _installed, _registrations
     with _install_lock:
@@ -67,7 +54,6 @@ def install() -> bool:
             return False
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_metric_on_duration)
-        monitoring.register_event_listener(_metric_on_event)
         _registrations += 1
         _installed = True
         return True

@@ -1,12 +1,24 @@
-"""Structured spans for control-plane operations.
+"""Structured spans and stages for host operations of the serving stack.
 
 The serving stack's control plane — admission at the fleet front-end,
 ``evict_sids``, the quiesce -> snapshot -> restore -> flip phases of a
-pod handoff, checkpoint save/restore, drift resets — is host code that
-runs at human-auditable cadence.  Each operation is wrapped in a
-``span``: a context manager that records name, wall duration, nesting
-(parent span id, depth), an *outcome* and free-form attributes, and
-emits one JSON line per completed span.
+pod handoff, checkpoint save/restore, drift checks — and each
+``IngestPipeline.run`` are host code that runs at human-auditable
+cadence.  Each operation is wrapped in a ``span``: a context manager
+that records name, wall duration, nesting (parent span id, depth), an
+*outcome* and free-form attributes, and emits one JSON line per
+completed span.
+
+Two sinks, one API: every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so under a profiler trace
+it sits on the host plane, on the device trace's clock.  Inside a span,
+per-batch work is timed by a lighter ``stage``: it opens the same kind
+of TraceMe and adds its seconds to the attribute ``<stage>_s`` of the
+enclosing span on its thread, and is never emitted to the recorder or
+the registry by itself.  A stage with no enclosing span (a producer
+thread's ``put``) shows on the profiler timeline only.  So the event
+ring holds one ``ingest_run`` per pipeline run, whatever the batch and
+put rates, and still carries the run's split into stages.
 
 Outcome contract: ``ok`` by default; an exception escaping the body
 records ``outcome="error"`` (with the exception type) and re-raises —
@@ -15,15 +27,15 @@ timeline.  Domain refusals set their own outcome explicitly
 (``sp.set_outcome("refused")``): a refusal is not an error, but it is
 an event.
 
-Durations are *dispatch* durations: spans never call
+Durations are *dispatch* durations: spans and stages never call
 ``block_until_ready`` — instrumenting must not add device syncs
 (DESIGN.md §13).  Wrap a span around code that already syncs (a
 handoff's host gather, ``pipeline.run``'s final block) and the
 duration is honest; wrap it around a bare jitted call and it measures
 enqueue time, which is what the control plane actually waits for.
 
-Spans are host-only by construction: entering one inside a JAX trace
-is a no-op (the static gate is podlint PL006; this is the runtime
+Spans and stages are host-only by construction: entering one inside a
+JAX trace is a no-op (the static gate is podlint PL006; this is the runtime
 backstop — a span recorded at trace time would fire once per compile
 with a meaningless duration, then never again).
 
@@ -41,6 +53,7 @@ from pathlib import Path
 from typing import Dict, List, Optional
 
 from jax.core import trace_ctx as _trace_ctx  # the "inside a trace?" probe
+from jax.profiler import TraceAnnotation
 
 from repro.concurrency import make_lock
 
@@ -90,7 +103,7 @@ class SpanRecorder:
         self._registry = registry
 
     # ------------------------------------------------------------- plumbing
-    def _stack(self) -> List[int]:
+    def _stack(self) -> List[Span]:
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
@@ -136,10 +149,12 @@ class SpanRecorder:
         with self._lock:
             span_id = self._next_id
             self._next_id += 1
-        sp = Span(name, span_id, stack[-1] if stack else None,
+        sp = Span(name, span_id, stack[-1].span_id if stack else None,
                   len(stack), dict(attrs))
-        stack.append(span_id)
+        stack.append(sp)
         t_wall = time.time()
+        tm = TraceAnnotation(name)
+        tm.__enter__()
         try:
             yield sp
         except BaseException as e:
@@ -147,6 +162,7 @@ class SpanRecorder:
             sp.attrs.setdefault("error", type(e).__name__)
             raise
         finally:
+            tm.__exit__(None, None, None)
             stack.pop()
             self._emit({
                 "name": sp.name,
@@ -189,6 +205,39 @@ class SpanRecorder:
                 self._fh = None
 
 
+class Stage:
+    """One timed interval inside the enclosing span (module docstring).
+
+    ``seconds`` holds the interval's length after exit, for callers
+    that keep their own totals (``TaggedBuffer``'s wait seconds)."""
+
+    __slots__ = ("name", "seconds", "_recorder", "_tm", "_t0")
+
+    def __init__(self, recorder: SpanRecorder, name: str):
+        self.name = name
+        self.seconds = 0.0
+        self._recorder = recorder
+        self._tm = None
+
+    def __enter__(self) -> "Stage":
+        if _trace_ctx.is_top_level():  # inside a trace: time nothing
+            self._tm = TraceAnnotation(self.name)
+            self._tm.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        if self._tm is None:
+            return
+        self._tm.__exit__(None, None, None)
+        stack = self._recorder._stack()
+        if stack:
+            attrs = stack[-1].attrs
+            key = self.name + "_s"
+            attrs[key] = attrs.get(key, 0.0) + self.seconds
+
+
 _RECORDER = SpanRecorder()
 
 
@@ -200,3 +249,10 @@ def span(name: str, **attrs: object):
     """``with obs.span("handoff", src=0, dst=1) as sp:`` on the default
     recorder — the one the instrumented serving modules use."""
     return _RECORDER.span(name, **attrs)
+
+
+def stage(name: str) -> Stage:
+    """``with obs.stage("ingest_route"):`` on the default recorder: adds
+    the interval's seconds to ``ingest_route_s`` of the enclosing
+    span."""
+    return Stage(_RECORDER, name)

@@ -111,8 +111,10 @@ class PodState:
 
 @hashable_lru(maxsize=64)
 def _drift_for(pod, min_items: int, min_rate: float):
-    return jax.jit(lambda s: pod.drift_check(
-        s, min_items=min_items, min_rate=min_rate))
+    def drift_check(state):  # named: traces show ``jit_drift_check``
+        return pod.drift_check(state, min_items=min_items, min_rate=min_rate)
+
+    return jax.jit(drift_check)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -539,7 +541,7 @@ class SummarizerPod:
                         total[k] = total.get(k, 0) + v
                 # host-side control plane between pipeline runs — safe to
                 # span here (the drift program itself stays untouched)
-                with obs.span("drift_reset", pod=str(pipeline.pod_id),
+                with obs.span("drift_check", pod=str(pipeline.pod_id),
                               every=drift_every):
                     state, _ = drift(state)
                 if remaining is not None:

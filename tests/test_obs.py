@@ -475,7 +475,10 @@ def test_drift_reset_span_in_serve(fresh_obs):
     pipe = IngestPipeline(pod=pod, source=iter([(sids, X)] * 4), batch=16)
     state, stats = pod.serve(state, pipe, drift_every=2)
     assert stats["batches"] == 4
-    assert len(rec.find("drift_reset")) >= 1
+    # the span is named for what it measures: every drift check, whether
+    # or not a session re-arms
+    assert len(rec.find("drift_check")) >= 2
+    assert rec.find("drift_reset") == []
 
 
 def test_pod_drain_metrics_delegates(fresh_obs):
@@ -488,3 +491,146 @@ def test_pod_drain_metrics_delegates(fresh_obs):
     state, _, _ = pod.admit(state, 42)
     pod.drain_metrics(state, pod="2")
     assert reg.snapshot().get("pod_active_sessions", pod="2") == 1
+
+
+# ------------------------------------------------- stages on the served path
+STAGES = ("ingest_slot_table", "ingest_get", "ingest_route",
+          "ingest_device_put", "ingest_dispatch", "ingest_sync")
+
+
+def _source_run(n_batches=4, pod_id="5"):
+    from repro.core.api import make
+    from repro.ingest import IngestPipeline
+    from repro.serve.summarize import SummarizerPod
+    algo = make("threesieves", d=4, K=4, T=16, eps=0.5)
+    pod = SummarizerPod(algo, sessions=4, chunk=16)
+    state = pod.init()
+    state, _, _ = pod.admit(state, 0)
+    rng = np.random.default_rng(1)
+    batches = [(np.zeros((16,), np.int32),
+                rng.normal(size=(16, 4)).astype(np.float32))
+               for _ in range(n_batches)]
+    pipe = IngestPipeline(pod=pod, source=iter(batches), batch=16,
+                          pod_id=pod_id)
+    return pipe.run(state)
+
+
+def test_stage_adds_to_the_enclosing_span_only(fresh_obs):
+    reg, rec = fresh_obs
+    with obs.stage("alone") as st:  # no span: nothing recorded
+        pass
+    assert st.seconds >= 0
+    assert rec.events == [] and reg.snapshot().families == []
+    with rec.span("outer"):
+        with rec.span("inner"):
+            with obs.stage("work"):
+                pass
+        for _ in range(3):
+            with obs.stage("work"):
+                pass
+    inner, outer = rec.events
+    assert set(inner["attrs"]) == {"work_s"}
+    assert set(outer["attrs"]) == {"work_s"}
+    assert 0 <= outer["attrs"]["work_s"] <= outer["dur_s"]
+    assert reg.snapshot().get("spans_total", name="outer", outcome="ok") == 1
+    assert [f["name"] for f in reg.snapshot().families] == [
+        "span_seconds", "spans_total"]
+
+
+def test_stage_is_noop_under_trace(fresh_obs):
+    _, rec = fresh_obs
+
+    @jax.jit
+    def f(x):
+        with obs.stage("traced-stage"):  # podlint: ignore[PL006] -- pinned
+            return x + 1
+
+    with rec.span("host"):
+        f(jnp.arange(3)).block_until_ready()
+    (ev,) = rec.events
+    assert "traced-stage_s" not in ev["attrs"]
+
+
+def test_ingest_run_span_holds_its_stages(fresh_obs):
+    """One ``ingest_run`` event per run, whatever the batch count; its
+    stage attributes are ≥ 0 and sum to at most its duration."""
+    _, rec = fresh_obs
+    _, stats = _source_run(n_batches=4)
+    (ev,) = rec.events
+    assert ev["name"] == "ingest_run" and ev["outcome"] == "ok"
+    a = ev["attrs"]
+    assert (a["pod"], a["batches"], a["items"], a["padded"]) == (
+        "5", 4, stats["items"], 0)
+    # per batch: chunks (S, C, d) f32, counts (S,), unknown (), overflow
+    # (S,) int32
+    assert a["bytes"] == 4 * 4 * (4 * 16 * 4 + 4 + 1 + 4)
+    stages = {k[:-2]: v for k, v in a.items() if k.endswith("_s")}
+    assert set(stages) == set(STAGES)
+    assert all(v >= 0 for v in stages.values())
+    assert sum(stages.values()) <= ev["dur_s"]
+
+
+def test_stages_are_tracemes_inside_ingest_run(fresh_obs, tmp_path):
+    """Under a profiler trace the span and its stages sit on the host
+    plane, on one thread, each stage inside ``ingest_run``."""
+    from jax.profiler import ProfileData
+    _source_run(n_batches=1)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _source_run(n_batches=2)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events]
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:CPU") for line in plane.lines]
+    (line,) = [evs for evs in lines
+               if any(n == "ingest_run" for n, _, _ in evs)]
+    (run,) = [ev for ev in line if ev[0] == "ingest_run"]
+    inside = [n for n, s, e in line if s >= run[1] and e <= run[2]]
+    assert set(STAGES) <= set(inside)
+    assert inside.count("ingest_route") == 2
+
+
+def test_buffer_waits_reach_the_drain(fresh_obs):
+    """A put blocked on a full buffer and a get below ``min_items`` each
+    show in ``buffer_wait_seconds_total`` after a drain — and neither
+    writes to the recorder or the registry by itself."""
+    import threading
+    import time
+
+    from repro.ingest import TaggedBuffer
+    reg, rec = fresh_obs
+    buf = TaggedBuffer(capacity=2, policy="block")
+    row = np.zeros((1, 2), np.float32)
+    buf.put([1, 1], np.zeros((2, 2), np.float32))
+    t = threading.Thread(target=buf.put, args=([1], row))
+    t.start()  # blocks: the buffer is full
+    time.sleep(0.2)
+    assert buf.get(2) is not None  # makes room
+    t.join(5)
+    assert not t.is_alive()
+    got = []
+    t = threading.Thread(target=lambda: got.append(buf.get(4, min_items=2)))
+    t.start()  # waits: one item buffered, two asked for
+    time.sleep(0.2)
+    buf.put([2], row)
+    t.join(5)
+    assert len(got[0][0]) == 2
+    assert rec.events == [] and reg.snapshot().families == []
+    waits = buf.wait_seconds()
+    assert waits["put"] >= 0.1 and waits["get"] >= 0.1
+    obs.drain.drain_buffer(buf, pod="6")
+    snap = reg.snapshot()
+    for side in ("put", "get"):
+        assert snap.get("buffer_wait_seconds_total", side=side,
+                        pod="6") == waits[side]
+
+
+def test_the_bridge_counts_compiles_only(fresh_obs):
+    reg, _ = fresh_obs
+    jax.jit(lambda x: x - 7)(jnp.arange(13)).block_until_ready()
+    names = {f["name"] for f in reg.snapshot().families}
+    assert "xla_compile_total" in names
+    assert not names & {"jax_events_total", "jax_event_duration_count"}

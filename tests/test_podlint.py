@@ -95,6 +95,11 @@ def test_pl006_flags_both_counter_and_span_but_not_at_set():
     assert not quiet
 
 
+def test_pl006_flags_stage_entry():
+    findings, _ = _lint_file(TESTDATA / "pl006_bad.py", select=["PL006"])
+    assert any("obs.stage(...)" in f.message for f in findings)
+
+
 # --------------------------------------------- interprocedural (PL007/PL008)
 def test_pl008_catches_the_pr5_pattern_cross_module():
     """The PR 5 deadlock split over two files: the router holds its

@@ -558,8 +558,8 @@ class TracerBranch(Rule):
 
 @register
 class MetricInTrace(Rule):
-    """``counter.inc()`` / ``hist.observe()`` / ``obs.span(...)`` inside
-    traced functions.
+    """``counter.inc()`` / ``hist.observe()`` / ``obs.span(...)`` /
+    ``obs.stage(...)`` inside traced functions.
 
     Telemetry executed under a trace is the worst kind of wrong: it does
     not crash.  The recording call runs once per *compile*, not per
@@ -581,7 +581,7 @@ class MetricInTrace(Rule):
     summary = "metric recording (.inc/.dec/.observe) or span entry in traced code"
     defaults: ClassVar[Dict[str, object]] = {
         "record_methods": ["inc", "dec", "observe"],
-        "span_callables": ["span"],
+        "span_callables": ["span", "stage"],
     }
 
     def check(self, model, cfg):

@@ -13,7 +13,8 @@ ITEMS = obs.get_registry().counter("fixture_items_total", "items seen")
 def step(state, x):
     ITEMS.inc()  # BAD: runs once per compile, not once per step
     with obs.span("step", n=x.shape[0]):  # BAD: span under the trace
-        gain = jnp.dot(state, x)
+        with obs.stage("gain"):  # BAD: so is a stage
+            gain = jnp.dot(state, x)
     return state + jnp.where(gain > 0, x, 0.0)
 
 
