@@ -40,6 +40,28 @@ round-robin, one item per live session per turn.  Per-session order is
 therefore preserved end-to-end (the pod's routing contract); global
 interleaving is deliberately NOT preserved — that is the fairness.
 
+Storage: ``put`` copies the rows it admits into one store, an array of
+rows with a free list of its slots, and a session's queue holds the
+slots of its items in one int64 array.  So the Python work of both
+sides scales with sessions and puts, not items, whatever arrays the
+producers put.  The store doubles when no slot is free and keeps its
+size: the buffer at its fullest plus the batch a ``get`` is copying
+out.  ``put`` groups its batch by session with one stable argsort and
+appends the slots of each session present in one step — whenever
+admission is a prefix decision: the ``block`` policy (the prefix that
+fits, then a wait for room, then the rest) and ``drop-newest`` (the
+prefix that fits; the rest is clipped).  Where admission decides item by
+item — a rate limit or a shed ladder installed, or ``drop-oldest``
+(each overflow clips another queue's head) — ``put`` keeps its per-item
+loop, and copies the rows it admitted in one step at its end.  ``get``
+computes the round-robin per session, not per item: ``r`` full turns,
+found from the sorted depths, give each drainable session ``min(depth,
+r)`` items, the partial turn one more to the first sessions in order
+that still hold items; it takes each session's share of slots under the
+lock, then outside it puts the rows in turn order (a stable sort by
+turn) into the batch with one ``np.take`` and gives the slots back.
+The result is the item-at-a-time loop's, bit for bit.
+
 Quiesce (the autoscaler's handoff primitive, DESIGN.md §10): a session
 marked ``quiesce``d keeps *receiving* items but ``get`` stops draining
 it — its backlog parks in the buffer, uncounted as dropped, until
@@ -77,6 +99,69 @@ POLICIES = ("block", "drop-newest", "drop-oldest")
 PAD_SID = -1  # the pod's queue-padding sentinel
 
 
+class _Fifo:
+    """One session's queue: the store slots of its items, oldest first,
+    in one int64 array (``idx[head:tail]``)."""
+
+    __slots__ = ("idx", "head", "tail")
+
+    def __init__(self):
+        self.idx = np.empty(16, np.int64)
+        self.head = self.tail = 0
+
+    def __len__(self) -> int:
+        return self.tail - self.head
+
+    def append(self, slots: np.ndarray) -> None:
+        m = len(slots)
+        if self.tail + m > len(self.idx):
+            n = self.tail - self.head
+            idx = np.empty(2 * (n + m), np.int64)
+            idx[:n] = self.idx[self.head:self.tail]
+            self.idx, self.head, self.tail = idx, 0, n
+        self.idx[self.tail:self.tail + m] = slots
+        self.tail += m
+
+    def push(self, slot) -> None:
+        if self.tail == len(self.idx):
+            self.append(np.array([slot]))
+        else:
+            self.idx[self.tail] = slot
+            self.tail += 1
+
+    def take(self, k: int) -> np.ndarray:
+        """The first ``k`` slots, off the queue (a view, valid until the
+        next ``append``)."""
+        self.head += k
+        return self.idx[self.head - k:self.head]
+
+    def slots(self) -> np.ndarray:
+        return self.idx[self.head:self.tail]
+
+
+def _round_robin(depth: list, max_items: int) -> list:
+    """Items each queue gives to a batch of at most ``max_items`` taken
+    one per queue per turn, queues in order: ``r`` full turns, then one
+    more item from each of the first queues still holding one."""
+    left = max_items
+    for i, c in enumerate(sorted(depth)):
+        if c * (len(depth) - i) > left:  # ``r`` turns end inside queue i
+            r = left // (len(depth) - i)
+            break
+        left -= c  # queue i empties within the ``r`` turns
+    else:
+        return depth
+    take = [min(c, r) for c in depth]
+    rest = max_items - sum(take)
+    for i, c in enumerate(depth):
+        if not rest:
+            break
+        if c > r:
+            take[i] += 1
+            rest -= 1
+    return take
+
+
 class TaggedBuffer:
     """Bounded, thread-safe, per-session-fair tagged item buffer.
 
@@ -99,8 +184,13 @@ class TaggedBuffer:
         self.rate_limit = rate_limit
         self.shed = shed
         self._clock = clock
-        self._q: "collections.OrderedDict[int, collections.deque]" = \
-            collections.OrderedDict()  # sid -> FIFO of (d,) float32 rows
+        self._q: "collections.OrderedDict[int, _Fifo]" = \
+            collections.OrderedDict()  # sid -> FIFO of store slots
+        # the store: every buffered row, and the rows a ``get`` still
+        # copies out, in one array; ``_free[:_nfree]`` the other slots
+        self._rows: Optional[np.ndarray] = None
+        self._free = np.empty(0, np.int64)
+        self._nfree = 0
         self._size = 0
         self._quiesced: set = set()  # sids parked: fed, never drained
         self._closed = False
@@ -119,6 +209,8 @@ class TaggedBuffer:
         self._rate_overrides: Dict[int, RateLimit] = {}
         self._wait_s = {"put": 0.0, "get": 0.0}  # side -> seconds waited
         self._admitted = 0  # items put admitted, lifetime
+        self._block_items = 0  # of them, admitted session by session
+        self._get_blocks = 0  # sessions' shares get took, lifetime
 
     # ------------------------------------------------------------- properties
     @property
@@ -202,6 +294,15 @@ class TaggedBuffer:
         with self._lock:
             return self._admitted
 
+    def block_counts(self) -> Dict[str, int]:
+        """Lifetime items ``put`` admitted through the block path
+        (``put_block_items``) and the sessions' shares ``get`` took
+        into batches, one slice of store slots each (``get_blocks``):
+        the storage format at work."""
+        with self._lock:
+            return {"put_block_items": self._block_items,
+                    "get_blocks": self._get_blocks}
+
     def _lock_wait(self, side: str) -> Optional[obs.Stage]:
         """An open ``buffer_<side>_wait_lock`` stage when another thread
         holds the lock, else None; ``_lock_waited`` closes it once the
@@ -255,12 +356,13 @@ class TaggedBuffer:
         capacity) at the source pod — re-admitting it at the target
         must neither block, drop, nor fail because the stream happened
         to close mid-handoff.  Not for producers; ``put`` is."""
+        tags = np.asarray(sids).ravel()
+        n = min(len(tags), len(rows))
+        X = np.asarray(rows[:n], np.float32)
         with self._lock:
-            for sid, row in zip(
-                    (int(s) for s in np.asarray(sids).ravel()), rows):
-                self._q.setdefault(sid, collections.deque()).append(
-                    np.asarray(row, np.float32))
-                self._size += 1
+            if n:
+                self._append_blocks(tags[:n], X)
+                self._size += n
             self._not_empty.notify_all()
 
     def extract(self, sids) -> Tuple[np.ndarray, list]:
@@ -273,14 +375,82 @@ class TaggedBuffer:
         with self._lock:
             for sid in (int(s) for s in np.asarray(sids).ravel()):
                 self._quiesced.discard(sid)
-                dq = self._q.pop(sid, None)
-                if dq:
-                    out_s.extend([sid] * len(dq))
-                    out_x.extend(dq)
-                    self._size -= len(dq)
+                q = self._q.pop(sid, None)
+                if q:
+                    out_s.extend([sid] * len(q))
+                    out_x.extend(self._rows[q.slots()])
+                    self._release(q.slots())
+                    self._size -= len(q)
             if out_s:
                 self._not_full.notify_all()
         return np.asarray(out_s, np.int32), out_x
+
+    def _fit(self, X: np.ndarray) -> None:
+        """Make the store hold rows shaped as ``X``'s (under the lock)."""
+        if self._rows is None or (X.shape[1:] != self._rows.shape[1:]
+                                  and self._nfree == len(self._rows)):
+            self._rows = np.empty((0, *X.shape[1:]), np.float32)
+            self._nfree = 0
+        elif X.shape[1:] != self._rows.shape[1:]:
+            raise ValueError(f"rows of shape {X.shape[1:]} in a buffer of "
+                             f"rows of shape {self._rows.shape[1:]}")
+
+    def _grow(self, m: int) -> None:
+        """Make ``m`` slots free, doubling the store (under the lock):
+        the slots a ``get`` still copies out stay valid in the old
+        array, and the rows are in the new one."""
+        if self._nfree >= m:
+            return
+        old = len(self._rows)
+        new = max(2 * old, old + m - self._nfree, 1024)
+        rows = np.empty((new, *self._rows.shape[1:]), np.float32)
+        rows[:old] = self._rows
+        free = np.empty(new, np.int64)
+        free[:self._nfree] = self._free[:self._nfree]
+        free[self._nfree:self._nfree + new - old] = np.arange(old, new)
+        self._rows, self._free = rows, free
+        self._nfree += new - old
+
+    def _store(self, X: np.ndarray) -> np.ndarray:
+        """Copy rows ``X`` into free slots of the store -> the slots
+        (under the lock)."""
+        m = len(X)
+        self._fit(X)
+        self._grow(m)
+        self._nfree -= m
+        slots = self._free[self._nfree:self._nfree + m].copy()
+        self._rows[slots] = X
+        return slots
+
+    def _release(self, slots: np.ndarray) -> None:
+        """Return store slots to the free list (under the lock): the
+        next puts take them from the end, in that order, so slots given
+        back in order make their copies run in order."""
+        k = len(slots)
+        self._free[self._nfree:self._nfree + k] = slots
+        self._nfree += k
+
+    def _append_blocks(self, sids: np.ndarray, X: np.ndarray) -> None:
+        """Queue rows ``X`` as their sessions' next items, session by
+        session; sessions new to the buffer join the round-robin in
+        order of first appearance, as item-by-item appends would have
+        them (under the lock; the caller counts them)."""
+        order = np.argsort(sids, kind="stable")
+        tags = sids[order]
+        starts = np.flatnonzero(np.r_[True, tags[1:] != tags[:-1]])
+        ends = np.r_[starts[1:], len(tags)]
+        slots = self._store(X)[order]
+        first = np.argsort(order[starts])
+        for sid, a, b in zip(tags[starts[first]].tolist(),
+                             starts[first].tolist(), ends[first].tolist()):
+            self._queue(sid).append(slots[a:b])
+
+    def _queue(self, sid: int) -> _Fifo:
+        """``sid``'s queue, made (last in the round-robin) if new."""
+        q = self._q.get(sid)
+        if q is None:
+            q = self._q[sid] = _Fifo()
+        return q
 
     # --------------------------------------------------------------- producer
     def _admit_rate(self, sid: int, now: float) -> bool:
@@ -309,28 +479,22 @@ class TaggedBuffer:
                 self._shed_by_policy.get(rung, 0) + 1
         return ok
 
-    def put(self, sids, X, timeout: Optional[float] = None) -> int:
-        """Enqueue a tagged batch; returns the number of items *not*
-        admitted (rate-limit throttles + ladder sheds + overflow drops
-        — each counted in its own ledger).
-
-        Admission order per item: token bucket (throttle), shed ladder
-        (policy shed), then capacity.  ``block`` waits for room
-        (``timeout`` seconds per stalled item, None = forever) and
-        raises ``TimeoutError`` on expiry; the drop policies never
-        wait.  Raises ``ValueError`` after ``close()``.
-        """
-        sids = np.asarray(sids, np.int32).ravel()
-        X = np.asarray(X, np.float32)
+    def _admit_items(self, tags: list, X: np.ndarray, lo: int,
+                     now: float) -> Tuple[int, int, bool]:
+        """Per-item admission from item ``lo`` on (under the lock): each
+        item (row ``X[j]``) meets the token bucket, the shed ladder and
+        the capacity in turn and, admitted, takes a slot and its place
+        in its queue at once; the rows are copied in one step on the
+        way out.  -> (the item it stopped at, items not admitted,
+        whether that item passed its checks and waits for room under
+        ``block``)."""
         dropped = 0
-        now = self._clock() if self.rate_limit or self._rate_overrides \
-            else 0.0
-        waiting = self._lock_wait("put")
-        with self._lock:
-            self._lock_waited("put", waiting)
-            for sid, row in zip(sids.tolist(), X):
-                if self._closed:
-                    raise ValueError("put() on a closed TaggedBuffer")
+        self._fit(X)
+        # slot -> item; a slot clipped and taken again holds its last item
+        held: Dict[int, int] = {}
+        try:
+            for j in range(lo, len(tags)):
+                sid = tags[j]
                 if not self._admit_rate(sid, now):
                     self.throttled[sid] = self.throttled.get(sid, 0) + 1
                     dropped += 1
@@ -340,37 +504,123 @@ class TaggedBuffer:
                     continue
                 if self._size >= self.capacity:
                     if self.policy == "block":
-                        with obs.stage("buffer_put_wait_room") as st:
-                            room = self._not_full.wait_for(
-                                lambda: self._size < self.capacity
-                                or self._closed, timeout)
-                        self._wait_s["put"] += st.seconds
-                        if not room:
-                            raise TimeoutError(
-                                f"TaggedBuffer full ({self.capacity}) for "
-                                f"{timeout}s")
-                        if self._closed:
-                            raise ValueError("put() on a closed TaggedBuffer")
-                    elif self.policy == "drop-newest":
+                        return j, dropped, True
+                    if self.policy == "drop-newest":
                         self.drops[sid] = self.drops.get(sid, 0) + 1
                         dropped += 1
                         continue
-                    else:  # drop-oldest: clip the longest queue's head
-                        # quiesced sessions are mid-migration: clipping
-                        # them breaks the handoff's zero-drop contract,
-                        # so they only pay when no one else can
-                        pool = [s for s in self._q if s not in
-                                self._quiesced] or list(self._q)
-                        victim = max(pool, key=lambda s: len(self._q[s]))
-                        self._q[victim].popleft()
-                        if not self._q[victim]:
-                            del self._q[victim]
-                        self._size -= 1
-                        self.drops[victim] = self.drops.get(victim, 0) + 1
-                        dropped += 1
-                self._q.setdefault(sid, collections.deque()).append(row)
+                    # drop-oldest: clip the longest queue's head; quiesced
+                    # sessions are mid-migration: clipping them breaks the
+                    # handoff's zero-drop contract, so they only pay when
+                    # no one else can
+                    pool = [s for s in self._q if s not in
+                            self._quiesced] or list(self._q)
+                    victim = max(pool, key=lambda s: len(self._q[s]))
+                    self._release(self._q[victim].take(1))
+                    if not self._q[victim]:
+                        del self._q[victim]
+                    self._size -= 1
+                    self.drops[victim] = self.drops.get(victim, 0) + 1
+                    dropped += 1
+                if not self._nfree:
+                    self._grow(1)
+                self._nfree -= 1
+                slot = int(self._free[self._nfree])
+                held[slot] = j
+                q = self._q.get(sid)
+                (self._queue(sid) if q is None else q).push(slot)
                 self._size += 1
                 self._admitted += 1
+            return len(tags), dropped, False
+        finally:
+            if held:
+                self._rows[np.fromiter(held, np.int64, len(held))] = X.take(
+                    np.fromiter(held.values(), np.int64, len(held)), axis=0)
+
+    def _admit_prefix(self, sids: np.ndarray, X: np.ndarray,
+                      lo: int) -> Tuple[int, int, bool]:
+        """Block admission from item ``lo`` on (under the lock): the
+        prefix that fits joins the queues session by session; the rest
+        waits for room under ``block`` or is clipped under
+        ``drop-newest``.  -> as ``_admit_items``."""
+        n = len(sids)
+        room = self.capacity - self._size
+        if room > 0:
+            hi = min(n, lo + room)
+            self._append_blocks(sids[lo:hi], X[lo:hi])
+            self._size += hi - lo
+            self._admitted += hi - lo
+            self._block_items += hi - lo
+            return hi, 0, False
+        if self.policy == "block":
+            return lo, 0, True
+        # drop-newest: nothing drains while the lock is held, so the
+        # rest of the call finds the buffer full
+        for sid, m in zip(*(a.tolist() for a in np.unique(
+                sids[lo:], return_counts=True))):
+            self.drops[sid] = self.drops.get(sid, 0) + m
+        return n, n - lo, False
+
+    def put(self, sids, X, timeout: Optional[float] = None) -> int:
+        """Enqueue a tagged batch; returns the number of items *not*
+        admitted (rate-limit throttles + ladder sheds + overflow drops
+        — each counted in its own ledger).
+
+        Admission order per item: token bucket (throttle), shed ladder
+        (policy shed), then capacity.  ``block`` waits for room
+        (``timeout`` seconds per stall, None = forever) and raises
+        ``TimeoutError`` on expiry, keeping what it admitted before;
+        the drop policies never wait.  Raises ``ValueError`` after
+        ``close()``.  Without a rate limit or a shed ladder, and under
+        ``block`` or ``drop-newest``, admission is a prefix decision
+        and the batch is queued session by session (module docstring).
+        """
+        sids = np.asarray(sids, np.int32).ravel()
+        X = np.asarray(X, np.float32)
+        n = min(len(sids), len(X))
+        sids = sids[:n]
+        dropped = 0
+        now = self._clock() if self.rate_limit or self._rate_overrides \
+            else 0.0
+        waiting = self._lock_wait("put")
+        with self._lock:
+            self._lock_waited("put", waiting)
+            per_item = (self.shed is not None
+                        or self.policy == "drop-oldest"
+                        or self.rate_limit is not None
+                        or any(v is not None
+                               for v in self._rate_overrides.values()))
+            tags = sids.tolist() if per_item else None
+            admitted = self._admitted
+            lo = 0
+            while lo < n:
+                if self._closed:
+                    raise ValueError("put() on a closed TaggedBuffer")
+                if per_item:
+                    lo, lost, full = self._admit_items(tags, X, lo, now)
+                else:
+                    lo, lost, full = self._admit_prefix(sids, X, lo)
+                dropped += lost
+                if not full:
+                    continue
+                self._not_empty.notify_all()  # the getter makes room
+                with obs.stage("buffer_put_wait_room") as st:
+                    room = self._not_full.wait_for(
+                        lambda: self._size < self.capacity
+                        or self._closed, timeout)
+                self._wait_s["put"] += st.seconds
+                if not room:
+                    raise TimeoutError(
+                        f"TaggedBuffer full ({self.capacity}) for "
+                        f"{timeout}s")
+                if self._closed:
+                    raise ValueError("put() on a closed TaggedBuffer")
+                if per_item:  # the stalled item passed its checks
+                    self._append_blocks(sids[lo:lo + 1], X[lo:lo + 1])
+                    self._size += 1
+                    self._admitted += 1
+                    lo += 1
+            if self._admitted > admitted:
                 self._not_empty.notify_all()  # waiters may need min_items
         return dropped
 
@@ -397,8 +647,9 @@ class TaggedBuffer:
         ``timeout`` raises ``TimeoutError`` on an open-but-underfilled
         buffer.  ``pad_to`` right-pads the batch with (PAD_SID,
         zero-row) entries to a fixed length — the shape contract of the
-        jitted pod program (``d`` sizes the zero rows when the batch
-        itself is empty).
+        jitted pod program.  ``d`` is ignored: the buffered rows give
+        the width, since a batch holds at least one item (it stays for
+        the callers that pass it).
         """
         need = max(1, min(min_items, max_items))
         waiting = self._lock_wait("get")
@@ -418,33 +669,43 @@ class TaggedBuffer:
                         f"TaggedBuffer below {need} items for {timeout}s")
             if self._avail() == 0:  # closed and drained (of drainables)
                 return None
-            out_s, out_x = [], []
-            while len(out_s) < max_items and self._q:
-                # one item per live session per round — the fairness turn
-                took = 0
-                for sid in list(self._q):
-                    if len(out_s) >= max_items:
-                        break
-                    if sid in self._quiesced:
-                        continue
-                    dq = self._q[sid]
-                    out_s.append(sid)
-                    out_x.append(dq.popleft())
-                    took += 1
-                    if not dq:
+            live = [(sid, q) for sid, q in self._q.items()
+                    if sid not in self._quiesced]
+            who, ks, parts = [], [], []  # each session's share, in order
+            for (sid, q), k in zip(
+                    live, _round_robin([len(q) for _, q in live], max_items)):
+                if k:
+                    who.append(sid)
+                    ks.append(k)
+                    parts.append(q.take(k))
+                    if not q:
                         del self._q[sid]
-                if not took:  # only quiesced queues remain
-                    break
-            self._size -= len(out_s)
+            at = np.concatenate(parts)  # their slots, session by session
+            store = self._rows  # a put that grows it leaves these rows
+            n = len(at)
+            self._size -= n
+            self._get_blocks += len(parts)
             self._not_full.notify_all()
-        sids = np.asarray(out_s, np.int32)
-        X = np.stack(out_x).astype(np.float32)
-        if pad_to is not None and len(sids) < pad_to:
-            n_pad = pad_to - len(sids)
-            width = X.shape[1] if X.size else d
-            if width is None:
-                raise ValueError("empty batch needs ``d`` to size padding")
-            sids = np.concatenate(
-                [sids, np.full((n_pad,), PAD_SID, np.int32)])
-            X = np.concatenate([X, np.zeros((n_pad, width), np.float32)])
+        try:
+            # a session's k-th item of this batch goes out in turn k: a
+            # stable sort by turn gives the round-robin order
+            take = np.array(ks)
+            turn = np.arange(n) - (take.cumsum() - take).repeat(take)
+            order = turn.argsort(kind="stable")
+            # as unsigned, a negative slot is out of range too
+            if at.view(np.uint64).max() >= len(store):
+                raise RuntimeError("TaggedBuffer: a queued slot lies "
+                                   "outside the store")
+            rows = max(n, pad_to or 0)
+            X = (np.zeros if rows > n else np.empty)(
+                (rows, *store.shape[1:]), np.float32)
+            at = at[order]
+            store.take(at, axis=0, out=X[:n], mode="clip")
+            at.sort(kind="stable")  # nearly sorted: the puts' order
+        finally:  # the slots are free once their rows are copied out
+            with self._lock:
+                self._release(at)
+        sids = np.empty(rows, np.int32)
+        sids[:n] = np.array(who, np.int32).repeat(take)[order]
+        sids[n:] = PAD_SID
         return sids, X

@@ -129,7 +129,7 @@ class IngestPipeline:
         self._advance = None
         self._feeders = []
         self._feed_exc: Optional[BaseException] = None
-        self._put_seen = (0, 0.0)  # buffer (admitted, put wait) at last run
+        self._seen: dict = {}  # the buffer's counters at the last run
         self.exhausted = False
 
     # ------------------------------------------------------------------ feed
@@ -219,7 +219,10 @@ class IngestPipeline:
         Each call leaves one ``ingest_run`` span (batches, items,
         padded, bytes sent to the device and, in buffer mode, the items
         producers put and the seconds they waited in ``put`` since the
-        last run: ``buffer_put_items``, ``buffer_put_wait_s``) split into
+        last run: ``buffer_put_items``, ``buffer_put_wait_s``; of those
+        items, the ones admitted session by session,
+        ``buffer_put_block_items``; the blocks ``get`` copied,
+        ``buffer_get_blocks``) split into
         the stages ``ingest_slot_table``, ``ingest_get`` (with the
         buffer's ``buffer_get_wait_*`` inside it), ``ingest_route``,
         ``ingest_device_put``, ``ingest_dispatch`` and ``ingest_sync``,
@@ -275,13 +278,17 @@ class IngestPipeline:
             sp.set(batches=batches, items=items, padded=padded,
                    bytes=sent)
             if self.buffer is not None:
-                # the producers' side since the last run: items admitted
-                # and seconds waited
-                put = (self.buffer.admitted(),
-                       self.buffer.wait_seconds()["put"])
-                sp.set(buffer_put_items=put[0] - self._put_seen[0],
-                       buffer_put_wait_s=put[1] - self._put_seen[1])
-                self._put_seen = put
+                # the buffer's side since the last run: items admitted
+                # (and of them, through the block path), seconds waited
+                # in put, blocks get copied
+                seen = {"buffer_put_items": self.buffer.admitted(),
+                        "buffer_put_wait_s":
+                            self.buffer.wait_seconds()["put"],
+                        **{f"buffer_{k}": v for k, v in
+                           self.buffer.block_counts().items()}}
+                sp.set(**{k: v - self._seen.get(k, 0)
+                          for k, v in seen.items()})
+                self._seen = seen
         wall = time.perf_counter() - t0
         # telemetry happens HERE and only here: block_until_ready above is
         # the run's host-sync boundary, so draining the device ledgers now

@@ -14,6 +14,7 @@ Socket tests carry a ``timeout`` mark (enforced by pytest-timeout when
 installed) *and* socket-level timeouts inside ``SocketSource`` itself,
 so a dead socket fails fast rather than hanging CI either way.
 """
+import collections
 import threading
 
 import jax
@@ -309,6 +310,31 @@ def test_buffer_block_policy_backpressure():
         TaggedBuffer(capacity=2).get(1, timeout=0.05)
 
 
+@pytest.mark.parametrize("admission", [
+    {}, {"rate_limit": RateLimit(rate=1e-6, burst=12.0)}])
+def test_buffer_block_stall_resumes_where_it_stopped(admission):
+    """A ``block`` put that stalls on a full buffer goes on with the item
+    it stopped at once room frees up, by either admission path: every
+    item once, in order, and (with a rate limit) each item's token
+    spent once — a burst of exactly 12 admits all 12."""
+    buf = TaggedBuffer(capacity=4, policy="block", clock=lambda: 0.0,
+                       **admission)
+    sids, X = _tagged(np.random.RandomState(1), 12, [1])
+    t = threading.Thread(target=lambda: (buf.put(sids, X), buf.close()),
+                         daemon=True)
+    t.start()
+    out = []
+    while (got := buf.get(3, timeout=10.0)) is not None:
+        out.append(got)
+    t.join(timeout=10.0)
+    out_s = np.concatenate([s for s, _ in out])
+    out_x = np.concatenate([x for _, x in out])
+    assert len(out_s) == 12 and not buf.throttled_counts()
+    want = _per_session(sids, X)
+    for s, xs in _per_session(out_s, out_x).items():
+        np.testing.assert_array_equal(xs, want[s])
+
+
 def test_buffer_get_min_items_waits_for_fill():
     """A trickling producer must not hand the consumer near-empty
     batches when a fill threshold is set; close still drains the tail."""
@@ -394,6 +420,418 @@ def test_buffer_get_pads_to_fixed_shape():
     assert s.shape == (6,) and x.shape == (6, 3)
     np.testing.assert_array_equal(s[2:], [PAD_SID] * 4)
     np.testing.assert_array_equal(x[2:], 0.0)
+
+
+class _ItemBuffer:
+    """The plain reference for ``TaggedBuffer``: the same contract, one
+    item at a time — a FIFO of single rows per session, a Python turn
+    per item in ``get``, ``np.stack`` of the rows.  Single-threaded
+    (every wait in the sequences below has a zero timeout)."""
+
+    def __init__(self, capacity, policy, *, rate_limit=None, shed=None,
+                 clock=None):
+        self.capacity, self.policy = capacity, policy
+        self.rate_limit, self.shed, self._clock = rate_limit, shed, clock
+        self._q = collections.OrderedDict()
+        self._size = 0
+        self._quiesced = set()
+        self._closed = False
+        self.drops, self.sheds, self.throttled = {}, {}, {}
+        self._shed_by_policy = {}
+        self._rung = "admit"
+        self._buckets = {}
+
+    @property
+    def size(self):
+        return self._size
+
+    def depths(self):
+        return {sid: len(dq) for sid, dq in self._q.items()}
+
+    def _avail(self):
+        return self._size - sum(
+            len(self._q[s]) for s in self._quiesced if s in self._q)
+
+    def quiesce(self, sids):
+        self._quiesced.update(int(s) for s in np.asarray(sids).ravel())
+
+    def release(self, sids):
+        self._quiesced.difference_update(
+            int(s) for s in np.asarray(sids).ravel())
+
+    def close(self):
+        self._closed = True
+
+    def inject(self, sids, rows):
+        for sid, row in zip((int(s) for s in np.asarray(sids).ravel()),
+                            rows):
+            self._q.setdefault(sid, collections.deque()).append(
+                np.asarray(row, np.float32))
+            self._size += 1
+
+    def extract(self, sids):
+        out_s, out_x = [], []
+        for sid in (int(s) for s in np.asarray(sids).ravel()):
+            self._quiesced.discard(sid)
+            dq = self._q.pop(sid, None)
+            if dq:
+                out_s.extend([sid] * len(dq))
+                out_x.extend(dq)
+                self._size -= len(dq)
+        return np.asarray(out_s, np.int32), out_x
+
+    def _admit_rate(self, sid, now):
+        if self.rate_limit is None:
+            return True
+        bucket = self._buckets.get(sid)
+        if bucket is None:
+            bucket = self._buckets[sid] = TokenBucket(self.rate_limit, now)
+        return bucket.allow(now)
+
+    def _admit_shed(self, sid):
+        ok, rung = self.shed.decide(
+            size=self._size, capacity=self.capacity,
+            depth=len(self._q[sid]) if sid in self._q else 0,
+            n_live=len(self._q))
+        self._rung = rung
+        if not ok:
+            self.sheds[sid] = self.sheds.get(sid, 0) + 1
+            self._shed_by_policy[rung] = \
+                self._shed_by_policy.get(rung, 0) + 1
+        return ok
+
+    def put(self, sids, X, timeout=None):
+        sids = np.asarray(sids, np.int32).ravel()
+        X = np.asarray(X, np.float32)
+        dropped = 0
+        now = self._clock() if self.rate_limit else 0.0
+        for sid, row in zip(sids.tolist(), X):
+            if self._closed:
+                raise ValueError("put() on a closed buffer")
+            if not self._admit_rate(sid, now):
+                self.throttled[sid] = self.throttled.get(sid, 0) + 1
+                dropped += 1
+                continue
+            if self.shed is not None and not self._admit_shed(sid):
+                dropped += 1
+                continue
+            if self._size >= self.capacity:
+                if self.policy == "block":  # nothing drains: timed out
+                    raise TimeoutError("buffer full")
+                if self.policy == "drop-newest":
+                    self.drops[sid] = self.drops.get(sid, 0) + 1
+                    dropped += 1
+                    continue
+                pool = [s for s in self._q if s not in
+                        self._quiesced] or list(self._q)
+                victim = max(pool, key=lambda s: len(self._q[s]))
+                self._q[victim].popleft()
+                if not self._q[victim]:
+                    del self._q[victim]
+                self._size -= 1
+                self.drops[victim] = self.drops.get(victim, 0) + 1
+                dropped += 1
+            self._q.setdefault(sid, collections.deque()).append(row)
+            self._size += 1
+        return dropped
+
+    def get(self, max_items, *, pad_to=None, d=None, min_items=1,
+            timeout=None):
+        need = max(1, min(min_items, max_items))
+        if not (self._avail() >= need or self._closed):
+            raise TimeoutError("buffer below min_items")
+        if self._avail() == 0:
+            return None
+        out_s, out_x = [], []
+        while len(out_s) < max_items and self._q:
+            took = 0
+            for sid in list(self._q):
+                if len(out_s) >= max_items:
+                    break
+                if sid in self._quiesced:
+                    continue
+                dq = self._q[sid]
+                out_s.append(sid)
+                out_x.append(dq.popleft())
+                took += 1
+                if not dq:
+                    del self._q[sid]
+            if not took:
+                break
+        self._size -= len(out_s)
+        sids = np.asarray(out_s, np.int32)
+        X = np.stack(out_x).astype(np.float32)
+        if pad_to is not None and len(sids) < pad_to:
+            n_pad = pad_to - len(sids)
+            sids = np.concatenate(
+                [sids, np.full((n_pad,), PAD_SID, np.int32)])
+            X = np.concatenate([X, np.zeros((n_pad, X.shape[1]),
+                                            np.float32)])
+        return sids, X
+
+
+def _outcome(fn, *args, **kw):
+    """A call's result, or the type of what it raised."""
+    try:
+        return fn(*args, **kw)
+    except (TimeoutError, ValueError) as e:
+        return type(e)
+
+
+def _same_batch(got, want):
+    if want is None or isinstance(want, type):
+        assert got is want
+        return
+    (gs, gx), (ws, wx) = got, want
+    assert gs.dtype == ws.dtype and gx.dtype == wx.dtype
+    assert gs.shape == ws.shape and gx.shape == wx.shape
+    assert gs.tobytes() == ws.tobytes() and gx.tobytes() == wx.tobytes()
+
+
+def _same_state(buf, ref):
+    # every slot of the store is held by one queued item or free, once
+    if buf._rows is not None:
+        held = [buf._free[:buf._nfree]] + [q.slots() for q in buf._q.values()]
+        np.testing.assert_array_equal(np.sort(np.concatenate(held)),
+                                      np.arange(len(buf._rows)))
+    # the dicts in order: the order is the round-robin's
+    assert list(buf.depths().items()) == list(ref.depths().items())
+    assert buf.size == ref.size
+    assert buf.quiesced() == ref._quiesced
+    assert buf.drop_counts() == ref.drops
+    assert buf.shed_counts() == ref.sheds
+    assert buf.throttled_counts() == ref.throttled
+    assert buf.shed_policy_counts() == ref._shed_by_policy
+    assert buf.shed_rung() == ref._rung
+
+
+ADMISSION = {  # name -> (policy, constructor keywords)
+    "block": ("block", {}),
+    "drop-newest": ("drop-newest", {}),
+    "drop-oldest": ("drop-oldest", {}),
+    "rate-limit": ("block", {"rate_limit": RateLimit(rate=100.0,
+                                                     burst=20.0)}),
+    "shed": ("drop-newest", {"shed": (0.3, 0.7)}),
+}
+
+
+@pytest.mark.parametrize("admission", sorted(ADMISSION))
+@settings(max_examples=8, deadline=None)
+@given(st.integers(0, 2**31 - 1))
+def test_buffer_blocks_bit_equal_to_item_reference(admission, seed):
+    """Block storage and the vectorised round-robin change the cost,
+    not the result: random puts (1–4096 items, repeated tags), gets
+    (random ``max_items``/``pad_to``/``min_items``), quiesce, release,
+    extract → inject and close give the item-at-a-time reference's
+    batches bit for bit, its return values and its ledgers."""
+    rng = np.random.RandomState(seed)
+    policy, kw = ADMISSION[admission]
+    now = [0.0]
+    capacity = int(rng.choice([64, 1500, 6000]))
+    bufs = []
+    for cls in (TaggedBuffer, _ItemBuffer):
+        ckw = dict(kw)
+        if "shed" in ckw:  # one ladder each, same seed: same draws
+            lo, hi = ckw["shed"]
+            ckw["shed"] = ShedPolicy(lo=lo, hi=hi, seed=seed)
+        bufs.append(cls(capacity, policy, clock=lambda: now[0], **ckw))
+    buf, ref = bufs
+    tenants = rng.choice(100, size=rng.randint(1, 40), replace=False)
+    d = 3
+    pool = np.empty((40 * 4096, d), np.float32)
+    for step in range(40):
+        op = rng.choice(["put", "put", "put", "get", "get", "quiesce",
+                         "release", "move", "tick"])
+        if op == "put":
+            n = int(rng.choice([rng.randint(1, 64), rng.randint(1, 4097)]))
+            s = rng.choice(tenants, n).astype(np.int32)
+            # a fresh array, a row slice of one shared array, or views
+            # that are not row slices of their base
+            X = [lambda: np.empty((n, d), np.float32),
+                 lambda: pool[step * 4096:step * 4096 + n],
+                 lambda: np.empty((2 * n, d), np.float32)[::2],
+                 lambda: np.empty((n, d + 2), np.float32)[:, 1:-1],
+                 lambda: np.empty((n + 1, d), np.float32).reshape(-1)[
+                     1:1 + n * d].reshape(n, d),  # rows off by an item
+                 ][rng.randint(5)]()
+            X[:] = rng.randn(n, d)
+            X[:, 0] = step * 10_000 + np.arange(n)  # a fingerprint
+            got, want = (_outcome(b.put, s, X, timeout=0.0)
+                         for b in bufs)
+            assert got == want
+        elif op == "get":
+            m = int(rng.randint(1, 5000))
+            pad = [None, m, m + int(rng.randint(0, 300))][rng.randint(3)]
+            k = int(rng.choice([1, rng.randint(1, m + 1)]))
+            _same_batch(*(_outcome(b.get, m, pad_to=pad, d=d, min_items=k,
+                                   timeout=0.0) for b in bufs))
+        elif op in ("quiesce", "release"):
+            sids = rng.choice(tenants, rng.randint(1, 4))
+            for b in bufs:
+                getattr(b, op)(sids)
+        elif op == "move":  # a handoff: extract, then inject back
+            sids = rng.choice(tenants, rng.randint(1, 4))
+            (gs, gx), (ws, wx) = (b.extract(sids) for b in bufs)
+            assert gs.tobytes() == ws.tobytes() and len(gx) == len(wx)
+            assert all(g.tobytes() == w.tobytes() for g, w in zip(gx, wx))
+            buf.inject(gs, gx)
+            ref.inject(ws, wx)
+        else:
+            now[0] += float(rng.uniform(0.0, 0.2))
+        _same_state(buf, ref)
+    for b in bufs:
+        b.close()
+    assert _outcome(buf.put, [1], np.zeros((1, d))) is ValueError
+    while True:
+        m = int(rng.randint(1, 3000))
+        got, want = (b.get(m, pad_to=m) for b in bufs)
+        _same_batch(got, want)
+        _same_state(buf, ref)
+        if want is None:
+            break
+
+
+def test_buffer_blocks_survive_concurrent_producers():
+    """More producer threads than cores put ragged batches into a small
+    ``block`` buffer while one consumer drains it, with the interpreter
+    switching threads often: every item arrives once, each session's in
+    order, and every item went through the block path."""
+    import os
+    import sys
+    workers = (os.cpu_count() or 2) + 2
+    per, sessions = 600, 3  # items per producer; sessions per producer
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        buf = TaggedBuffer(capacity=64, policy="block")
+
+        def producer(p):
+            rng = np.random.RandomState(p)
+            sids = (p * sessions + rng.randint(0, sessions, per)).astype(
+                np.int32)
+            X = np.stack([np.full(per, p, np.float32),
+                          np.arange(per, dtype=np.float32)], axis=1)
+            lo = 0
+            while lo < per:
+                hi = min(per, lo + int(rng.randint(1, 100)))
+                buf.put(sids[lo:hi], X[lo:hi], timeout=30.0)
+                lo = hi
+
+        threads = [threading.Thread(target=producer, args=(p,), daemon=True)
+                   for p in range(workers)]
+        for t in threads:
+            t.start()
+        out_s, out_x = [], []
+        rng = np.random.RandomState(0)
+        while sum(len(s) for s in out_s) < workers * per:
+            s, x = buf.get(int(rng.randint(1, 200)), timeout=30.0)
+            out_s.append(s)
+            out_x.append(x)
+        for t in threads:
+            t.join(timeout=30.0)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    out_s, out_x = np.concatenate(out_s), np.concatenate(out_x)
+    assert buf.size == 0 and len(out_s) == workers * per
+    assert buf.block_counts()["put_block_items"] == workers * per
+    for p in range(workers):  # each producer's items once, in order
+        mine = out_x[out_x[:, 0] == p, 1]
+        np.testing.assert_array_equal(np.sort(mine), np.arange(per))
+        for s in range(p * sessions, (p + 1) * sessions):
+            seq = out_x[out_s == s, 1]
+            assert np.all(np.diff(seq) > 0)
+
+
+def _round_robin_puts(buf, puts, sliced=False, sessions=256, n=4096, d=2):
+    """``puts`` puts of ``n`` items dealt to the sessions in turn, as the
+    served benchmark's producer deals them: each put a fresh array, or
+    (``sliced``) the next row slice of one array."""
+    t = np.arange(puts * n)
+    pool = np.zeros((puts * n, d), np.float32)
+    for p in range(puts):
+        X = pool[p * n:(p + 1) * n] if sliced else np.zeros((n, d),
+                                                            np.float32)
+        buf.put((t[p * n:(p + 1) * n] % sessions).astype(np.int32), X)
+
+
+@pytest.mark.parametrize("sliced", [False, True])
+def test_buffer_block_path_counts_blocks_not_items(sliced):
+    """4096-item puts under ``block`` are queued session by session, and
+    a batch of 131072 takes one share of slots per session, whether each
+    put is a fresh array or a row slice of one array."""
+    buf = TaggedBuffer(capacity=2 * 131072, policy="block")
+    _round_robin_puts(buf, 32, sliced)
+    assert buf.block_counts() == {"put_block_items": 131072,
+                                  "get_blocks": 0}
+    sids, X = buf.get(131072)
+    assert len(sids) == 131072 and buf.size == 0
+    assert buf.block_counts()["get_blocks"] == 256
+    # the round-robin: every turn deals the 256 sessions in order
+    np.testing.assert_array_equal(sids.reshape(512, 256),
+                                  np.tile(np.arange(256), (512, 1)))
+
+
+@pytest.mark.parametrize("admission", [
+    {"rate_limit": RateLimit(rate=1e9)}, {"shed": ShedPolicy(lo=0.9, hi=0.95)}])
+def test_buffer_per_item_admission_skips_the_block_path(admission):
+    """A rate limit or a shed ladder decides item by item: nothing goes
+    through the block path, though a session's items still leave as one
+    share."""
+    buf = TaggedBuffer(capacity=8192, policy="block", **admission)
+    _round_robin_puts(buf, 1)
+    assert buf.block_counts() == {"put_block_items": 0, "get_blocks": 0}
+    assert buf.admitted() == 4096
+    buf.get(4096)
+    assert buf.block_counts()["get_blocks"] == 256
+
+
+def test_buffer_store_reuses_freed_slots():
+    """The rows a ``get`` has copied out free their slots for the next
+    puts, so the store stays at the size the buffer holds at its fullest
+    plus a batch in flight, however many items pass through."""
+    buf = TaggedBuffer(capacity=4096, policy="block")
+    rng = np.random.RandomState(0)
+    for _ in range(50):
+        s = rng.randint(0, 37, 4096).astype(np.int32)
+        X = rng.randn(4096, 4).astype(np.float32)
+        buf.put(s, X)
+        got_s, got_x = buf.get(4096)
+        assert len(got_s) == 4096 and buf.size == 0
+    assert len(buf._rows) <= 2 * 4096 and buf._nfree == len(buf._rows)
+
+
+def test_buffer_get_refuses_a_slot_outside_the_store():
+    """A queue that names a slot the store does not have makes ``get``
+    raise, where the gather would otherwise clamp it to the last row."""
+    buf = TaggedBuffer(capacity=64, policy="block")
+    buf.put(np.int32([5, 5, 6]), np.ones((3, 2), np.float32))
+    q = buf._q[5]
+    q.idx[q.head] = len(buf._rows) + 3
+    with pytest.raises(RuntimeError, match="outside the store"):
+        buf.get(3)
+
+
+def test_pipeline_run_span_carries_the_block_counters():
+    """A buffer-mode run leaves the block counters on its
+    ``ingest_run`` span, as deltas since the previous run."""
+    from repro import obs
+    rec = obs.get_recorder()
+    rec.clear()
+    pod = _pod(S=3, C=32, T=9)
+    st = _admit_all(pod, pod.init(), [1, 2, 3])
+    buf = TaggedBuffer(capacity=256, policy="block")
+    pipe = IngestPipeline(pod, buffer=buf, batch=32, min_fill=32)
+    for _ in range(2):
+        buf.put(np.tile(np.int32([1, 2, 3]), 20),
+                np.ones((60, D), np.float32))
+        st, _ = pipe.run(st, max_batches=1)
+    runs = [e["attrs"] for e in rec.events if e["name"] == "ingest_run"]
+    rec.clear()
+    assert [(a["buffer_put_items"], a["buffer_put_block_items"])
+            for a in runs] == [(60, 60), (60, 60)]
+    # 11/11/10 items of 20/20/20, then of 29/29/30: a share per session
+    assert [a["buffer_get_blocks"] for a in runs] == [3, 3]
 
 
 # ------------------------------------------------------------------- routing
