@@ -634,7 +634,7 @@ class TaggedBuffer:
     # --------------------------------------------------------------- consumer
     def get(self, max_items: int, *, pad_to: Optional[int] = None,
             timeout: Optional[float] = None, d: Optional[int] = None,
-            min_items: int = 1
+            min_items: int = 1, per_session: Optional[int] = None
             ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         """Dequeue up to ``max_items`` items, round-robin across sessions.
 
@@ -649,7 +649,11 @@ class TaggedBuffer:
         zero-row) entries to a fixed length — the shape contract of the
         jitted pod program.  ``d`` is ignored: the buffered rows give
         the width, since a batch holds at least one item (it stays for
-        the callers that pass it).
+        the callers that pass it).  ``per_session`` caps the items one
+        session gives a batch (the round-robin ends a queue's turns
+        there): the pod's per-session ``chunk``, so that a backlog left
+        in few sessions, as at the end of a stream, never overflows
+        them.  The batch then holds fewer than ``max_items`` items.
         """
         need = max(1, min(min_items, max_items))
         waiting = self._lock_wait("get")
@@ -672,8 +676,10 @@ class TaggedBuffer:
             live = [(sid, q) for sid, q in self._q.items()
                     if sid not in self._quiesced]
             who, ks, parts = [], [], []  # each session's share, in order
-            for (sid, q), k in zip(
-                    live, _round_robin([len(q) for _, q in live], max_items)):
+            depth = [len(q) for _, q in live]
+            if per_session is not None:
+                depth = [min(c, per_session) for c in depth]
+            for (sid, q), k in zip(live, _round_robin(depth, max_items)):
                 if k:
                     who.append(sid)
                     ks.append(k)

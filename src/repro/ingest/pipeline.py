@@ -9,9 +9,10 @@ host, the routing scatter, and the vmapped ``run_batched`` program.
     host   | route(i) put(i)| route(i+1) put  | route(i+2) ...  |
 
   * routing moves to host (``host_route`` — a numpy mirror of
-    ``SummarizerPod.route``, bit-equal by construction and pinned by
-    test), so the device program is ``ingest_routed``: run_batched +
-    counters only, no (N, S) id-match or scatter on its critical path;
+    ``SummarizerPod.route``, bit-equal and pinned by test; the sorted
+    table of live ids it searches is built once per run), so the
+    device program is ``ingest_routed``: run_batched + counters only,
+    no slot lookup or scatter on its critical path;
   * JAX's async dispatch provides the overlap: ``advance(i)`` returns
     as soon as the program is enqueued, and the host spends the device
     step's wall time producing, repacking and routing batch i+1, then
@@ -57,34 +58,91 @@ from .buffer import PAD_SID, TaggedBuffer
 from .sources import Source, TaggedBatch
 
 
-def host_route(sid_table: np.ndarray, active: np.ndarray, sids: np.ndarray,
-               X: np.ndarray, chunk: int
-               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Numpy mirror of ``SummarizerPod.route`` — bit-equal by construction.
+def live_table(sid_table: np.ndarray, active: np.ndarray
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """The live sessions' ids, sorted, and the slot that holds each.
 
-    (sid_table (S,), active (S,), sids (N,), X (N, d), chunk C) ->
-    (chunks (S, C, d), counts (S,), unknown (), overflow (S,)).
-    Stability of the argsort gives per-session FIFO, exactly as the
-    device scatter's stable sort does.
+    (sid_table (S,), active (S,)) -> (ids (L,), slots (L,)).  The sort
+    is stable, so a live id held by several slots lists its first slot
+    first.  Built once per ``IngestPipeline.run`` with the slot-table
+    snapshot: the table only changes through lifecycle calls.
     """
-    S, C = len(sid_table), chunk
-    N = len(sids)
+    live = np.flatnonzero(np.asarray(active, bool))
+    ids = np.asarray(sid_table, np.int32)[live]
+    order = np.argsort(ids, kind="stable")
+    return ids[order], live[order]
+
+
+def host_slots(table: Tuple[np.ndarray, np.ndarray], sids: np.ndarray,
+               sessions: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Each item's slot and its position in that slot's chunk.
+
+    (``live_table`` output, sids (N,), S) -> (slot (N,), pos (N,),
+    found (N,)).  A binary search of the sorted live ids finds an item's
+    id in O(log S); it is found when the id at the found position equals
+    the item's, and its slot is then the first live slot holding it.
+    Items with no live session (unknown ids, ``PAD_SID``, stale ids on
+    freed slots) go to the trash row S.  Positions count each slot's
+    items in stream order (a stable argsort): per-session FIFO.
+    """
+    ids, slots = table
     sids = np.asarray(sids, np.int32)
-    match = (sids[:, None] == sid_table[None, :]) & active[None, :]
-    found = match.any(axis=1)
-    slot = np.where(found, match.argmax(axis=1), S)
-    order = np.argsort(slot, kind="stable")
-    seg_start = np.searchsorted(slot[order], slot[order], side="left")
+    N = len(sids)
+    if len(ids):
+        at = np.minimum(np.searchsorted(ids, sids), len(ids) - 1)
+        found = ids[at] == sids
+        slot = np.where(found, slots[at], sessions)
+    else:
+        found = np.zeros((N,), bool)
+        slot = np.full((N,), sessions, np.int64)
+    # a stable sort of the narrowest unsigned type that holds S is a
+    # radix sort up to 16 bits: several times faster than on int64
+    order = np.argsort(slot.astype(np.min_scalar_type(sessions)),
+                       kind="stable")
+    per = np.bincount(slot, minlength=sessions + 1)
     pos = np.empty((N,), np.int64)
-    pos[order] = np.arange(N, dtype=np.int64) - seg_start
+    pos[order] = np.arange(N, dtype=np.int64) - (per.cumsum() - per)[
+        slot[order]]
+    return slot, pos, found
+
+
+def host_scatter(slot: np.ndarray, pos: np.ndarray, found: np.ndarray,
+                 sids: np.ndarray, X: np.ndarray, sessions: int, chunk: int
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Write the found items into fresh (S, C, d) chunks and count them.
+
+    -> (chunks (S, C, d), counts (S,), unknown (), overflow (S,)): an
+    item past its slot's ``chunk`` is counted in ``overflow``, an item
+    with a non-negative id and no live session in ``unknown``.
+    """
+    S, C = sessions, chunk
     keep = found & (pos < C)
     chunks = np.zeros((S, C) + X.shape[1:], X.dtype)
     chunks[slot[keep], pos[keep]] = X[keep]
     counts = np.bincount(slot[keep], minlength=S).astype(np.int32)
-    unknown = np.int32((~found & (sids >= 0)).sum())
+    unknown = np.int32((~found & (np.asarray(sids) >= 0)).sum())
     over = found & (pos >= C)
     overflow = np.bincount(slot[over], minlength=S).astype(np.int32)
     return chunks, counts, unknown, overflow
+
+
+def host_route(sid_table: np.ndarray, active: np.ndarray, sids: np.ndarray,
+               X: np.ndarray, chunk: int
+               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Numpy mirror of ``SummarizerPod.route``, bit-equal to it.
+
+    (sid_table (S,), active (S,), sids (N,), X (N, d), chunk C) ->
+    (chunks (S, C, d), counts (S,), unknown (), overflow (S,)).
+    ``live_table`` + ``host_slots`` + ``host_scatter``: an item's slot
+    is found by a binary search of the live ids, O(N log S), and is the
+    one the plain (N, S) match ``(sids[:, None] == sid_table) & active``
+    picks (its first live slot, else the trash row), which the tests
+    keep as the reference.  The stable argsort gives per-session FIFO,
+    exactly as the device scatter's stable sort does.
+    """
+    S = len(sid_table)
+    slot, pos, found = host_slots(live_table(sid_table, active), sids, S)
+    return host_scatter(slot, pos, found, sids, X, S, chunk)
 
 
 @hashable_lru(maxsize=32)
@@ -98,10 +156,13 @@ class IngestPipeline:
 
     ``batch`` is the fixed device batch size: ragged source batches are
     repacked (and the final partial batch PAD_SID-padded) so the jitted
-    step compiles exactly once.  Size it so that no session exceeds the
-    pod's per-session routing capacity ``chunk`` within one batch —
-    ``batch <= pod.chunk`` is the safe default for a single-session
-    worst case (everything else is counted overflow, never corrupted).
+    step compiles exactly once.  In buffer mode a batch takes at most
+    the pod's per-session routing capacity ``chunk`` from one session
+    (``TaggedBuffer.get``'s ``per_session``), so nothing overflows.  In
+    source mode size it so that no session exceeds ``chunk`` within one
+    batch — ``batch <= pod.chunk`` is the safe default for a
+    single-session worst case (everything else is counted overflow,
+    never corrupted).
     """
 
     pod: "object"  # SummarizerPod (kept loose to avoid an import cycle)
@@ -168,7 +229,8 @@ class IngestPipeline:
             while True:
                 got = self.buffer.get(B, pad_to=B, d=d,
                                       timeout=self.get_timeout,
-                                      min_items=self.min_fill)
+                                      min_items=self.min_fill,
+                                      per_session=self.pod.chunk)
                 if got is None:
                     return
                 yield got
@@ -224,7 +286,8 @@ class IngestPipeline:
         ``buffer_put_block_items``; the blocks ``get`` copied,
         ``buffer_get_blocks``) split into
         the stages ``ingest_slot_table``, ``ingest_get`` (with the
-        buffer's ``buffer_get_wait_*`` inside it), ``ingest_route``,
+        buffer's ``buffer_get_wait_*`` inside it), ``ingest_route``
+        (split into ``ingest_slot_lookup`` and ``ingest_scatter``),
         ``ingest_device_put``, ``ingest_dispatch`` and ``ingest_sync``,
         each an ``<stage>_s`` attribute and a profiler TraceMe.
         """
@@ -239,8 +302,9 @@ class IngestPipeline:
         # batch, and none of them syncs the device
         with obs.span("ingest_run", pod=str(self.pod_id)) as sp:
             with obs.stage("ingest_slot_table"):  # waits for the last step
-                sid_table = np.asarray(state.sid)
-                active = np.asarray(state.active)
+                sid = np.asarray(state.sid)
+                table = live_table(sid, np.asarray(state.active))
+            S = len(sid)
             while max_batches is None or batches < max_batches:
                 try:
                     with obs.stage("ingest_get"):
@@ -257,7 +321,11 @@ class IngestPipeline:
                         self._gen = None
                     break
                 with obs.stage("ingest_route"):
-                    routed = host_route(sid_table, active, sids, X, C)
+                    with obs.stage("ingest_slot_lookup"):
+                        slot, pos, found = host_slots(table, sids, S)
+                    with obs.stage("ingest_scatter"):
+                        routed = host_scatter(slot, pos, found, sids, X,
+                                              S, C)
                 with obs.stage("ingest_device_put"):
                     args = [jax.device_put(a) for a in routed]
                 with obs.stage("ingest_dispatch"):

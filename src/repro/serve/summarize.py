@@ -19,10 +19,10 @@ program:
                 its bit-equal ``vmap(algo.run_batched)`` reference
                 elsewhere — selected by ``podstep_backend`` /
                 ``REPRO_PODSTEP_BACKEND`` (DESIGN.md §11);
-  * lifecycle — admit into a free slot, evict, and drift-triggered
-                reset all reuse slots via masked row-selects
-                (``tree_select``), so the compiled program never sees a
-                shape change and nothing retraces;
+  * lifecycle — admit writes one free slot's rows at a dynamic index;
+                evict and drift-triggered reset reuse slots via masked
+                row-selects (``tree_select``), so the compiled program
+                never sees a shape change and nothing retraces;
   * scale-out — ``make_sharded_update`` shard_maps the same program
                 over the mesh 'data' axis: P shards x S slots = P*S
                 sessions per pod, still one SPMD program (the dry-run
@@ -206,13 +206,6 @@ class SummarizerPod:
                                lengthscale=spec.lengthscale,
                                kernel_kind=spec.kernel_kind)
 
-    def _fresh_rows(self, hyper: Optional[HyperParams]):
-        """(S,)-stacked freshly-initialized algorithm rows, all carrying
-        ``hyper`` (or the pod default when ``None``)."""
-        one = (self.algo.init() if hyper is None
-               else self.algo.init(hyper))
-        return stack_states(one, self.sessions)
-
     def admit(self, state: PodState, session_id: Array, spec=None
               ) -> Tuple[PodState, Array, Array]:
         """Admit a session into the first free slot.
@@ -223,8 +216,9 @@ class SummarizerPod:
         untouched instead of occupying a phantom second slot that
         ``route`` would never feed and ``evict`` would free together
         with the real one.  Otherwise the slot's algorithm state is
-        re-initialized, so a recycled slot starts fresh — no recompile,
-        just a masked select.
+        re-initialized, so a recycled slot starts fresh — no recompile:
+        the chosen slot's rows are written at a dynamic index, O(one
+        session) whatever S.
 
         ``spec`` selects the tenant's hyperparameters (``SessionSpec`` or
         pre-built ``HyperParams``; default = the pod's own spec): the
@@ -254,21 +248,26 @@ class SummarizerPod:
         # negative ids are reserved (-1 marks free slots and queue
         # padding); admitting one would route every padding item into it
         ok = (sess >= 0) & jnp.where(present, spec_ok, jnp.any(free))
-        hot = (jnp.arange(self.sessions) == slot) & ok & ~present
-        z = jnp.zeros((self.sessions,), jnp.int32)
+        write = ok & ~present
+        fresh = self.algo.init() if hyper is None else self.algo.init(hyper)
+
+        def put(rows, row):  # the chosen slot's row, kept when not written
+            return rows.at[slot].set(jnp.where(write, row, rows[slot]))
+
+        z = jnp.int32(0)
         state = dataclasses.replace(
             state,
-            algo=tree_select(hot, self._fresh_rows(hyper), state.algo),
-            sid=jnp.where(hot, jnp.asarray(session_id, jnp.int32), state.sid),
-            active=state.active | hot,
-            items=jnp.where(hot, z, state.items),
-            accepts=jnp.where(hot, z, state.accepts),
-            win_items=jnp.where(hot, z, state.win_items),
-            win_accepts=jnp.where(hot, z, state.win_accepts),
-            resets=jnp.where(hot, z, state.resets),
+            algo=jax.tree_util.tree_map(put, state.algo, fresh),
+            sid=put(state.sid, sess),
+            active=put(state.active, True),
+            items=put(state.items, z),
+            accepts=put(state.accepts, z),
+            win_items=put(state.win_items, z),
+            win_accepts=put(state.win_accepts, z),
+            resets=put(state.resets, z),
             # session-scoped: a recycled slot starts with a clean overflow
             # ledger; drops_unknown is pod-scoped and survives admits
-            drops_overflow=jnp.where(hot, z, state.drops_overflow),
+            drops_overflow=put(state.drops_overflow, z),
         )
         return state, slot, ok
 
@@ -348,8 +347,11 @@ class SummarizerPod:
         sids (N,) int32 session ids (-1 = queue padding), X (N, d)
         -> (chunks (S, C, d), counts (S,), unknown (), overflow (S,)).
 
-        Fixed-shape throughout: each item resolves to its slot (items
-        with no live session fall into a trash row), takes the next
+        Fixed-shape throughout: each item resolves to its slot by a
+        search of the slot table sorted by (id, free, slot), O((N + S)
+        log(N + S)), which finds the first live slot holding the id (the
+        slot the plain (N, S) match's argmax picks; items with no live
+        session fall into a trash row), takes the next
         position in that slot's buffer (stable sort + searchsorted — no
         O(N^2) pairwise ranks), and one scatter writes all of them.
         The two drop causes are counted separately: ``unknown`` (no live
@@ -365,9 +367,15 @@ class SummarizerPod:
         """
         S, C = self.sessions, self.chunk
         N = sids.shape[0]
-        match = (sids[:, None] == state.sid[None, :]) & state.active[None, :]
-        found = jnp.any(match, axis=1)
-        slot = jnp.where(found, jnp.argmax(match, axis=1), S)  # S = trash
+        ids, free, slots = jax.lax.sort(
+            (state.sid, (~state.active).astype(jnp.int32),
+             jnp.arange(S, dtype=jnp.int32)), num_keys=3)
+        live = jnp.where(free == 0, slots, S)  # S = trash: a stale id
+        # method="sort": one sort of the N + S keys; on a TPU v5e the
+        # default scan's log S rounds of N-wide gathers cost ~3x as much
+        at = jnp.minimum(jnp.searchsorted(ids, sids, method="sort"), S - 1)
+        slot = jnp.where(ids[at] == sids, live[at], S)
+        found = slot < S
 
         order = jnp.argsort(slot)  # stable: preserves stream order per slot
         sorted_slot = slot[order]
@@ -382,8 +390,7 @@ class SummarizerPod:
         chunks = jnp.zeros((S + 1, C) + X.shape[1:], X.dtype)
         chunks = chunks.at[slot_f, pos_f].set(X)[:S]
         counts = jnp.bincount(slot_f, length=S).astype(jnp.int32)
-        # (bincount drops the out-of-range trash index S — no (N, S)
-        # equality matrix on the hot path)
+        # (bincount drops the out-of-range trash index S)
         unknown = jnp.sum(~found & (sids >= 0)).astype(jnp.int32)
         over_slot = jnp.where(found & (pos >= C), slot, S)
         overflow = jnp.bincount(over_slot, length=S).astype(jnp.int32)

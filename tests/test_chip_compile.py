@@ -107,3 +107,50 @@ def test_gain_kernel_compiles_under_vmap(one_chip):
         *a, a=1.0, block_b=DEFAULT_BLOCK_B)))
     args = _gain_args(one_chip, 1024, 128, 256, batch=(64,))
     _check(gains.lower(*args).compile())
+
+
+def test_many_tenant_programs_compile(one_chip):
+    """The ts4096 pod (S=4096 sessions of chunk 64, K=100, d=256): the
+    fused step's wrapper, the device route of a 131072-item batch (the
+    sorted slot table and its binary search) and the one-slot admit,
+    scanned over every session as the benchmark admits them."""
+    from repro.serve import SummarizerPod
+
+    algo = make(SessionSpec(algo="threesieves", K=100, d=256, T=2500,
+                            eps=0.01, lengthscale=0.0625, backend="jnp"))
+    S, C, N = 4096, 64, 131072
+    pod = SummarizerPod(algo=algo, sessions=S, chunk=C)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+
+    state = on_chip(pod.abstract_state())
+    chunks = jax.ShapeDtypeStruct((S, C, 256), jnp.float32, sharding=one_chip)
+    counts = jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)
+    _check(_pod_step_fused.lower(algo, state.algo, chunks, counts,
+                                 use_pallas=True, interpret=False).compile())
+
+    def fits(compiled):
+        mem = compiled.memory_analysis()
+        assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+                ) <= HBM_BYTES
+
+    sids = jax.ShapeDtypeStruct((N,), jnp.int32, sharding=one_chip)
+    X = jax.ShapeDtypeStruct((N, 256), jnp.float32, sharding=one_chip)
+    fits(jax.jit(pod.route).lower(state, sids, X).compile())
+
+    def admit_all(state, sids, rows):
+        def body(st, xs):
+            st, slot, ok = pod.admit(st, xs[0], spec=xs[1])
+            return st, (slot, ok)
+
+        return jax.lax.scan(body, state, (sids, rows))
+
+    hp = algo.hyper(K=100, T=2500, eps=0.01, lengthscale=0.0625)
+    rows = on_chip(jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda l: jnp.broadcast_to(l, (S,) + jnp.shape(l)), hp)))
+    fits(jax.jit(admit_all).lower(state, jax.ShapeDtypeStruct(
+        (S,), jnp.int32, sharding=one_chip), rows).compile())

@@ -495,7 +495,9 @@ def test_pod_drain_metrics_delegates(fresh_obs):
 
 # ------------------------------------------------- stages on the served path
 STAGES = ("ingest_slot_table", "ingest_get", "ingest_route",
-          "ingest_device_put", "ingest_dispatch", "ingest_sync")
+          "ingest_device_put", "ingest_dispatch", "ingest_sync",
+          "ingest_slot_lookup", "ingest_scatter")
+ROUTE_SPLIT = ("ingest_slot_lookup", "ingest_scatter")  # inside the route
 
 
 def _source_run(n_batches=4, pod_id="5"):
@@ -553,7 +555,8 @@ def test_stage_is_noop_under_trace(fresh_obs):
 
 def test_ingest_run_span_holds_its_stages(fresh_obs):
     """One ``ingest_run`` event per run, whatever the batch count; its
-    stage attributes are ≥ 0 and sum to at most its duration."""
+    stage attributes are ≥ 0, the outer ones sum to at most its duration
+    and the route's split to at most the route."""
     _, rec = fresh_obs
     _, stats = _source_run(n_batches=4)
     (ev,) = rec.events
@@ -567,7 +570,9 @@ def test_ingest_run_span_holds_its_stages(fresh_obs):
     stages = {k[:-2]: v for k, v in a.items() if k.endswith("_s")}
     assert set(stages) == set(STAGES)
     assert all(v >= 0 for v in stages.values())
-    assert sum(stages.values()) <= ev["dur_s"]
+    assert sum(v for k, v in stages.items()
+               if k not in ROUTE_SPLIT) <= ev["dur_s"]
+    assert sum(stages[k] for k in ROUTE_SPLIT) <= stages["ingest_route"]
 
 
 def test_stages_are_tracemes_inside_ingest_run(fresh_obs, tmp_path):
@@ -591,6 +596,11 @@ def test_stages_are_tracemes_inside_ingest_run(fresh_obs, tmp_path):
     inside = [n for n, s, e in line if s >= run[1] and e <= run[2]]
     assert set(STAGES) <= set(inside)
     assert inside.count("ingest_route") == 2
+    routes = [(s, e) for n, s, e in line if n == "ingest_route"]
+    for name in ROUTE_SPLIT:
+        spans = [(s, e) for n, s, e in line if n == name]
+        assert len(spans) == 2 and all(
+            any(rs <= s and e <= re for rs, re in routes) for s, e in spans)
 
 
 def test_buffer_waits_reach_the_drain(fresh_obs):
