@@ -6,8 +6,12 @@ per-session token-bucket rate limits and the watermark shedding ladder,
 ``repro.ingest.shedding``), and IngestPipeline double-buffers host
 routing against the device step:
 
-    Source -> TaggedBuffer -> host_route -> device_put -> ingest_routed
+    Source -> TaggedBuffer -> chunks -> device_put -> ingest_routed
     (producer threads)        (overlapped with the running pod program)
+
+The chunks are copied straight from the buffer's store, one share per
+session; a pipeline fed by a ``Source`` directly routes its batches
+with ``host_route``.
 
 Above that sits the fleet edge: ``PodRouter`` fans one tagged ingress
 across pod shards, and ``repro.ingest.pubsub`` puts a partitioned,

@@ -57,10 +57,14 @@ loop, and copies the rows it admitted in one step at its end.  ``get``
 computes the round-robin per session, not per item: ``r`` full turns,
 found from the sorted depths, give each drainable session ``min(depth,
 r)`` items, the partial turn one more to the first sessions in order
-that still hold items; it takes each session's share of slots under the
-lock, then outside it puts the rows in turn order (a stable sort by
-turn) into the batch with one ``np.take`` and gives the slots back.
-The result is the item-at-a-time loop's, bit for bit.
+that still hold items.  ``lease`` takes each session's share of slots
+under the lock and leaves the rows in the store; ``get`` then, outside
+the lock, puts them in turn order (a stable sort by turn) into the batch
+with one ``np.take`` and gives the slots back.  The result is the
+item-at-a-time loop's, bit for bit.  A consumer that lays the rows out
+itself takes the ``Lease`` instead: the pipeline copies each share
+straight into its session's chunk of the device batch
+(``repro.ingest.pipeline``), and releases the slots after the copy.
 
 Quiesce (the autoscaler's handoff primitive, DESIGN.md §10): a session
 marked ``quiesce``d keeps *receiving* items but ``get`` stops draining
@@ -632,29 +636,15 @@ class TaggedBuffer:
             self._not_full.notify_all()
 
     # --------------------------------------------------------------- consumer
-    def get(self, max_items: int, *, pad_to: Optional[int] = None,
-            timeout: Optional[float] = None, d: Optional[int] = None,
-            min_items: int = 1, per_session: Optional[int] = None
-            ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-        """Dequeue up to ``max_items`` items, round-robin across sessions.
-
-        Blocks until at least ``min_items`` are available (or the buffer
-        is closed — then drains what is left, however little, and
-        finally returns ``None``, the end-of-stream sentinel).  A
-        ``min_items`` near the device batch size keeps a fast consumer
-        from burning full jitted steps on near-all-padding batches when
-        the producer trickles; the default of 1 favors latency.
-        ``timeout`` raises ``TimeoutError`` on an open-but-underfilled
-        buffer.  ``pad_to`` right-pads the batch with (PAD_SID,
-        zero-row) entries to a fixed length — the shape contract of the
-        jitted pod program.  ``d`` is ignored: the buffered rows give
-        the width, since a batch holds at least one item (it stays for
-        the callers that pass it).  ``per_session`` caps the items one
-        session gives a batch (the round-robin ends a queue's turns
-        there): the pod's per-session ``chunk``, so that a backlog left
-        in few sessions, as at the end of a stream, never overflows
-        them.  The batch then holds fewer than ``max_items`` items.
-        """
+    def lease(self, max_items: int, *, timeout: Optional[float] = None,
+              min_items: int = 1, per_session: Optional[int] = None
+              ) -> Optional["Lease"]:
+        """Take up to ``max_items`` items off the queues, round-robin across
+        sessions, and leave their rows in the store: -> a ``Lease`` of the
+        sessions' shares, or ``None`` once the buffer is closed and
+        drained.  The consumer step under ``get`` (which see for
+        ``timeout``, ``min_items`` and ``per_session``); a caller that
+        copies the rows itself releases the lease when done."""
         need = max(1, min(min_items, max_items))
         waiting = self._lock_wait("get")
         with self._lock:
@@ -687,31 +677,101 @@ class TaggedBuffer:
                     if not q:
                         del self._q[sid]
             at = np.concatenate(parts)  # their slots, session by session
-            store = self._rows  # a put that grows it leaves these rows
-            n = len(at)
-            self._size -= n
+            # a put that grows the store leaves these rows in this array
+            lease = Lease(self, np.array(who, np.int32),
+                          np.array(ks, np.int64), at, self._rows)
+            self._size -= len(at)
             self._get_blocks += len(parts)
             self._not_full.notify_all()
-        try:
+        # as unsigned, a negative slot is out of range too
+        if at.view(np.uint64).max() >= len(lease.store):
+            lease.release()
+            raise RuntimeError("TaggedBuffer: a queued slot lies outside "
+                               "the store")
+        return lease
+
+    def _give_back(self, slots: np.ndarray) -> None:
+        """Free a lease's slots, in store order (``_release``)."""
+        # nearly sorted already: the puts' order
+        slots = np.sort(slots, kind="stable")
+        with self._lock:
+            self._release(slots)
+
+    def get(self, max_items: int, *, pad_to: Optional[int] = None,
+            timeout: Optional[float] = None, d: Optional[int] = None,
+            min_items: int = 1, per_session: Optional[int] = None
+            ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """Dequeue up to ``max_items`` items, round-robin across sessions.
+
+        Blocks until at least ``min_items`` are available (or the buffer
+        is closed — then drains what is left, however little, and
+        finally returns ``None``, the end-of-stream sentinel).  A
+        ``min_items`` near the device batch size keeps a fast consumer
+        from burning full jitted steps on near-all-padding batches when
+        the producer trickles; the default of 1 favors latency.
+        ``timeout`` raises ``TimeoutError`` on an open-but-underfilled
+        buffer.  ``pad_to`` right-pads the batch with (PAD_SID,
+        zero-row) entries to a fixed length — the shape contract of the
+        jitted pod program.  ``d`` is ignored: the buffered rows give
+        the width, since a batch holds at least one item (it stays for
+        the callers that pass it).  ``per_session`` caps the items one
+        session gives a batch (the round-robin ends a queue's turns
+        there): the pod's per-session ``chunk``, so that a backlog left
+        in few sessions, as at the end of a stream, never overflows
+        them.  The batch then holds fewer than ``max_items`` items.
+        """
+        lease = self.lease(max_items, timeout=timeout, min_items=min_items,
+                           per_session=per_session)
+        if lease is None:
+            return None
+        with lease:  # the slots are free once their rows are copied out
             # a session's k-th item of this batch goes out in turn k: a
             # stable sort by turn gives the round-robin order
-            take = np.array(ks)
+            take, n = lease.counts, lease.items
             turn = np.arange(n) - (take.cumsum() - take).repeat(take)
             order = turn.argsort(kind="stable")
-            # as unsigned, a negative slot is out of range too
-            if at.view(np.uint64).max() >= len(store):
-                raise RuntimeError("TaggedBuffer: a queued slot lies "
-                                   "outside the store")
             rows = max(n, pad_to or 0)
             X = (np.zeros if rows > n else np.empty)(
-                (rows, *store.shape[1:]), np.float32)
-            at = at[order]
-            store.take(at, axis=0, out=X[:n], mode="clip")
-            at.sort(kind="stable")  # nearly sorted: the puts' order
-        finally:  # the slots are free once their rows are copied out
-            with self._lock:
-                self._release(at)
+                (rows, *lease.store.shape[1:]), np.float32)
+            lease.store.take(lease.slots[order], axis=0, out=X[:n],
+                             mode="clip")
         sids = np.empty(rows, np.int32)
-        sids[:n] = np.array(who, np.int32).repeat(take)[order]
+        sids[:n] = lease.sids.repeat(take)[order]
         sids[n:] = PAD_SID
         return sids, X
+
+
+class Lease:
+    """One batch's shares, off their queues, their rows still in the store.
+
+    ``sids`` (m,) int32 the sessions in round-robin order, ``counts`` (m,)
+    the items each gives, ``slots`` (n,) their store slots, session by
+    session and each session's oldest first, ``store`` the array the
+    slots index (a put that doubles the store meanwhile leaves it
+    valid).  ``release`` (or leaving a ``with`` block) frees the slots
+    once their rows are copied out; until then no put reuses them.
+    """
+
+    __slots__ = ("sids", "counts", "slots", "store", "_buffer")
+
+    def __init__(self, buffer: TaggedBuffer, sids: np.ndarray,
+                 counts: np.ndarray, slots: np.ndarray, store: np.ndarray):
+        self.sids, self.counts, self.slots = sids, counts, slots
+        self.store = store
+        self._buffer: Optional[TaggedBuffer] = buffer
+
+    @property
+    def items(self) -> int:
+        return len(self.slots)
+
+    def release(self) -> None:
+        """Give the slots back to the buffer; a second call does nothing."""
+        buffer, self._buffer = self._buffer, None
+        if buffer is not None:
+            buffer._give_back(self.slots)
+
+    def __enter__(self) -> "Lease":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.release()

@@ -8,11 +8,20 @@ host, the routing scatter, and the vmapped ``run_batched`` program.
     device |  advance(i-1)  |   advance(i)    |  advance(i+1)  |
     host   | route(i) put(i)| route(i+1) put  | route(i+2) ...  |
 
-  * routing moves to host (``host_route`` — a numpy mirror of
-    ``SummarizerPod.route``, bit-equal and pinned by test; the sorted
-    table of live ids it searches is built once per run), so the
-    device program is ``ingest_routed``: run_batched + counters only,
-    no slot lookup or scatter on its critical path;
+  * routing moves to host, so the device program is
+    ``ingest_routed``: run_batched + counters only, no slot lookup or
+    scatter on its critical path.  The sorted table of live ids is
+    built once per run.  In buffer mode a batch is one copy:
+    ``TaggedBuffer.lease`` hands over each session's share (its store
+    slots, FIFO), ``share_slots`` finds the share's slot once, and
+    ``fill_chunks`` copies the rows from the store straight into the
+    next of two reused (S, C, d) host arrays (``_ChunkRing``), zeroing
+    only the rows that array held last time and not now; an array is
+    refilled only once the step that read it, two batches back, has
+    ended, which on the chip it long has.  In source
+    mode the repacked batch goes through ``host_route`` (a numpy
+    mirror of ``SummarizerPod.route``).  Both give the chunks
+    ``SummarizerPod.route`` would, bit for bit (pinned by tests);
   * JAX's async dispatch provides the overlap: ``advance(i)`` returns
     as soon as the program is enqueued, and the host spends the device
     step's wall time producing, repacking and routing batch i+1, then
@@ -73,28 +82,36 @@ def live_table(sid_table: np.ndarray, active: np.ndarray
     return ids[order], live[order]
 
 
+def share_slots(table: Tuple[np.ndarray, np.ndarray], sids: np.ndarray,
+                sessions: int) -> np.ndarray:
+    """The slot of each id: (``live_table`` output, sids (N,), S) -> (N,).
+
+    A binary search of the sorted live ids finds an id in O(log S); it is
+    found when the id at the found position equals it, and its slot is
+    then the first live slot holding it.  An id with no live session
+    (unknown, ``PAD_SID``, stale on a freed slot) gets the trash row S.
+    """
+    ids, slots = table
+    sids = np.asarray(sids, np.int32)
+    if not len(ids):
+        return np.full((len(sids),), sessions, np.int64)
+    at = np.minimum(np.searchsorted(ids, sids), len(ids) - 1)
+    return np.where(ids[at] == sids, slots[at], sessions)
+
+
 def host_slots(table: Tuple[np.ndarray, np.ndarray], sids: np.ndarray,
                sessions: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Each item's slot and its position in that slot's chunk.
 
     (``live_table`` output, sids (N,), S) -> (slot (N,), pos (N,),
-    found (N,)).  A binary search of the sorted live ids finds an item's
-    id in O(log S); it is found when the id at the found position equals
-    the item's, and its slot is then the first live slot holding it.
-    Items with no live session (unknown ids, ``PAD_SID``, stale ids on
-    freed slots) go to the trash row S.  Positions count each slot's
-    items in stream order (a stable argsort): per-session FIFO.
+    found (N,)): ``share_slots`` per item, and items with no live
+    session go to the trash row S.  Positions count each slot's items in
+    stream order (a stable argsort): per-session FIFO.
     """
-    ids, slots = table
     sids = np.asarray(sids, np.int32)
     N = len(sids)
-    if len(ids):
-        at = np.minimum(np.searchsorted(ids, sids), len(ids) - 1)
-        found = ids[at] == sids
-        slot = np.where(found, slots[at], sessions)
-    else:
-        found = np.zeros((N,), bool)
-        slot = np.full((N,), sessions, np.int64)
+    slot = share_slots(table, sids, sessions)
+    found = slot < sessions
     # a stable sort of the narrowest unsigned type that holds S is a
     # radix sort up to 16 bits: several times faster than on int64
     order = np.argsort(slot.astype(np.min_scalar_type(sessions)),
@@ -145,6 +162,98 @@ def host_route(sid_table: np.ndarray, active: np.ndarray, sids: np.ndarray,
     return host_scatter(slot, pos, found, sids, X, S, chunk)
 
 
+# the copy stages its rows through a block of this size, which stays in
+# cache between the gather and the scatter
+_BLOCK_BYTES = 1 << 19
+
+
+def _ranges(starts: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """The ranges ``[starts[i], starts[i] + lengths[i])``, concatenated."""
+    ends = lengths.cumsum()
+    n = int(ends[-1]) if len(ends) else 0
+    return np.repeat(starts - (ends - lengths), lengths) + np.arange(n)
+
+
+def fill_chunks(lease, slot: np.ndarray, chunks: np.ndarray,
+                held: np.ndarray
+                ) -> Tuple[np.ndarray, np.int32, np.ndarray, int]:
+    """Copy a ``TaggedBuffer.lease``'s shares straight into their slots'
+    chunks: ``host_route`` of the same batch, with no packed batch.
+
+    (lease, slot (m,) of each share from ``share_slots``, chunks
+    (S, C, d) as its last fill left it, held (S,) the items each slot
+    held then) -> (counts (S,), unknown (), overflow (S,), rows zeroed).
+    A share's j-th item goes to row j of its slot's chunk, for j < C;
+    the rest count in ``overflow``, and a share with no live slot counts
+    in ``unknown`` if its id is not negative.  The rows the last fill
+    held and this one does not are zeroed first, and ``held`` becomes
+    ``counts``: so ``chunks`` ends bit-equal to ``host_route``'s, at a
+    cost in items, not in S·C.  Each kept row is copied once, from the
+    store to its chunk, through a cache-sized block.
+    """
+    S, C = chunks.shape[:2]
+    rows = chunks.reshape((S * C,) + chunks.shape[2:])
+    k = lease.counts
+    found = slot < S
+    kept = np.where(found, np.minimum(k, C), 0)
+    counts = np.zeros((S,), np.int32)
+    counts[slot[found]] = kept[found]  # one share per session, so per slot
+    overflow = np.zeros((S,), np.int32)
+    overflow[slot[found]] = (k - kept)[found]
+    unknown = np.int32(k[~found & (lease.sids >= 0)].sum())
+
+    stale = np.maximum(held - counts, 0)
+    zeroed = int(stale.sum())
+    if zeroed:
+        rows[_ranges(np.arange(S) * C + counts, stale)] = 0
+    held[:] = counts  # a copy cut short leaves rows below counts only
+
+    src = lease.slots
+    if (kept < k).any():
+        src = src[_ranges(k.cumsum() - k, kept)]
+    dst = _ranges(slot * C, kept)
+    step = max(1, _BLOCK_BYTES // max(1, rows[:1].nbytes))
+    block = np.empty((min(step, len(src)),) + rows.shape[1:], rows.dtype)
+    for lo in range(0, len(src), step):
+        part = block[:len(src[lo:lo + step])]
+        lease.store.take(src[lo:lo + step], axis=0, out=part, mode="clip")
+        rows[dst[lo:lo + step]] = part
+    return counts, unknown, overflow, zeroed
+
+
+class _ChunkRing:
+    """The host chunk arrays a buffer-mode pipeline fills in turn.
+
+    Two, zeroed once: the host fills one while the device step reads
+    the other.  Each keeps the items its slots held at its last fill
+    (``fill_chunks``), and an output of the step that read it, which
+    ``next`` waits on before the array is filled again: a transfer may
+    still read the host array (and on the CPU the device array may be
+    it) until that step has run.
+    """
+
+    def __init__(self, shape: tuple, dtype):
+        self.shape, self.dtype = shape, dtype
+        self.chunks = [np.zeros(shape, dtype) for _ in range(2)]
+        self.held = [np.zeros(shape[:1], np.int64) for _ in range(2)]
+        self.reader = [None, None]
+        self.turn = 0
+
+    def next(self) -> Tuple[np.ndarray, np.ndarray]:
+        """The array to fill next and its held counts, once free."""
+        i = self.turn
+        if self.reader[i] is not None:
+            jax.block_until_ready(self.reader[i])
+            self.reader[i] = None
+        return self.chunks[i], self.held[i]
+
+    def read_by(self, out) -> None:
+        """Record the step that reads the array ``next`` gave; the next
+        call gives the other one."""
+        self.reader[self.turn] = out
+        self.turn ^= 1
+
+
 @hashable_lru(maxsize=32)
 def _advance_for(pod, donate):
     return jax.jit(pod.ingest_routed, donate_argnums=donate)
@@ -188,6 +297,7 @@ class IngestPipeline:
                 "exactly one of source= or buffer= must be given")
         self._gen: Optional[Iterator[TaggedBatch]] = None
         self._advance = None
+        self._ring: Optional[_ChunkRing] = None  # buffer mode's chunks
         self._feeders = []
         self._feed_exc: Optional[BaseException] = None
         self._seen: dict = {}  # the buffer's counters at the last run
@@ -221,19 +331,10 @@ class IngestPipeline:
         return t
 
     def _fixed_batches(self) -> Iterator[TaggedBatch]:
-        """Repack ragged tagged batches into exactly-``batch``-sized ones
-        (last one padded); per-session FIFO is order-preserving here."""
+        """Repack the source's ragged tagged batches into
+        exactly-``batch``-sized ones (last one padded); per-session FIFO
+        is order-preserving here."""
         B = self.batch
-        d = self.pod.algo.f.d
-        if self.buffer is not None:
-            while True:
-                got = self.buffer.get(B, pad_to=B, d=d,
-                                      timeout=self.get_timeout,
-                                      min_items=self.min_fill,
-                                      per_session=self.pod.chunk)
-                if got is None:
-                    return
-                yield got
         stash: list = []
         count = 0
         for sids, X in self.source:
@@ -266,6 +367,50 @@ class IngestPipeline:
             self._advance = _advance_for(self.pod, donate)
         return self._advance
 
+    def _direct_batch(self, table, S):
+        """Buffer mode: lease the next batch's shares and copy them
+        straight from the buffer's store into the next ring array.
+        -> (routed, items, padded, items copied, rows zeroed) or None."""
+        with obs.stage("ingest_get"):
+            lease = self.buffer.lease(self.batch, timeout=self.get_timeout,
+                                      min_items=self.min_fill,
+                                      per_session=self.pod.chunk)
+        if lease is None:
+            return None
+        with lease, obs.stage("ingest_route"):
+            with obs.stage("ingest_slot_lookup"):
+                slot = share_slots(table, lease.sids, S)
+            with obs.stage("ingest_scatter"):
+                shape = (S, self.pod.chunk) + lease.store.shape[1:]
+                ring = self._ring
+                if ring is None or (ring.shape, ring.dtype) != (
+                        shape, lease.store.dtype):
+                    ring = self._ring = _ChunkRing(shape, lease.store.dtype)
+                chunks, held = ring.next()
+                counts, unknown, overflow, zeroed = fill_chunks(
+                    lease, slot, chunks, held)
+                lease.release()
+        n = lease.items
+        return ((chunks, counts, unknown, overflow), n, self.batch - n,
+                int(counts.sum()), zeroed)
+
+    def _routed_batch(self, table, S):
+        """Source mode: the next repacked batch, through ``host_route``'s
+        two halves.  -> as ``_direct_batch``."""
+        with obs.stage("ingest_get"):
+            got = next(self._gen, None)
+        if got is None:
+            return None
+        sids, X = got
+        with obs.stage("ingest_route"):
+            with obs.stage("ingest_slot_lookup"):
+                slot, pos, found = host_slots(table, sids, S)
+            with obs.stage("ingest_scatter"):
+                routed = host_scatter(slot, pos, found, sids, X, S,
+                                      self.pod.chunk)
+        n_pad = int((sids == PAD_SID).sum())
+        return routed, len(sids) - n_pad, n_pad, 0, 0
+
     def run(self, state, *, max_batches: Optional[int] = None):
         """Ingest up to ``max_batches`` device batches (None = until the
         feed ends); resumable — the feed position persists across calls.
@@ -278,24 +423,35 @@ class IngestPipeline:
         failure recorded by a ``feed_from`` thread re-raises from here:
         a broken wire must never look like a clean end-of-stream.
 
+        A batch is built by the feed mode.  In buffer mode it is one
+        copy: ``TaggedBuffer.lease`` takes the sessions' shares, each
+        share's slot is looked up once (``share_slots``), and
+        ``fill_chunks`` copies the rows from the buffer's store into the
+        next of two reused chunk arrays.  In source mode the repacked
+        batch goes through ``host_route`` (``host_slots`` +
+        ``host_scatter``).  The chunks sent are the same either way.
+
         Each call leaves one ``ingest_run`` span (batches, items,
-        padded, bytes sent to the device and, in buffer mode, the items
-        producers put and the seconds they waited in ``put`` since the
-        last run: ``buffer_put_items``, ``buffer_put_wait_s``; of those
-        items, the ones admitted session by session,
-        ``buffer_put_block_items``; the blocks ``get`` copied,
-        ``buffer_get_blocks``) split into
-        the stages ``ingest_slot_table``, ``ingest_get`` (with the
-        buffer's ``buffer_get_wait_*`` inside it), ``ingest_route``
-        (split into ``ingest_slot_lookup`` and ``ingest_scatter``),
-        ``ingest_device_put``, ``ingest_dispatch`` and ``ingest_sync``,
-        each an ``<stage>_s`` attribute and a profiler TraceMe.
+        padded, bytes sent to the device, ``direct_items`` (the items
+        copied straight from the buffer's store into a chunk array: all
+        the routed items in buffer mode, 0 in source mode) and
+        ``zeroed_rows`` (the rows a reused chunk array held and had
+        zeroed); in buffer mode also the items producers put and the
+        seconds they waited in ``put`` since the last run:
+        ``buffer_put_items``, ``buffer_put_wait_s``; of those items, the
+        ones admitted session by session, ``buffer_put_block_items``;
+        the sessions' shares taken, ``buffer_get_blocks``) split into
+        the stages ``ingest_slot_table``, ``ingest_get`` (the lease, or
+        the source's repack, with the buffer's ``buffer_get_wait_*``
+        inside it), ``ingest_route`` (split into ``ingest_slot_lookup``
+        and ``ingest_scatter``), ``ingest_device_put``,
+        ``ingest_dispatch`` and ``ingest_sync``, each an ``<stage>_s``
+        attribute and a profiler TraceMe.
         """
         advance = self._advance_fn()
-        C = self.pod.chunk
-        if self._gen is None:
+        if self.buffer is None and self._gen is None:
             self._gen = self._fixed_batches()
-        batches = items = padded = sent = 0
+        batches = items = padded = sent = direct = zeroed = 0
         drop_unknown = drop_overflow = 0
         t0 = time.perf_counter()
         # one span per run; the stages split it without an event per
@@ -305,46 +461,41 @@ class IngestPipeline:
                 sid = np.asarray(state.sid)
                 table = live_table(sid, np.asarray(state.active))
             S = len(sid)
+            build = (self._routed_batch if self.buffer is None
+                     else self._direct_batch)
             while max_batches is None or batches < max_batches:
-                try:
-                    with obs.stage("ingest_get"):
-                        sids, X = next(self._gen)
-                except StopIteration:
+                got = build(table, S)
+                if got is None:
+                    # buffer mode: a later run() re-checks the buffer — a
+                    # pod handoff may inject relocated backlog AFTER the
+                    # stream closed, and it must still drain (source mode
+                    # keeps the spent generator: re-creating it would
+                    # replay the source from the start)
                     self.exhausted = True
-                    if self.buffer is not None:
-                        # buffer mode: a later run() must re-check the
-                        # buffer — a pod handoff may inject relocated
-                        # backlog AFTER the stream closed, and it must
-                        # still drain (source mode keeps the spent
-                        # generator: re-creating it would replay the
-                        # source from the start)
-                        self._gen = None
                     break
-                with obs.stage("ingest_route"):
-                    with obs.stage("ingest_slot_lookup"):
-                        slot, pos, found = host_slots(table, sids, S)
-                    with obs.stage("ingest_scatter"):
-                        routed = host_scatter(slot, pos, found, sids, X,
-                                              S, C)
+                routed, n, n_pad, n_direct, n_zeroed = got
                 with obs.stage("ingest_device_put"):
                     args = [jax.device_put(a) for a in routed]
                 with obs.stage("ingest_dispatch"):
-                    state, _ = advance(state, *args)
+                    state, out = advance(state, *args)
+                if self.buffer is not None:
+                    self._ring.read_by(out)
                 # while the device runs this step, the loop's next
                 # iteration produces + routes the following batch on
                 # host — the overlap
                 _, _, unknown, overflow = routed
                 batches += 1
-                n_pad = int((sids == PAD_SID).sum())
-                items += len(sids) - n_pad
+                items += n
                 padded += n_pad
+                direct += n_direct
+                zeroed += n_zeroed
                 sent += sum(a.nbytes for a in routed)
                 drop_unknown += int(unknown)
                 drop_overflow += int(overflow.sum())
             with obs.stage("ingest_sync"):
                 jax.block_until_ready(state.items)
             sp.set(batches=batches, items=items, padded=padded,
-                   bytes=sent)
+                   bytes=sent, direct_items=direct, zeroed_rows=zeroed)
             if self.buffer is not None:
                 # the buffer's side since the last run: items admitted
                 # (and of them, through the block path), seconds waited

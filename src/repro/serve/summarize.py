@@ -362,8 +362,10 @@ class SummarizerPod:
 
         ``ingest.host_route`` is the host-side (numpy) mirror of this
         scatter, bit-equal by construction — the double-buffered
-        pipeline pre-routes chunk i+1 there while the device runs step i
-        (tests/test_ingest.py pins the equivalence).
+        pipeline pre-routes chunk i+1 on the host (with it, or in buffer
+        mode by copying the buffer's shares straight into their chunks)
+        while the device runs step i (tests/test_ingest.py pins the
+        equivalence).
         """
         S, C = self.sessions, self.chunk
         N = sids.shape[0]

@@ -691,9 +691,10 @@ def test_buffer_blocks_bit_equal_to_item_reference(admission, seed):
             break
 
 
-def test_buffer_blocks_survive_concurrent_producers():
+def _drain_concurrent_producers(take):
     """More producer threads than cores put ragged batches into a small
-    ``block`` buffer while one consumer drains it, with the interpreter
+    ``block`` buffer while one consumer drains it with ``take(buf,
+    max_items, sessions) -> (sids, rows)``, with the interpreter
     switching threads often: every item arrives once, each session's in
     order, and every item went through the block path."""
     import os
@@ -724,7 +725,7 @@ def test_buffer_blocks_survive_concurrent_producers():
         out_s, out_x = [], []
         rng = np.random.RandomState(0)
         while sum(len(s) for s in out_s) < workers * per:
-            s, x = buf.get(int(rng.randint(1, 200)), timeout=30.0)
+            s, x = take(buf, int(rng.randint(1, 200)), workers * sessions)
             out_s.append(s)
             out_x.append(x)
         for t in threads:
@@ -741,6 +742,40 @@ def test_buffer_blocks_survive_concurrent_producers():
         for s in range(p * sessions, (p + 1) * sessions):
             seq = out_x[out_s == s, 1]
             assert np.all(np.diff(seq) > 0)
+
+
+def test_buffer_blocks_survive_concurrent_producers():
+    """``get`` drains a buffer that concurrent producers fill
+    (``_drain_concurrent_producers``)."""
+    _drain_concurrent_producers(
+        lambda buf, m, S: buf.get(m, timeout=30.0))
+
+
+def test_leases_survive_concurrent_producers():
+    """The pipeline's consumer drains a buffer that concurrent producers
+    fill and grow (``_drain_concurrent_producers``): each batch is
+    leased and copied by ``fill_chunks`` into one reused chunk array
+    while the puts go on, and is read back from the chunks."""
+    from repro.ingest.pipeline import fill_chunks, live_table, share_slots
+    arrays = {}
+
+    def take(buf, m, S):
+        if not arrays:
+            arrays.update(chunks=np.zeros((S, 200, 2), np.float32),
+                          held=np.zeros((S,), np.int64),
+                          table=live_table(np.arange(S, dtype=np.int32),
+                                           np.ones(S, bool)))
+        chunks = arrays["chunks"]
+        with buf.lease(m, timeout=30.0) as lease:
+            slot = share_slots(arrays["table"], lease.sids, S)
+            counts, unknown, overflow, _ = fill_chunks(
+                lease, slot, chunks, arrays["held"])
+        assert int(unknown) == 0 and overflow.sum() == 0
+        np.testing.assert_array_equal(counts[slot], lease.counts)
+        return (lease.sids.repeat(lease.counts),
+                np.concatenate([chunks[i, :counts[i]] for i in slot]))
+
+    _drain_concurrent_producers(take)
 
 
 def _round_robin_puts(buf, puts, sliced=False, sessions=256, n=4096, d=2):
@@ -898,6 +933,76 @@ def test_pipeline_bit_equal_to_sync_ingest_loop():
         np.testing.assert_array_equal(
             np.asarray(la), np.asarray(lb),
             err_msg=f"leaf {jax.tree_util.keystr(pa)} differs")
+
+
+def test_pipeline_buffer_mode_copies_once_bit_equal_to_sync_loop():
+    """Buffer mode builds each batch by one copy from the buffer's store
+    into two reused chunk arrays.  Over two runs of at least six batches
+    whose shares shrink (each array refilled, rows it held zeroed), each
+    step is sent what ``host_route`` makes of ``get``'s batch, and the
+    pod state equals the synchronous ``jit(pod.ingest)`` loop over
+    ``get``'s batches of the same stream, bit for bit.  The
+    ``ingest_run`` spans count every item as copied directly, the rows
+    zeroed, and every stage of the route; a source-mode run copies
+    none directly."""
+    from repro import obs
+    rec = obs.get_recorder()
+    rec.clear()
+    pod = _pod(S=4, C=8)
+    B = 16
+    rng = np.random.RandomState(6)
+    sids = rng.permutation(np.repeat(np.int32([10, 11, 12, 13]),
+                                     [40, 20, 8, 4])).astype(np.int32)
+    X = rng.randn(len(sids), D).astype(np.float32)
+    st0 = _admit_all(pod, pod.init(), [10, 11, 12, 13])
+    buf, ref = (TaggedBuffer(capacity=128, policy="block") for _ in "ab")
+    for b in (buf, ref):
+        b.put(sids, X)
+        b.close()
+
+    ing = jax.jit(pod.ingest)
+    st_sync, want = st0, []
+    while (got := ref.get(B, pad_to=B, per_session=pod.chunk)) is not None:
+        st_sync, _ = ing(st_sync, jnp.asarray(got[0]), jnp.asarray(got[1]))
+        want.append(host_route(np.asarray(st0.sid), np.asarray(st0.active),
+                               *got, pod.chunk))
+
+    pipe = IngestPipeline(pod, buffer=buf, batch=B)
+    step, sent = pipe._advance_fn(), []
+
+    def advance(state, *args):  # what each step is sent, as it is sent
+        sent.append([np.array(a) for a in args])
+        return step(state, *args)
+
+    pipe._advance = advance
+    st_pipe, s1 = pipe.run(st0, max_batches=3)
+    st_pipe, s2 = pipe.run(st_pipe)
+    assert s1["batches"] + s2["batches"] == len(want) >= 6
+    for i, (got, w) in enumerate(zip(sent, want)):
+        for g, x in zip(got, w):
+            assert g.dtype == np.asarray(x).dtype
+            np.testing.assert_array_equal(g, x, err_msg=f"batch {i}")
+    assert s1["items"] + s2["items"] == len(sids)
+    for (pa, la), lb in zip(jax.tree_util.tree_leaves_with_path(st_sync),
+                            jax.tree_util.tree_leaves(st_pipe)):
+        np.testing.assert_array_equal(
+            np.asarray(la), np.asarray(lb),
+            err_msg=f"leaf {jax.tree_util.keystr(pa)} differs")
+    runs = [e["attrs"] for e in rec.events if e["name"] == "ingest_run"]
+    assert [a["direct_items"] for a in runs] == [a["items"] for a in runs]
+    assert sum(a["zeroed_rows"] for a in runs) > 0
+    for stage in ("ingest_get", "ingest_route", "ingest_slot_lookup",
+                  "ingest_scatter", "ingest_device_put"):
+        assert all(f"{stage}_s" in a for a in runs), stage
+
+    rec.clear()
+    src = IngestPipeline(pod, source=ReplaySource(sids=sids, X=X, batch=B),
+                         batch=B)
+    src.run(st0)
+    (run,) = [e["attrs"] for e in rec.events if e["name"] == "ingest_run"]
+    rec.clear()
+    assert run["items"] == len(sids)
+    assert run["direct_items"] == 0 and run["zeroed_rows"] == 0
 
 
 def test_pipeline_repacks_ragged_batches_fifo():

@@ -22,7 +22,8 @@ from repro.core.api import make
 from repro.core.sieve_family import stack_states, tree_select
 from repro.ingest import (PAD_SID, IngestPipeline, ReplaySource, TaggedBuffer,
                           host_route)
-from repro.ingest.pipeline import host_slots, live_table
+from repro.ingest.pipeline import (fill_chunks, host_slots, live_table,
+                                   share_slots)
 from repro.serve import SummarizerPod
 
 
@@ -318,6 +319,81 @@ def test_pipeline_drains_a_concentrated_backlog_without_overflow():
     np.testing.assert_array_equal(np.asarray(st.items),
                                   np.bincount(sids, minlength=S))
     assert stats["batches"] > len(sids) // (2 * C)
+
+
+# ---------------------------------------- one copy from the buffer's store
+DIRECT_PODS = {"ts256": (16, 32), "many": (256, 4)}  # name -> (S, C)
+DIRECT_CASES = ("quiesced", "unknown", "capped", "uncapped", "closed")
+
+
+@pytest.mark.parametrize("case", DIRECT_CASES)
+@pytest.mark.parametrize("pod", sorted(DIRECT_PODS))
+def test_direct_fill_bit_equals_host_route_of_get(pod, case):
+    """Two buffers fed alike: the shares ``lease`` takes from one,
+    copied by ``fill_chunks`` into one reused chunk array, give batch
+    after batch what ``host_route`` gives for ``get``'s padded batch of
+    the other — chunks, counts, unknown and overflow, dtypes included —
+    while the shares shrink, so rows held last time must be zeroed, and
+    both buffers free the same slots.  ``quiesced`` parks a session,
+    ``unknown`` sends an evicted id (freed slot, stale id), an id never
+    admitted and a negative id, ``capped`` / ``uncapped`` a backlog of
+    three chunks in one session with and without the per-session cap
+    (overflow), ``closed`` drains to a partial last batch."""
+    S, C = DIRECT_PODS[pod]
+    B = S * C // 2
+    cap = None if case == "uncapped" else C
+    rng = np.random.RandomState(len(pod) * 100 + DIRECT_CASES.index(case))
+    sid_table = (np.arange(S) * 3 + 100).astype(np.int32)
+    active = np.ones(S, bool)
+    sessions = list(sid_table)
+    if case == "unknown":
+        active[1] = False  # evicted: the freed slot keeps a stale id
+        sessions += [7, -7]
+    bufs = [TaggedBuffer(capacity=8 * B, policy="block") for _ in range(2)]
+    chunks = np.zeros((S, C, 3), np.float32)
+    held = np.zeros((S,), np.int64)
+    zeroed, unknown, overflow = [], 0, 0
+    puts = [2 * B, B // 2, B // 8, B // 32]
+    for step in range(len(puts) + (8 if case == "closed" else 0)):
+        if step < len(puts):
+            sids = rng.choice(np.asarray(sessions, np.int32), puts[step])
+            if step == 0 and case in ("capped", "uncapped"):
+                sids = np.concatenate([np.full(3 * C, sid_table[2]), sids])
+            X = rng.randn(len(sids), 3).astype(np.float32)
+            X[:, 0] = step * 10 ** 5 + np.arange(len(sids))
+            for buf in bufs:
+                buf.put(sids.astype(np.int32), X)
+                if case == "quiesced" and step == 0:
+                    buf.quiesce([sid_table[3]])
+                if case == "closed" and step == len(puts) - 1:
+                    buf.close()
+        got = bufs[0].get(B, pad_to=B, per_session=cap)
+        lease = bufs[1].lease(B, per_session=cap)
+        if got is None:
+            assert case == "closed" and lease is None
+            break
+        want = host_route(sid_table, active, *got, C)
+        with lease:
+            slot = share_slots(live_table(sid_table, active), lease.sids, S)
+            *have, z = fill_chunks(lease, slot, chunks, held)
+        zeroed.append(z)
+        last = lease.items
+        unknown += int(want[2])
+        overflow += int(want[3].sum())
+        for h, w in zip([chunks] + have, want):
+            assert np.asarray(h).dtype == np.asarray(w).dtype
+            np.testing.assert_array_equal(h, w, err_msg=f"batch {step}")
+        np.testing.assert_array_equal(held, want[1])
+        a, b = bufs
+        assert a.size == b.size and a.depths() == b.depths()
+        np.testing.assert_array_equal(a._free[:a._nfree], b._free[:b._nfree])
+    assert len(zeroed) >= 4 and max(zeroed) > 0
+    assert (unknown > 0) == (case == "unknown")
+    assert (overflow > 0) == (case == "uncapped")
+    if case == "closed":
+        assert got is None and 0 < last < B  # then the end of the stream
+    if case == "quiesced":
+        assert bufs[1].depths()[int(sid_table[3])] > 0
 
 
 # ------------------------------------------------- many small sessions served
