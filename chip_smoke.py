@@ -9,9 +9,10 @@
 
 Phase 1 drives a multi-tenant ``SummarizerPod`` the way a deployment
 does: ``DriftSource`` -> ``TaggedBuffer`` (block policy) ->
-``IngestPipeline`` (``feed_from``, double-buffered ``host_route``, the
-donated device step) -> ``SummarizerPod.serve(drift_every=...)`` ->
-``readout()`` -> ``drain_metrics()``.  The pod is ThreeSieves at the
+``IngestPipeline`` (``feed_from``, each lease copied straight into
+double-buffered chunks, the donated device step) ->
+``SummarizerPod.serve(drift_every=...)`` -> ``readout()`` ->
+``drain_metrics()``.  The pod is ThreeSieves at the
 ``paper-summarizer__pod256`` shape (S=256 sessions, K_max=100, d=256,
 chunk C=1024, f32), with tenants on mixed plans K in {10, 50, 100}, fed
 device batches of S*C/2 items.  Phase 2 runs a SieveStreaming++ pod
@@ -293,7 +294,7 @@ def sharded_phase(size, rehearse):
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.ingest.pipeline import host_route
+    from repro.ingest import PAD_SID
     from repro.launch.mesh import auto_mesh
 
     n_dev, S = 4, size.S_shard
@@ -320,21 +321,23 @@ def sharded_phase(size, rehearse):
     step = jax.jit(pod.make_sharded_update(mesh, pre_routed=True),
                    in_shardings=sharded, out_shardings=sharded)
     local_step = jax.jit(pod.ingest_routed)
+    route = jax.jit(pod.route)
 
     with Phase("sharded pod, 4 devices vs 4 one-device pods"):
         src = source(size, n_dev * S, size.batches_shard, SEED + 1)
         items = 0
         for tags, X in src:
             routed = []
+            X0 = jax.device_put(X, dev0)
             for p, st in enumerate(locals_):
-                mine = (tags // S) == p
-                r = host_route(np.asarray(st.sid), np.asarray(st.active),
-                               tags[mine], X[mine], size.C)
+                # another shard's items are padding here: one shape
+                mine = np.where((tags // S) == p, tags, np.int32(PAD_SID))
+                r = route(st, jax.device_put(mine, dev0), X0)
                 routed.append(r)
-                locals_[p], _ = local_step(
-                    st, *(jax.device_put(a, dev0) for a in r))
+                locals_[p], _ = local_step(st, *r)
             chunks, counts, unknown, overflow = (
-                np.concatenate([np.atleast_1d(r[i]) for r in routed])
+                np.concatenate([np.atleast_1d(np.asarray(r[i]))
+                                for r in routed])
                 for i in range(4))
             glob, _ = step(glob, *(jax.device_put(a, sharded) for a in
                                    (chunks, counts, unknown, overflow)))

@@ -10,8 +10,8 @@ routing against the device step:
     (producer threads)        (overlapped with the running pod program)
 
 The chunks are copied straight from the buffer's store, one share per
-session; a pipeline fed by a ``Source`` directly routes its batches
-with ``host_route``.
+session; a ``Source`` reaches the buffer through producer threads
+(``IngestPipeline.feed_from``).
 
 Above that sits the fleet edge: ``PodRouter`` fans one tagged ingress
 across pod shards, and ``repro.ingest.pubsub`` puts a partitioned,
@@ -20,7 +20,7 @@ untrusted producers and the router, with exactly-once producer resume
 and sync-boundary offset commits.
 """
 from .buffer import PAD_SID, POLICIES, TaggedBuffer
-from .pipeline import IngestPipeline, PodRouter, host_route
+from .pipeline import IngestPipeline, PodRouter
 from .pubsub import (Publisher, PubSubBroker, PubSubFrontEnd, PubSubListener,
                      partition_of, publish_frame)
 from .shedding import RUNGS, RateLimit, ShedPolicy, TokenBucket
@@ -29,7 +29,7 @@ from .sources import (MAGIC, DriftSource, ReplaySource, SocketSource, Source,
                       send_frame)
 
 __all__ = ["PAD_SID", "POLICIES", "TaggedBuffer", "IngestPipeline",
-           "PodRouter", "host_route", "MAGIC", "DriftSource", "ReplaySource",
+           "PodRouter", "MAGIC", "DriftSource", "ReplaySource",
            "SocketSource", "Source", "SubsampleSource", "TaggedBatch",
            "connect_producer", "send_frame",
            "Publisher", "PubSubBroker", "PubSubFrontEnd", "PubSubListener",

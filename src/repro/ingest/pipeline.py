@@ -11,20 +11,19 @@ host, the routing scatter, and the vmapped ``run_batched`` program.
   * routing moves to host, so the device program is
     ``ingest_routed``: run_batched + counters only, no slot lookup or
     scatter on its critical path.  The sorted table of live ids is
-    built once per run.  In buffer mode a batch is one copy:
-    ``TaggedBuffer.lease`` hands over each session's share (its store
-    slots, FIFO), ``share_slots`` finds the share's slot once, and
-    ``fill_chunks`` copies the rows from the store straight into the
-    next of two reused (S, C, d) host arrays (``_ChunkRing``), zeroing
-    only the rows that array held last time and not now; an array is
-    refilled only once the step that read it, two batches back, has
-    ended, which on the chip it long has.  In source
-    mode the repacked batch goes through ``host_route`` (a numpy
-    mirror of ``SummarizerPod.route``).  Both give the chunks
-    ``SummarizerPod.route`` would, bit for bit (pinned by tests);
+    built once per run.  A batch is one copy: ``TaggedBuffer.lease``
+    hands over each session's share (its store slots, FIFO),
+    ``share_slots`` finds the share's slot once, and ``fill_chunks``
+    copies the rows from the store straight into the next of two reused
+    (S, C, d) host arrays (``_ChunkRing``), zeroing only the rows that
+    array held last time and not now; an array is refilled only once
+    the step that read it, two batches back, has ended, which on the
+    chip it long has.  The chunks are the ones ``SummarizerPod.route``
+    makes of ``TaggedBuffer.get``'s padded batch, bit for bit (pinned
+    by tests);
   * JAX's async dispatch provides the overlap: ``advance(i)`` returns
     as soon as the program is enqueued, and the host spends the device
-    step's wall time producing, repacking and routing batch i+1, then
+    step's wall time leasing and copying batch i+1, then
     ``jax.device_put``-ing it;
   * the pod state is donated to the jitted step (off-CPU), so the
     stacked session pytree is updated in place — no per-step state
@@ -37,12 +36,10 @@ ops between ``run()`` calls are picked up by the next snapshot
 (drift resets keep slots, so ``serve``'s periodic ``drift_check`` needs
 no re-snapshot).
 
-Feed modes:
-  * ``source=``              pull tagged batches inline and repack to the
-                             fixed device batch size (benchmarks, replays);
-  * ``buffer=``              drain a ``TaggedBuffer`` that producer
-                             threads fill (sockets, generators) — add
-                             ``feed_from(source)`` to spawn the feeder.
+Every batch comes from a ``TaggedBuffer`` that producers fill (sockets,
+generators, a router).  ``feed_from(source)`` spawns a producer thread
+for a tagged ``Source``; a replay ``put``s its batches and ``close``s
+the buffer before ``run``.
 
 ``PodRouter`` is the fleet front-end above all of that: one ingress
 point fanning a tagged stream out to N pods' buffers through a host
@@ -54,7 +51,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Dict, Iterator, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
@@ -63,8 +60,8 @@ from repro import obs
 from repro.compat import hashable_lru
 from repro.concurrency import make_lock
 
-from .buffer import PAD_SID, TaggedBuffer
-from .sources import Source, TaggedBatch
+from .buffer import TaggedBuffer
+from .sources import Source
 
 
 def live_table(sid_table: np.ndarray, active: np.ndarray
@@ -99,69 +96,6 @@ def share_slots(table: Tuple[np.ndarray, np.ndarray], sids: np.ndarray,
     return np.where(ids[at] == sids, slots[at], sessions)
 
 
-def host_slots(table: Tuple[np.ndarray, np.ndarray], sids: np.ndarray,
-               sessions: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Each item's slot and its position in that slot's chunk.
-
-    (``live_table`` output, sids (N,), S) -> (slot (N,), pos (N,),
-    found (N,)): ``share_slots`` per item, and items with no live
-    session go to the trash row S.  Positions count each slot's items in
-    stream order (a stable argsort): per-session FIFO.
-    """
-    sids = np.asarray(sids, np.int32)
-    N = len(sids)
-    slot = share_slots(table, sids, sessions)
-    found = slot < sessions
-    # a stable sort of the narrowest unsigned type that holds S is a
-    # radix sort up to 16 bits: several times faster than on int64
-    order = np.argsort(slot.astype(np.min_scalar_type(sessions)),
-                       kind="stable")
-    per = np.bincount(slot, minlength=sessions + 1)
-    pos = np.empty((N,), np.int64)
-    pos[order] = np.arange(N, dtype=np.int64) - (per.cumsum() - per)[
-        slot[order]]
-    return slot, pos, found
-
-
-def host_scatter(slot: np.ndarray, pos: np.ndarray, found: np.ndarray,
-                 sids: np.ndarray, X: np.ndarray, sessions: int, chunk: int
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Write the found items into fresh (S, C, d) chunks and count them.
-
-    -> (chunks (S, C, d), counts (S,), unknown (), overflow (S,)): an
-    item past its slot's ``chunk`` is counted in ``overflow``, an item
-    with a non-negative id and no live session in ``unknown``.
-    """
-    S, C = sessions, chunk
-    keep = found & (pos < C)
-    chunks = np.zeros((S, C) + X.shape[1:], X.dtype)
-    chunks[slot[keep], pos[keep]] = X[keep]
-    counts = np.bincount(slot[keep], minlength=S).astype(np.int32)
-    unknown = np.int32((~found & (np.asarray(sids) >= 0)).sum())
-    over = found & (pos >= C)
-    overflow = np.bincount(slot[over], minlength=S).astype(np.int32)
-    return chunks, counts, unknown, overflow
-
-
-def host_route(sid_table: np.ndarray, active: np.ndarray, sids: np.ndarray,
-               X: np.ndarray, chunk: int
-               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Numpy mirror of ``SummarizerPod.route``, bit-equal to it.
-
-    (sid_table (S,), active (S,), sids (N,), X (N, d), chunk C) ->
-    (chunks (S, C, d), counts (S,), unknown (), overflow (S,)).
-    ``live_table`` + ``host_slots`` + ``host_scatter``: an item's slot
-    is found by a binary search of the live ids, O(N log S), and is the
-    one the plain (N, S) match ``(sids[:, None] == sid_table) & active``
-    picks (its first live slot, else the trash row), which the tests
-    keep as the reference.  The stable argsort gives per-session FIFO,
-    exactly as the device scatter's stable sort does.
-    """
-    S = len(sid_table)
-    slot, pos, found = host_slots(live_table(sid_table, active), sids, S)
-    return host_scatter(slot, pos, found, sids, X, S, chunk)
-
-
 # the copy stages its rows through a block of this size, which stays in
 # cache between the gather and the scatter
 _BLOCK_BYTES = 1 << 19
@@ -178,7 +112,8 @@ def fill_chunks(lease, slot: np.ndarray, chunks: np.ndarray,
                 held: np.ndarray
                 ) -> Tuple[np.ndarray, np.int32, np.ndarray, int]:
     """Copy a ``TaggedBuffer.lease``'s shares straight into their slots'
-    chunks: ``host_route`` of the same batch, with no packed batch.
+    chunks: ``SummarizerPod.route`` of the same batch, with no packed
+    batch.
 
     (lease, slot (m,) of each share from ``share_slots``, chunks
     (S, C, d) as its last fill left it, held (S,) the items each slot
@@ -187,8 +122,8 @@ def fill_chunks(lease, slot: np.ndarray, chunks: np.ndarray,
     the rest count in ``overflow``, and a share with no live slot counts
     in ``unknown`` if its id is not negative.  The rows the last fill
     held and this one does not are zeroed first, and ``held`` becomes
-    ``counts``: so ``chunks`` ends bit-equal to ``host_route``'s, at a
-    cost in items, not in S·C.  Each kept row is copied once, from the
+    ``counts``: so ``chunks`` ends bit-equal to ``route``'s, at a cost
+    in items, not in S·C.  Each kept row is copied once, from the
     store to its chunk, through a cache-sized block.
     """
     S, C = chunks.shape[:2]
@@ -222,7 +157,7 @@ def fill_chunks(lease, slot: np.ndarray, chunks: np.ndarray,
 
 
 class _ChunkRing:
-    """The host chunk arrays a buffer-mode pipeline fills in turn.
+    """The host chunk arrays a pipeline fills in turn.
 
     Two, zeroed once: the host fills one while the device step reads
     the other.  Each keeps the items its slots held at its last fill
@@ -261,25 +196,20 @@ def _advance_for(pod, donate):
 
 @dataclasses.dataclass
 class IngestPipeline:
-    """Drive a SummarizerPod from a tagged source, double-buffered.
+    """Drive a SummarizerPod from a ``TaggedBuffer``, double-buffered.
 
-    ``batch`` is the fixed device batch size: ragged source batches are
-    repacked (and the final partial batch PAD_SID-padded) so the jitted
-    step compiles exactly once.  In buffer mode a batch takes at most
-    the pod's per-session routing capacity ``chunk`` from one session
-    (``TaggedBuffer.get``'s ``per_session``), so nothing overflows.  In
-    source mode size it so that no session exceeds ``chunk`` within one
-    batch — ``batch <= pod.chunk`` is the safe default for a
-    single-session worst case (everything else is counted overflow,
-    never corrupted).
+    ``batch`` is the fixed device batch size: every device batch holds
+    at most ``batch`` items (the last ones fewer, counted as padding), so
+    the jitted step compiles exactly once.  A batch takes at most the
+    pod's per-session routing capacity ``chunk`` from one session
+    (``TaggedBuffer.lease``'s ``per_session``), so nothing overflows.
     """
 
     pod: "object"  # SummarizerPod (kept loose to avoid an import cycle)
-    source: Optional[Source] = None
-    buffer: Optional[TaggedBuffer] = None
+    buffer: TaggedBuffer
     batch: int = 256
-    get_timeout: Optional[float] = None  # buffer mode: None = wait forever
-    min_fill: int = 1  # buffer mode: items to wait for per device batch
+    get_timeout: Optional[float] = None  # None = wait forever
+    min_fill: int = 1  # items to wait for per device batch
     # (raise toward ``batch`` when a trickling producer must not burn a
     # full jitted step per item; 1 favors latency)
     pod_id: "object" = 0  # telemetry label; PodRouter stamps its key here
@@ -292,12 +222,8 @@ class IngestPipeline:
     on_sync: "object" = None
 
     def __post_init__(self):
-        if (self.source is None) == (self.buffer is None):
-            raise ValueError(
-                "exactly one of source= or buffer= must be given")
-        self._gen: Optional[Iterator[TaggedBatch]] = None
         self._advance = None
-        self._ring: Optional[_ChunkRing] = None  # buffer mode's chunks
+        self._ring: Optional[_ChunkRing] = None
         self._feeders = []
         self._feed_exc: Optional[BaseException] = None
         self._seen: dict = {}  # the buffer's counters at the last run
@@ -307,11 +233,9 @@ class IngestPipeline:
     def feed_from(self, source: Source, *, close: bool = True,
                   put_timeout: Optional[float] = None) -> threading.Thread:
         """Spawn a daemon thread that puts ``source`` into the buffer
-        (and closes it on exhaustion) — the producer half of buffer mode.
+        (and closes it on exhaustion) — the producer half of the feed.
         Backpressure is the buffer's policy: ``block`` pauses the
         feeder, the drop policies clip per session."""
-        if self.buffer is None:
-            raise ValueError("feed_from() needs buffer mode")
 
         def _run():
             try:
@@ -330,33 +254,6 @@ class IngestPipeline:
         self._feeders.append(t)
         return t
 
-    def _fixed_batches(self) -> Iterator[TaggedBatch]:
-        """Repack the source's ragged tagged batches into
-        exactly-``batch``-sized ones (last one padded); per-session FIFO
-        is order-preserving here."""
-        B = self.batch
-        stash: list = []
-        count = 0
-        for sids, X in self.source:
-            if not count and len(sids) == B:
-                yield sids, X  # aligned fast path: no copy
-                continue
-            stash.append((sids, X))
-            count += len(sids)
-            while count >= B:
-                s = np.concatenate([p[0] for p in stash])
-                x = np.concatenate([p[1] for p in stash])
-                yield s[:B], x[:B]
-                stash = [(s[B:], x[B:])] if count > B else []
-                count -= B
-        if count:
-            s = np.concatenate([p[0] for p in stash])
-            x = np.concatenate([p[1] for p in stash])
-            pad = B - count
-            yield (np.concatenate([s, np.full((pad,), PAD_SID, np.int32)]),
-                   np.concatenate([x, np.zeros((pad, x.shape[1]),
-                                               np.float32)]))
-
     # ------------------------------------------------------------------- run
     def _advance_fn(self):
         if self._advance is None:
@@ -367,10 +264,10 @@ class IngestPipeline:
             self._advance = _advance_for(self.pod, donate)
         return self._advance
 
-    def _direct_batch(self, table, S):
-        """Buffer mode: lease the next batch's shares and copy them
-        straight from the buffer's store into the next ring array.
-        -> (routed, items, padded, items copied, rows zeroed) or None."""
+    def _next_batch(self, table, S):
+        """Lease the next batch's shares and copy them straight from the
+        buffer's store into the next ring array.
+        -> (routed, items, padded, rows zeroed) or None."""
         with obs.stage("ingest_get"):
             lease = self.buffer.lease(self.batch, timeout=self.get_timeout,
                                       min_items=self.min_fill,
@@ -391,67 +288,41 @@ class IngestPipeline:
                     lease, slot, chunks, held)
                 lease.release()
         n = lease.items
-        return ((chunks, counts, unknown, overflow), n, self.batch - n,
-                int(counts.sum()), zeroed)
-
-    def _routed_batch(self, table, S):
-        """Source mode: the next repacked batch, through ``host_route``'s
-        two halves.  -> as ``_direct_batch``."""
-        with obs.stage("ingest_get"):
-            got = next(self._gen, None)
-        if got is None:
-            return None
-        sids, X = got
-        with obs.stage("ingest_route"):
-            with obs.stage("ingest_slot_lookup"):
-                slot, pos, found = host_slots(table, sids, S)
-            with obs.stage("ingest_scatter"):
-                routed = host_scatter(slot, pos, found, sids, X, S,
-                                      self.pod.chunk)
-        n_pad = int((sids == PAD_SID).sum())
-        return routed, len(sids) - n_pad, n_pad, 0, 0
+        return (chunks, counts, unknown, overflow), n, self.batch - n, zeroed
 
     def run(self, state, *, max_batches: Optional[int] = None):
         """Ingest up to ``max_batches`` device batches (None = until the
-        feed ends); resumable — the feed position persists across calls.
-        Returns ``(state, stats)``.
+        buffer is closed and drained); resumable — a later call drains
+        what the buffer holds then.  Returns ``(state, stats)``.
 
         ``stats`` carries the drop counters the host routing observed
-        (``dropped_unknown`` / ``dropped_overflow``) — items lost to a
-        mis-sized ``batch`` vs ``pod.chunk`` or to dead session ids are
-        loud here, not just in the device-side ledgers.  A producer
-        failure recorded by a ``feed_from`` thread re-raises from here:
-        a broken wire must never look like a clean end-of-stream.
+        (``dropped_unknown`` / ``dropped_overflow``) — items lost to
+        dead session ids are loud here, not just in the device-side
+        ledgers.  A producer failure recorded by a ``feed_from`` thread
+        re-raises from here: a broken wire must never look like a clean
+        end-of-stream.
 
-        A batch is built by the feed mode.  In buffer mode it is one
-        copy: ``TaggedBuffer.lease`` takes the sessions' shares, each
-        share's slot is looked up once (``share_slots``), and
-        ``fill_chunks`` copies the rows from the buffer's store into the
-        next of two reused chunk arrays.  In source mode the repacked
-        batch goes through ``host_route`` (``host_slots`` +
-        ``host_scatter``).  The chunks sent are the same either way.
+        A batch is one copy: ``TaggedBuffer.lease`` takes the sessions'
+        shares, each share's slot is looked up once (``share_slots``),
+        and ``fill_chunks`` copies the rows from the buffer's store into
+        the next of two reused chunk arrays.
 
         Each call leaves one ``ingest_run`` span (batches, items,
-        padded, bytes sent to the device, ``direct_items`` (the items
-        copied straight from the buffer's store into a chunk array: all
-        the routed items in buffer mode, 0 in source mode) and
-        ``zeroed_rows`` (the rows a reused chunk array held and had
-        zeroed); in buffer mode also the items producers put and the
-        seconds they waited in ``put`` since the last run:
+        padded, bytes sent to the device, ``zeroed_rows`` (the rows a
+        reused chunk array held and had zeroed), the items producers put
+        and the seconds they waited in ``put`` since the last run:
         ``buffer_put_items``, ``buffer_put_wait_s``; of those items, the
         ones admitted session by session, ``buffer_put_block_items``;
         the sessions' shares taken, ``buffer_get_blocks``) split into
-        the stages ``ingest_slot_table``, ``ingest_get`` (the lease, or
-        the source's repack, with the buffer's ``buffer_get_wait_*``
-        inside it), ``ingest_route`` (split into ``ingest_slot_lookup``
-        and ``ingest_scatter``), ``ingest_device_put``,
-        ``ingest_dispatch`` and ``ingest_sync``, each an ``<stage>_s``
-        attribute and a profiler TraceMe.
+        the stages ``ingest_slot_table``, ``ingest_get`` (the lease,
+        with the buffer's ``buffer_get_wait_*`` inside it),
+        ``ingest_route`` (split into ``ingest_slot_lookup`` and
+        ``ingest_scatter``), ``ingest_device_put``, ``ingest_dispatch``
+        and ``ingest_sync``, each an ``<stage>_s`` attribute and a
+        profiler TraceMe.
         """
         advance = self._advance_fn()
-        if self.buffer is None and self._gen is None:
-            self._gen = self._fixed_batches()
-        batches = items = padded = sent = direct = zeroed = 0
+        batches = items = padded = sent = zeroed = 0
         drop_unknown = drop_overflow = 0
         t0 = time.perf_counter()
         # one span per run; the stages split it without an event per
@@ -461,53 +332,44 @@ class IngestPipeline:
                 sid = np.asarray(state.sid)
                 table = live_table(sid, np.asarray(state.active))
             S = len(sid)
-            build = (self._routed_batch if self.buffer is None
-                     else self._direct_batch)
             while max_batches is None or batches < max_batches:
-                got = build(table, S)
+                got = self._next_batch(table, S)
                 if got is None:
-                    # buffer mode: a later run() re-checks the buffer — a
-                    # pod handoff may inject relocated backlog AFTER the
-                    # stream closed, and it must still drain (source mode
-                    # keeps the spent generator: re-creating it would
-                    # replay the source from the start)
+                    # a later run() re-checks the buffer — a pod handoff
+                    # may inject relocated backlog AFTER the stream
+                    # closed, and it must still drain
                     self.exhausted = True
                     break
-                routed, n, n_pad, n_direct, n_zeroed = got
+                routed, n, n_pad, n_zeroed = got
                 with obs.stage("ingest_device_put"):
                     args = [jax.device_put(a) for a in routed]
                 with obs.stage("ingest_dispatch"):
                     state, out = advance(state, *args)
-                if self.buffer is not None:
-                    self._ring.read_by(out)
+                self._ring.read_by(out)
                 # while the device runs this step, the loop's next
-                # iteration produces + routes the following batch on
+                # iteration leases + copies the following batch on
                 # host — the overlap
                 _, _, unknown, overflow = routed
                 batches += 1
                 items += n
                 padded += n_pad
-                direct += n_direct
                 zeroed += n_zeroed
                 sent += sum(a.nbytes for a in routed)
                 drop_unknown += int(unknown)
                 drop_overflow += int(overflow.sum())
             with obs.stage("ingest_sync"):
                 jax.block_until_ready(state.items)
+            # the buffer's side since the last run: items admitted (and
+            # of them, through the block path), seconds waited in put,
+            # shares taken
+            seen = {"buffer_put_items": self.buffer.admitted(),
+                    "buffer_put_wait_s": self.buffer.wait_seconds()["put"],
+                    **{f"buffer_{k}": v for k, v in
+                       self.buffer.block_counts().items()}}
             sp.set(batches=batches, items=items, padded=padded,
-                   bytes=sent, direct_items=direct, zeroed_rows=zeroed)
-            if self.buffer is not None:
-                # the buffer's side since the last run: items admitted
-                # (and of them, through the block path), seconds waited
-                # in put, blocks get copied
-                seen = {"buffer_put_items": self.buffer.admitted(),
-                        "buffer_put_wait_s":
-                            self.buffer.wait_seconds()["put"],
-                        **{f"buffer_{k}": v for k, v in
-                           self.buffer.block_counts().items()}}
-                sp.set(**{k: v - self._seen.get(k, 0)
-                          for k, v in seen.items()})
-                self._seen = seen
+                   bytes=sent, zeroed_rows=zeroed,
+                   **{k: v - self._seen.get(k, 0) for k, v in seen.items()})
+            self._seen = seen
         wall = time.perf_counter() - t0
         # telemetry happens HERE and only here: block_until_ready above is
         # the run's host-sync boundary, so draining the device ledgers now
@@ -545,15 +407,14 @@ class IngestPipeline:
                     "PAD_SID filler rows burned in partial batches",
                     ("pod",)).labels(pod=pod).inc(padded)
         obs.drain.drain_pod(state, pod=pod, registry=reg)
-        if self.buffer is not None:
-            obs.drain.drain_buffer(self.buffer, pod=pod, registry=reg)
+        obs.drain.drain_buffer(self.buffer, pod=pod, registry=reg)
 
 
 @dataclasses.dataclass
 class PodRouter:
     """Fleet front-end: one tagged ingress, N pods, a host routing table.
 
-    Each pod runs its own buffer-mode ``IngestPipeline``; the router owns
+    Each pod runs its own ``IngestPipeline``; the router owns
     the sid -> pod-id table and fans ``put`` batches out to the right
     pod's ``TaggedBuffer`` (per-session FIFO is preserved — a session's
     items all flow through one buffer at a time).  Items for sids with
@@ -578,9 +439,6 @@ class PodRouter:
 
     def __post_init__(self):
         for pid, pipe in self.pipelines.items():
-            if pipe.buffer is None:
-                raise ValueError(
-                    f"pod {pid}: PodRouter needs buffer-mode pipelines")
             pipe.pod_id = pid  # every pipe's metrics carry its fleet id
         self._table: Dict[int, int] = {}
         self._lock = make_lock("PodRouter._lock")

@@ -8,8 +8,10 @@ A *source* is anything that yields tagged host batches
 because the whole point of the ingest subsystem is to do the per-item
 work (generation, thinning, framing, routing) off the device's critical
 path and ship only routed fixed-shape chunk buffers down.  Batches may
-be ragged (N varies per batch); the pipeline repacks them into the
-fixed device batch size, so a source never worries about shapes.
+be ragged (N varies per batch): a source feeds a pod through a
+``TaggedBuffer`` (``IngestPipeline.feed_from``, or ``put`` then
+``close`` for a replay), and the pipeline leases fixed-size device
+batches from it, so a source never worries about shapes.
 
 Four implementations cover the serving regimes:
 

@@ -20,9 +20,9 @@ Four pieces, one rule:
 
 The rule: **telemetry never touches the hot path.**  No ``.item()``, no
 ``np.asarray``, no metric recording inside traced code — podlint PL004
-and PL006 gate it statically, ``benchmarks/obs_bench.py`` prices it
-(<2% items/sec at S=64), and the span API no-ops under a trace as the
-runtime backstop.
+and PL006 gate it statically, a test pins a bare run and an
+instrumented one to bit-equal pod state, and the span API no-ops under
+a trace as the runtime backstop.
 """
 from . import drain
 from .jaxbridge import install as install_jax_bridge

@@ -25,8 +25,8 @@ registry) and a *conflicting* re-registration raises — two meanings for
 one name is how dashboards lie.
 
 ``NullRegistry`` is the disabled form: the same surface, every
-operation a no-op — the "bare" arm of ``benchmarks/obs_bench.py`` and
-the escape hatch for perf-paranoid callers.
+operation a no-op — ``metrics=obs.NULL`` on a pipeline, the escape
+hatch for perf-paranoid callers.
 """
 from __future__ import annotations
 

@@ -43,7 +43,7 @@ after are disjoint in time per session).  The distributed argument of
 the source paper §7 says a summary is a function of (state, item
 order), not of which machine holds it — so the migrated tenant's next
 readout is bit-equal to the run that never moved (pinned in
-tests/test_autoscale.py, measured in benchmarks/autoscale_bench.py).
+tests/test_autoscale.py, for one move and for a move there and back).
 
 A refusal is atomic: if the target pod cannot host the victim set (or a
 victim sid is already live there), ``handoff`` returns ``ok=False``
@@ -124,7 +124,7 @@ class PodAutoscaler:
     """Drive drain/migrate rebalancing over a ``PodRouter`` fleet.
 
     ``pods`` maps pod id -> ``SummarizerPod`` program, with one
-    buffer-mode pipeline per pod registered in ``router`` under the
+    pipeline (and its buffer) per pod registered in ``router`` under the
     same ids.  Pod *states* stay with the caller (they are the values
     the caller's serve loop threads through ``pipeline.run``);
     state-changing methods take and return the states dict.

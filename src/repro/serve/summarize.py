@@ -360,12 +360,13 @@ class SummarizerPod:
         counted per session so the noisy tenant is identifiable).
         Folding them together would hide the first behind the second.
 
-        ``ingest.host_route`` is the host-side (numpy) mirror of this
-        scatter, bit-equal by construction — the double-buffered
-        pipeline pre-routes chunk i+1 on the host (with it, or in buffer
-        mode by copying the buffer's shares straight into their chunks)
-        while the device runs step i (tests/test_ingest.py pins the
-        equivalence).
+        This is the routing ``ingest``, the synchronous reference loop
+        and ``make_sharded_update`` run.  The double-buffered pipeline
+        routes chunk i+1 on the host while the device runs step i, by
+        copying a ``TaggedBuffer`` lease's shares straight into their
+        chunks (``ingest.pipeline.fill_chunks``); the chunks equal this
+        route's of ``TaggedBuffer.get``'s padded batch, bit for bit
+        (tests/test_many_tenants.py pins the equivalence).
         """
         S, C = self.sessions, self.chunk
         N = sids.shape[0]

@@ -16,8 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core.api import make
-from repro.ingest import (IngestPipeline, PodRouter, ReplaySource,
-                          TaggedBuffer)
+from repro.ingest import IngestPipeline, PodRouter, TaggedBuffer
 from repro.serve import (HandoffReport, PodAutoscaler, ScalePolicy,
                          SummarizerPod)
 
@@ -182,6 +181,52 @@ def test_handoff_quiesce_preserves_fifo_backlog():
     assert stats["items"] == 10
     _assert_summary_equals_standalone(
         podB, states[1], 5, list(pre) + list(backlog) + list(post))
+
+
+@pytest.mark.parametrize("parked", [False, True])
+def test_handoff_there_and_back_bit_equal(parked):
+    """A session moved to the other pod and back, with items ingested
+    before, between and after, ends bit-equal to the run that never
+    moved, as do the sessions that stayed.  ``parked``: the return trip
+    happens while the first target still holds the session's items
+    undrained, so they travel back as its backlog, FIFO."""
+    podA, podB = _pod(S=4), _pod(S=4)
+    sids_all = [70, 71, 72]
+    router, pipes = _fleet([podA, podB])
+    states = {0: _admit_all(podA, podA.init(), sids_all), 1: podB.init()}
+    router.assign(sids_all, 0)
+    asc = PodAutoscaler(router=router, pods={0: podA, 1: podB})
+    rng = np.random.RandomState(21)
+    feed = []
+
+    def put():
+        feed.append(_tagged(rng, 40, sids_all))
+        router.put(*feed[-1])
+
+    def drain(pods=(0, 1)):
+        for i in pods:
+            while pipes[i].buffer.size:
+                states[i], st = pipes[i].run(states[i], max_batches=1)
+                assert st["dropped_unknown"] == 0
+                assert st["dropped_overflow"] == 0
+
+    put()
+    drain()
+    states, there = asc.handoff(states, 0, 1, [71])
+    put()
+    drain((0,) if parked else (0, 1))
+    states, back = asc.handoff(states, 1, 0, [71])
+    assert there.ok and back.ok and back.moved == [71]
+    assert (back.backlog_items > 0) == parked
+    put()
+    drain()
+    assert not router.drops_unrouted
+    assert sorted(podA.routing_table(states[0])) == sids_all
+    assert int(np.asarray(states[1].active).sum()) == 0
+    per = _per_session(feed)
+    for sid in sids_all:
+        _assert_summary_equals_standalone(podA, states[0], sid, per[sid],
+                                          "there and back")
 
 
 def test_handoff_after_stream_close_still_delivers_backlog():
@@ -447,10 +492,6 @@ def test_router_counts_unrouted_and_feeds_by_table():
     assert router.drops_unrouted == {9: 2, 2: 1}
     with pytest.raises(KeyError):
         router.assign([3], 7)
-    with pytest.raises(ValueError, match="buffer-mode"):
-        PodRouter(pipelines={0: IngestPipeline(
-            podA, source=ReplaySource(sids=np.zeros(1, np.int32),
-                                      X=np.zeros((1, D), np.float32)))})
 
 
 def test_router_feeder_failure_surfaces_in_both_pods():

@@ -33,8 +33,8 @@ def test_checker_catches_rot(tmp_path):
 
 def test_makefile_parser_sees_phony_and_rules():
     targets = _make_targets(ROOT)
-    for t in ("verify", "lint", "analyze", "docs-check", "bench-shed",
-              "bench-gate", "verify-lockdep"):
+    for t in ("verify", "lint", "analyze", "docs-check", "bench",
+              "verify-lockdep"):
         assert t in targets
     assert "PYTHONPATH" not in targets  # := assignment is not a rule
 

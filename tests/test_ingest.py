@@ -6,9 +6,9 @@ The two load-bearing claims, pinned property-style:
     and the pod: ragged batches, repacking across chunk boundaries,
     buffer fairness rotation, and the drop policies (survivors stay in
     order; only *which* items survive changes);
-  * equivalence — ``host_route`` is bit-equal to the device ``route``,
-    and the double-buffered pipeline is bit-equal to the synchronous
-    ingest loop on the same stream.
+  * equivalence — the device ``route`` is bit-equal to the plain
+    first-live-slot match, and the double-buffered pipeline is
+    bit-equal to the synchronous ingest loop on the same stream.
 
 Socket tests carry a ``timeout`` mark (enforced by pytest-timeout when
 installed) *and* socket-level timeouts inside ``SocketSource`` itself,
@@ -27,8 +27,9 @@ from repro.core.api import make
 from repro.ingest import (PAD_SID, DriftSource, IngestPipeline, RateLimit,
                           ReplaySource, ShedPolicy, SocketSource,
                           SubsampleSource, TaggedBuffer, TokenBucket,
-                          connect_producer, host_route, send_frame)
+                          connect_producer, send_frame)
 from repro.serve import SummarizerPod
+from test_many_tenants import _match_route
 
 D = 5
 
@@ -56,6 +57,17 @@ def _tagged(rng, n, sessions, d=D):
 
 def _per_session(sids, X):
     return {int(s): X[sids == s] for s in np.unique(sids)}
+
+
+def _closed_buffer(feed):
+    """A buffer holding ``feed``'s tagged batches, put in order, then
+    closed: a replay whose device batches are fixed before any run."""
+    buf = TaggedBuffer(capacity=max(1, sum(len(s) for s, _ in feed)),
+                       policy="block")
+    for sids, X in feed:
+        buf.put(sids, X)
+    buf.close()
+    return buf
 
 
 # -------------------------------------------------------------------- sources
@@ -848,7 +860,7 @@ def test_buffer_get_refuses_a_slot_outside_the_store():
 
 
 def test_pipeline_run_span_carries_the_block_counters():
-    """A buffer-mode run leaves the block counters on its
+    """A pipeline run leaves the block counters on its
     ``ingest_run`` span, as deltas since the previous run."""
     from repro import obs
     rec = obs.get_recorder()
@@ -873,9 +885,10 @@ def test_pipeline_run_span_carries_the_block_counters():
 @settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10_000))
 def test_host_route_bit_equals_device_route(seed):
-    """The pipeline's host scatter mirrors ``SummarizerPod.route`` —
-    chunks, counts and both drop counters — including unknown sids,
-    padding and per-session overflow."""
+    """``SummarizerPod.route`` gives what the plain (N, S) match of ids
+    against the table and per-slot FIFO positions give — chunks, counts
+    and both drop counters — including unknown sids, padding and
+    per-session overflow."""
     rng = np.random.RandomState(seed)
     pod = _pod(S=4, C=3)
     state = _admit_all(pod, pod.init(), [10, 11, 12, 13])
@@ -883,8 +896,9 @@ def test_host_route_bit_equals_device_route(seed):
                       26).astype(np.int32)
     X = rng.randn(26, D).astype(np.float32)
     cj, nj, uj, oj = pod.route(state, jnp.asarray(sids), jnp.asarray(X))
-    ch, nh, uh, oh = host_route(np.asarray(state.sid),
-                                np.asarray(state.active), sids, X, pod.chunk)
+    (ch, nh, uh, oh), _ = _match_route(np.asarray(state.sid),
+                                       np.asarray(state.active), sids, X,
+                                       pod.chunk)
     np.testing.assert_array_equal(np.asarray(cj), ch)
     np.testing.assert_array_equal(np.asarray(nj), nh)
     assert int(uj) == int(uh)
@@ -907,27 +921,27 @@ def _assert_sessions_match_standalone(pod, state, per):
                                       err_msg=f"session {sid}")
 
 
-def test_pipeline_bit_equal_to_sync_ingest_loop():
+@pytest.mark.parametrize("S", [4, 1, 16, 64])
+def test_pipeline_bit_equal_to_sync_ingest_loop(S):
     """Same stream, two execution strategies: the double-buffered
     pipeline's final pod state equals the synchronous per-batch
-    ``jit(pod.ingest)`` loop bit for bit."""
-    pod = _pod(S=4, C=16)
+    ``jit(pod.ingest)`` loop over ``get``'s batches bit for bit, from
+    one session to more sessions than a batch has items."""
+    pod = _pod(S=S, C=16)
     rng = np.random.RandomState(2)
-    feed = []
-    for _ in range(6):
-        sids, X = _tagged(rng, 32, [10, 11, 12, 13])
-        feed.append((sids, X))
-    st0 = _admit_all(pod, pod.init(), [10, 11, 12, 13])
+    sessions = list(range(10, 10 + S))
+    feed = [_tagged(rng, 32, sessions) for _ in range(6)]
+    st0 = _admit_all(pod, pod.init(), sessions)
 
     ing = jax.jit(pod.ingest)
-    st_sync = st0
-    for sids, X in feed:
-        st_sync, _ = ing(st_sync, jnp.asarray(sids), jnp.asarray(X))
+    st_sync, ref, n_sync = st0, _closed_buffer(feed), 0
+    while (got := ref.get(32, pad_to=32, per_session=pod.chunk)) is not None:
+        st_sync, _ = ing(st_sync, jnp.asarray(got[0]), jnp.asarray(got[1]))
+        n_sync += 1
 
-    pipe = IngestPipeline(pod, source=ReplaySource.from_batches(feed),
-                          batch=32)
+    pipe = IngestPipeline(pod, buffer=_closed_buffer(feed), batch=32)
     st_pipe, stats = pipe.run(st0)
-    assert stats["batches"] == 6 and stats["items"] == 192
+    assert stats["batches"] == n_sync >= 6 and stats["items"] == 192
     for (pa, la), lb in zip(jax.tree_util.tree_leaves_with_path(st_sync),
                             jax.tree_util.tree_leaves(st_pipe)):
         np.testing.assert_array_equal(
@@ -936,15 +950,15 @@ def test_pipeline_bit_equal_to_sync_ingest_loop():
 
 
 def test_pipeline_buffer_mode_copies_once_bit_equal_to_sync_loop():
-    """Buffer mode builds each batch by one copy from the buffer's store
+    """The pipeline builds each batch by one copy from the buffer's store
     into two reused chunk arrays.  Over two runs of at least six batches
     whose shares shrink (each array refilled, rows it held zeroed), each
-    step is sent what ``host_route`` makes of ``get``'s batch, and the
+    step is sent what ``SummarizerPod.route`` makes of ``get``'s batch,
+    and the
     pod state equals the synchronous ``jit(pod.ingest)`` loop over
     ``get``'s batches of the same stream, bit for bit.  The
-    ``ingest_run`` spans count every item as copied directly, the rows
-    zeroed, and every stage of the route; a source-mode run copies
-    none directly."""
+    ``ingest_run`` spans count the rows zeroed and every stage of the
+    route."""
     from repro import obs
     rec = obs.get_recorder()
     rec.clear()
@@ -960,12 +974,11 @@ def test_pipeline_buffer_mode_copies_once_bit_equal_to_sync_loop():
         b.put(sids, X)
         b.close()
 
-    ing = jax.jit(pod.ingest)
+    ing, route = jax.jit(pod.ingest), jax.jit(pod.route)
     st_sync, want = st0, []
     while (got := ref.get(B, pad_to=B, per_session=pod.chunk)) is not None:
         st_sync, _ = ing(st_sync, jnp.asarray(got[0]), jnp.asarray(got[1]))
-        want.append(host_route(np.asarray(st0.sid), np.asarray(st0.active),
-                               *got, pod.chunk))
+        want.append([np.asarray(a) for a in route(st0, *got)])
 
     pipe = IngestPipeline(pod, buffer=buf, batch=B)
     step, sent = pipe._advance_fn(), []
@@ -989,26 +1002,18 @@ def test_pipeline_buffer_mode_copies_once_bit_equal_to_sync_loop():
             np.asarray(la), np.asarray(lb),
             err_msg=f"leaf {jax.tree_util.keystr(pa)} differs")
     runs = [e["attrs"] for e in rec.events if e["name"] == "ingest_run"]
-    assert [a["direct_items"] for a in runs] == [a["items"] for a in runs]
+    rec.clear()
+    assert sum(a["items"] for a in runs) == len(sids)
     assert sum(a["zeroed_rows"] for a in runs) > 0
     for stage in ("ingest_get", "ingest_route", "ingest_slot_lookup",
                   "ingest_scatter", "ingest_device_put"):
         assert all(f"{stage}_s" in a for a in runs), stage
 
-    rec.clear()
-    src = IngestPipeline(pod, source=ReplaySource(sids=sids, X=X, batch=B),
-                         batch=B)
-    src.run(st0)
-    (run,) = [e["attrs"] for e in rec.events if e["name"] == "ingest_run"]
-    rec.clear()
-    assert run["items"] == len(sids)
-    assert run["direct_items"] == 0 and run["zeroed_rows"] == 0
-
 
 def test_pipeline_repacks_ragged_batches_fifo():
-    """Ragged source batches cross device-batch boundaries; per-session
-    FIFO must survive the repacking (each session bit-equal to its
-    standalone run on the original item order)."""
+    """Ragged puts drain per-session FIFO through the pipeline: device
+    batches cross the puts' boundaries, and each session ends bit-equal
+    to its standalone run on the original item order."""
     pod = _pod(S=3, C=16)
     rng = np.random.RandomState(4)
     sids, X = _tagged(rng, 70, [20, 21, 22])
@@ -1017,8 +1022,7 @@ def test_pipeline_repacks_ragged_batches_fifo():
         ragged.append((sids[lo:lo + n], X[lo:lo + n]))
         lo += n
     st = _admit_all(pod, pod.init(), [20, 21, 22])
-    pipe = IngestPipeline(pod, source=ReplaySource.from_batches(ragged),
-                          batch=16)
+    pipe = IngestPipeline(pod, buffer=_closed_buffer(ragged), batch=16)
     st, stats = pipe.run(st)
     assert stats["items"] == 70
     assert stats["padded"] == (16 - 70 % 16) % 16
@@ -1048,7 +1052,7 @@ def test_pod_serve_drift_loop():
     src = DriftSource(seed=3, n_sessions=2, batch=32, d=D, n_batches=12,
                       drift_per_batch=0.5)
     st = _admit_all(pod, pod.init(), [0, 1])
-    pipe = IngestPipeline(pod, source=src, batch=32)
+    pipe = IngestPipeline(pod, buffer=_closed_buffer(list(src)), batch=32)
     st, stats = pod.serve(st, pipe, drift_every=3, min_items=30,
                           min_rate=0.9)
     assert pipe.exhausted
@@ -1063,12 +1067,11 @@ def test_pipeline_resume_is_retrace_free(retrace_guard):
     pads every device batch to the fixed (batch, d) shape, so the
     resumed drain — including the ragged tail — is served entirely from
     the warmup compile (the double-buffered advance, donation included)."""
-    pod = _pod(S=2, C=16)
+    pod = _pod(S=2, C=32)
     rng = np.random.RandomState(12)
     sids, X = _tagged(rng, 90, [1, 2])  # ragged tail: 90 = 32 + 32 + 26
     st = _admit_all(pod, pod.init(), [1, 2])
-    pipe = IngestPipeline(pod, source=ReplaySource(sids=sids, X=X, batch=32),
-                          batch=32)
+    pipe = IngestPipeline(pod, buffer=_closed_buffer([(sids, X)]), batch=32)
     st, s1 = pipe.run(st, max_batches=1)  # warmup: compiles the step
     assert s1["batches"] == 1
     with retrace_guard.budget(0):
@@ -1100,7 +1103,7 @@ def test_pipeline_surfaces_producer_failure():
     with pytest.raises(RuntimeError, match="producer failed"):
         pipe.run(st)
     # drop counters ride along in stats on the healthy path
-    pipe2 = IngestPipeline(pod, source=ReplaySource(sids=sids, X=X, batch=8))
+    pipe2 = IngestPipeline(pod, buffer=_closed_buffer([(sids, X)]))
     _, stats = pipe2.run(st)
     assert stats["dropped_unknown"] == 0 and stats["dropped_overflow"] == 0
 
@@ -1111,7 +1114,7 @@ def test_pod_serve_respects_max_batches_with_drift():
     pod = _pod(S=2, C=32, T=5)
     src = DriftSource(seed=3, n_sessions=2, batch=32, d=D, n_batches=12)
     st = _admit_all(pod, pod.init(), [0, 1])
-    pipe = IngestPipeline(pod, source=src, batch=32)
+    pipe = IngestPipeline(pod, buffer=_closed_buffer(list(src)), batch=32)
     st, stats = pod.serve(st, pipe, max_batches=4, drift_every=64,
                           min_items=10**6, min_rate=0.0)
     assert stats["batches"] == 4
@@ -1119,6 +1122,51 @@ def test_pod_serve_respects_max_batches_with_drift():
     # the feed is resumable: a later serve continues where it stopped
     st, stats = pod.serve(st, pipe, max_batches=None)
     assert stats["batches"] == 8 and pipe.exhausted
+
+
+# ---------------------------------------------------------- shed ladder
+def _overload_run(mult, buffer, rounds=12, batch=16, d=8):
+    """One hot tenant (0) offering ``mult * batch - 3`` items a round and
+    three quiet ones (1-3) one each, against a pod that drains one
+    device batch a round; the stream is the same for every buffer.
+    -> ({sid: f(S)}, the buffer's largest depth)."""
+    algo = make("threesieves", d=d, K=4, T=64, eps=0.5)
+    pod = SummarizerPod(algo, sessions=4, chunk=batch)
+    st = _admit_all(pod, pod.init(), range(4))
+    pipe = IngestPipeline(pod, buffer=buffer, batch=batch, get_timeout=60.0)
+    rng = np.random.default_rng(5)
+    max_depth = 0
+    for _ in range(rounds):
+        sids = np.int32([0] * (mult * batch - 3) + [1, 2, 3])
+        buffer.put(sids, rng.normal(size=(len(sids), d)).astype(np.float32))
+        max_depth = max(max_depth, buffer.size)
+        st, _ = pipe.run(st, max_batches=1)
+    buffer.close()
+    st, _ = pipe.run(st)
+    fval = np.asarray(pod.readout(st).fval)
+    return ({int(s): float(fval[i])
+             for i, s in enumerate(np.asarray(st.sid)) if s >= 0}, max_depth)
+
+
+@pytest.mark.parametrize("mult", [2, 4, 10])
+def test_shed_ladder_under_sustained_overload(mult):
+    """At 2x, 4x and 10x the drain rate the watermark ladder keeps the
+    buffer within its capacity and never reaches its capacity wall; the
+    quiet tenants are shed nothing and end bit-equal to the run with no
+    shedding, and up to 4x the summed f stays within 5% of it."""
+    f_base, _ = _overload_run(mult, TaggedBuffer(1 << 20))
+    buf = TaggedBuffer(64, policy="drop-newest",
+                       shed=ShedPolicy(lo=0.25, hi=0.6, p_floor=0.1,
+                                       clip_mult=2.0, seed=1))
+    f_shed, max_depth = _overload_run(mult, buf)
+    assert max_depth <= buf.capacity
+    assert buf.total_drops() == 0 and buf.total_sheds() > 0
+    sheds = buf.shed_counts()
+    for q in (1, 2, 3):
+        assert sheds.get(q, 0) == 0, q
+        assert f_shed[q] == f_base[q], q
+    if mult <= 4:
+        assert sum(f_shed.values()) >= 0.95 * sum(f_base.values())
 
 
 # -------------------------------------------------------------------- socket

@@ -4,7 +4,7 @@ left in few sessions without overflowing them.
 
 Two references are kept here as the code stood before: the plain (N, S)
 id match the routing used, and the admit that wrote every slot through a
-masked select over an (S,)-stacked fresh copy.  ``host_route``,
+masked select over an (S,)-stacked fresh copy.  ``share_slots``,
 ``SummarizerPod.route`` and ``SummarizerPod.admit`` are pinned bit-equal
 to them on seeded cases.
 """
@@ -20,10 +20,8 @@ import pytest
 
 from repro.core.api import make
 from repro.core.sieve_family import stack_states, tree_select
-from repro.ingest import (PAD_SID, IngestPipeline, ReplaySource, TaggedBuffer,
-                          host_route)
-from repro.ingest.pipeline import (fill_chunks, host_slots, live_table,
-                                   share_slots)
+from repro.ingest import PAD_SID, IngestPipeline, ReplaySource, TaggedBuffer
+from repro.ingest.pipeline import fill_chunks, live_table, share_slots
 from repro.serve import SummarizerPod
 
 
@@ -125,39 +123,38 @@ def _tagged(rng, sid, active, N, C, d=3):
     return sids, X
 
 
+def _device_route(sid_table, active, sids, X, chunk):
+    """``jax.jit(SummarizerPod.route)`` over this slot table, on numpy."""
+    algo = make("threesieves", K=2, d=X.shape[1], lengthscale=1.0)
+    pod = SummarizerPod(algo=algo, sessions=len(sid_table), chunk=chunk)
+    state = dataclasses.replace(pod.init(), sid=jnp.asarray(sid_table),
+                                active=jnp.asarray(active))
+    return [np.asarray(a) for a in jax.jit(pod.route)(
+        state, jnp.asarray(sids), jnp.asarray(X))]
+
+
 @pytest.mark.parametrize("S", [1, 2, 5, 64, 512, 4096])
 @pytest.mark.parametrize("seed", [0, 1])
 def test_lookup_bit_equals_the_plain_match(S, seed):
-    """``host_route`` and ``SummarizerPod.route`` give what the (N, S)
-    match gave — chunks, counts, unknown, overflow, and every item's
-    slot and FIFO position — with inactive slots, stale ids on freed
+    """``share_slots`` finds every item's slot as the (N, S) match did,
+    and ``SummarizerPod.route`` gives what it gave — chunks, counts,
+    unknown and overflow — with inactive slots, stale ids on freed
     slots, a live id on two slots, unknown ids, PAD_SID and overflow."""
     rng = np.random.RandomState(1000 * seed + S)
     C = 4
     sid, active = _slot_table(rng, S)
     N = min(4 * S + 16, 4096)
     sids, X = _tagged(rng, sid, active, N, C)
-    want, (w_slot, w_pos, w_found) = _match_route(sid, active, sids, X, C)
+    want, (w_slot, _, _) = _match_route(sid, active, sids, X, C)
     assert want[3].sum() > 0  # the cases hold an overflow
     if S >= 2:
         assert int(want[2]) > 0  # ... and unknown ids
 
-    slot, pos, found = host_slots(live_table(sid, active), sids, S)
-    np.testing.assert_array_equal(slot, w_slot)
-    np.testing.assert_array_equal(pos, w_pos)
-    np.testing.assert_array_equal(found, w_found)
-    got = host_route(sid, active, sids, X, C)
-    for g, w in zip(got, want):
-        assert np.asarray(g).dtype == np.asarray(w).dtype
+    np.testing.assert_array_equal(
+        share_slots(live_table(sid, active), sids, S), w_slot)
+    for g, w in zip(_device_route(sid, active, sids, X, C), want):
+        assert g.dtype == np.asarray(w).dtype
         np.testing.assert_array_equal(g, w)
-
-    algo = make("threesieves", K=2, d=X.shape[1], lengthscale=1.0)
-    pod = SummarizerPod(algo=algo, sessions=S, chunk=C)
-    state = dataclasses.replace(pod.init(), sid=jnp.asarray(sid),
-                                active=jnp.asarray(active))
-    dev = jax.jit(pod.route)(state, jnp.asarray(sids), jnp.asarray(X))
-    for g, w in zip(dev, want):
-        np.testing.assert_array_equal(np.asarray(g), w)
 
 
 def test_lookup_with_no_live_session():
@@ -167,7 +164,7 @@ def test_lookup_with_no_live_session():
     sids = np.asarray([5, PAD_SID, 9, 5], np.int32)
     X = np.ones((4, 2), np.float32)
     want, _ = _match_route(sid, active, sids, X, 2)
-    for g, w in zip(host_route(sid, active, sids, X, 2), want):
+    for g, w in zip(_device_route(sid, active, sids, X, 2), want):
         np.testing.assert_array_equal(g, w)
     assert int(want[2]) == 3 and want[1].sum() == 0
 
@@ -331,14 +328,15 @@ DIRECT_CASES = ("quiesced", "unknown", "capped", "uncapped", "closed")
 def test_direct_fill_bit_equals_host_route_of_get(pod, case):
     """Two buffers fed alike: the shares ``lease`` takes from one,
     copied by ``fill_chunks`` into one reused chunk array, give batch
-    after batch what ``host_route`` gives for ``get``'s padded batch of
-    the other — chunks, counts, unknown and overflow, dtypes included —
-    while the shares shrink, so rows held last time must be zeroed, and
-    both buffers free the same slots.  ``quiesced`` parks a session,
-    ``unknown`` sends an evicted id (freed slot, stale id), an id never
-    admitted and a negative id, ``capped`` / ``uncapped`` a backlog of
-    three chunks in one session with and without the per-session cap
-    (overflow), ``closed`` drains to a partial last batch."""
+    after batch what ``jit(SummarizerPod.route)`` gives for ``get``'s
+    padded batch of the other — chunks, counts, unknown and overflow,
+    dtypes included — while the shares shrink, so rows held last time
+    must be zeroed, and both buffers free the same slots.  ``quiesced``
+    parks a session, ``unknown`` sends an evicted id (freed slot, stale
+    id), an id never admitted and a negative id, ``capped`` /
+    ``uncapped`` a backlog of three chunks in one session with and
+    without the per-session cap (overflow), ``closed`` drains to a
+    partial last batch."""
     S, C = DIRECT_PODS[pod]
     B = S * C // 2
     cap = None if case == "uncapped" else C
@@ -350,6 +348,11 @@ def test_direct_fill_bit_equals_host_route_of_get(pod, case):
         active[1] = False  # evicted: the freed slot keeps a stale id
         sessions += [7, -7]
     bufs = [TaggedBuffer(capacity=8 * B, policy="block") for _ in range(2)]
+    algo = make("threesieves", K=2, d=3, lengthscale=1.0)
+    dev = SummarizerPod(algo=algo, sessions=S, chunk=C)
+    state = dataclasses.replace(dev.init(), sid=jnp.asarray(sid_table),
+                                active=jnp.asarray(active))
+    route = jax.jit(dev.route)
     chunks = np.zeros((S, C, 3), np.float32)
     held = np.zeros((S,), np.int64)
     zeroed, unknown, overflow = [], 0, 0
@@ -372,7 +375,7 @@ def test_direct_fill_bit_equals_host_route_of_get(pod, case):
         if got is None:
             assert case == "closed" and lease is None
             break
-        want = host_route(sid_table, active, *got, C)
+        want = [np.asarray(a) for a in route(state, *got)]
         with lease:
             slot = share_slots(live_table(sid_table, active), lease.sids, S)
             *have, z = fill_chunks(lease, slot, chunks, held)

@@ -28,6 +28,7 @@ import pytest
 from repro import obs
 from repro.obs import jaxbridge
 from repro.obs.registry import MetricsSnapshot
+from test_ingest import _closed_buffer
 
 # --------------------------------------------------------------------------
 # isolation: every test gets a fresh default registry + a cleared recorder
@@ -243,7 +244,7 @@ def test_drop_ledgers_unify_across_all_three_layers(fresh_obs):
     buf.put(np.array([5, 5, 5], np.int32), np.zeros((3, 2), np.float32))
     obs.drain.drain_buffer(buf, pod="1")
 
-    class _Pod:  # buffer-mode pipeline shell; never run
+    class _Pod:  # pipeline shell; never run
         class algo:
             class f:
                 d = 2
@@ -365,11 +366,12 @@ def test_pipeline_records_at_sync_boundary_without_retracing(
                  rng.normal(size=(16, 4)).astype(np.float32))
                 for _ in range(n)]
 
-    warm = IngestPipeline(pod=pod, source=iter(batches(1)), batch=16)
+    warm = IngestPipeline(pod=pod, buffer=_closed_buffer(batches(1)),
+                          batch=16)
     state, _ = warm.run(state)
     with retrace_guard.budget(0):
-        pipe = IngestPipeline(pod=pod, source=iter(batches(5)), batch=16,
-                              pod_id="9")
+        pipe = IngestPipeline(pod=pod, buffer=_closed_buffer(batches(5)),
+                              batch=16, pod_id="9")
         state, stats = pipe.run(state)
     assert stats["items"] == 80
     snap = reg.snapshot()
@@ -393,11 +395,44 @@ def test_pipeline_metrics_null_disables(fresh_obs):
     state, _, _ = pod.admit(state, 0)
     sids = np.zeros((16,), np.int32)
     X = np.ones((16, 4), np.float32)
-    pipe = IngestPipeline(pod=pod, source=iter([(sids, X)]), batch=16,
-                          metrics=obs.NULL)
+    pipe = IngestPipeline(pod=pod, buffer=_closed_buffer([(sids, X)]),
+                          batch=16, metrics=obs.NULL)
     state, stats = pipe.run(state)
     assert stats["items"] == 16
     assert reg.snapshot().get("ingest_items_total", pod="0") is None
+
+
+def test_bare_and_instrumented_runs_give_bit_equal_state(fresh_obs):
+    """Recording never touches what is computed: one feed through a
+    pipeline with ``metrics=obs.NULL`` and through one that records into
+    the default registry ends in bit-equal pod state, and only the
+    second leaves counters."""
+    from repro.core.api import make
+    from repro.ingest import IngestPipeline
+    from repro.serve.summarize import SummarizerPod
+    reg, _ = fresh_obs
+    algo = make("threesieves", d=4, K=4, T=16, eps=0.5)
+    pod = SummarizerPod(algo, sessions=8, chunk=16)
+    state0 = pod.init()
+    for sid in range(8):
+        state0, _, _ = pod.admit(state0, sid)
+    rng = np.random.default_rng(3)
+    feed = [(rng.integers(0, 9, 64).astype(np.int32),
+             rng.normal(size=(64, 4)).astype(np.float32)) for _ in range(4)]
+    out = {}
+    for pod_id, metrics in (("bare", obs.NULL), ("instrumented", None)):
+        pipe = IngestPipeline(pod=pod, buffer=_closed_buffer(feed),
+                              batch=32, pod_id=pod_id, metrics=metrics)
+        out[pod_id], stats = pipe.run(state0)
+        assert stats["batches"] >= 8 and stats["dropped_unknown"] > 0
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(out["bare"]),
+            jax.tree_util.tree_leaves(out["instrumented"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+    snap = reg.snapshot()
+    assert snap.get("ingest_items_total", pod="bare") is None
+    assert snap.get("ingest_items_total", pod="instrumented") == 256
 
 
 def test_handoff_refusal_leaves_refused_span_with_no_phases(fresh_obs):
@@ -472,7 +507,8 @@ def test_drift_reset_span_in_serve(fresh_obs):
     state, _, _ = pod.admit(state, 0)
     sids = np.zeros((16,), np.int32)
     X = np.ones((16, 4), np.float32)
-    pipe = IngestPipeline(pod=pod, source=iter([(sids, X)] * 4), batch=16)
+    pipe = IngestPipeline(pod=pod, buffer=_closed_buffer([(sids, X)] * 4),
+                          batch=16)
     state, stats = pod.serve(state, pipe, drift_every=2)
     assert stats["batches"] == 4
     # the span is named for what it measures: every drift check, whether
@@ -500,7 +536,7 @@ STAGES = ("ingest_slot_table", "ingest_get", "ingest_route",
 ROUTE_SPLIT = ("ingest_slot_lookup", "ingest_scatter")  # inside the route
 
 
-def _source_run(n_batches=4, pod_id="5"):
+def _replay_run(n_batches=4, pod_id="5"):
     from repro.core.api import make
     from repro.ingest import IngestPipeline
     from repro.serve.summarize import SummarizerPod
@@ -512,8 +548,8 @@ def _source_run(n_batches=4, pod_id="5"):
     batches = [(np.zeros((16,), np.int32),
                 rng.normal(size=(16, 4)).astype(np.float32))
                for _ in range(n_batches)]
-    pipe = IngestPipeline(pod=pod, source=iter(batches), batch=16,
-                          pod_id=pod_id)
+    pipe = IngestPipeline(pod=pod, buffer=_closed_buffer(batches),
+                          batch=16, pod_id=pod_id)
     return pipe.run(state)
 
 
@@ -558,7 +594,7 @@ def test_ingest_run_span_holds_its_stages(fresh_obs):
     stage attributes are ≥ 0, the outer ones sum to at most its duration
     and the route's split to at most the route."""
     _, rec = fresh_obs
-    _, stats = _source_run(n_batches=4)
+    _, stats = _replay_run(n_batches=4)
     (ev,) = rec.events
     assert ev["name"] == "ingest_run" and ev["outcome"] == "ok"
     a = ev["attrs"]
@@ -567,7 +603,11 @@ def test_ingest_run_span_holds_its_stages(fresh_obs):
     # per batch: chunks (S, C, d) f32, counts (S,), unknown (), overflow
     # (S,) int32
     assert a["bytes"] == 4 * 4 * (4 * 16 * 4 + 4 + 1 + 4)
-    stages = {k[:-2]: v for k, v in a.items() if k.endswith("_s")}
+    # the producers' wait in put since the last run: a count the span
+    # carries, not one of its stages
+    assert a["buffer_put_wait_s"] == 0
+    stages = {k[:-2]: v for k, v in a.items()
+              if k.endswith("_s") and k != "buffer_put_wait_s"}
     assert set(stages) == set(STAGES)
     assert all(v >= 0 for v in stages.values())
     assert sum(v for k, v in stages.items()
@@ -579,10 +619,10 @@ def test_stages_are_tracemes_inside_ingest_run(fresh_obs, tmp_path):
     """Under a profiler trace the span and its stages sit on the host
     plane, on one thread, each stage inside ``ingest_run``."""
     from jax.profiler import ProfileData
-    _source_run(n_batches=1)  # compile outside the trace
+    _replay_run(n_batches=1)  # compile outside the trace
     jax.profiler.start_trace(str(tmp_path))
     try:
-        _source_run(n_batches=2)
+        _replay_run(n_batches=2)
     finally:
         jax.profiler.stop_trace()
     (path,) = tmp_path.glob("**/*.xplane.pb")

@@ -253,12 +253,8 @@ def test_sharded_update_matches_local():
                                   np.asarray(stats_shard["counts"]))
 
     # the pre-routed variant (the ingest pipeline's device program):
-    # host-routed chunks in, identical state out
-    from repro.ingest import host_route
-
-    chunks, counts, unknown, overflow = host_route(
-        np.asarray(st.sid), np.asarray(st.active), np.asarray(sids),
-        np.asarray(X), pod.chunk)
+    # routed chunks in, identical state out
+    chunks, counts, unknown, overflow = jax.jit(pod.route)(st, sids, X)
     upd_pre = pod.make_sharded_update(mesh, pre_routed=True)
     with mesh:
         st_pre, stats_pre = jax.jit(upd_pre)(
