@@ -41,28 +41,38 @@ therefore preserved end-to-end (the pod's routing contract); global
 interleaving is deliberately NOT preserved — that is the fairness.
 
 Storage: ``put`` copies the rows it admits into one store, an array of
-rows with a free list of its slots, and a session's queue holds the
-slots of its items in one int64 array.  So the Python work of both
-sides scales with sessions and puts, not items, whatever arrays the
-producers put.  The store doubles when no slot is free and keeps its
-size: the buffer at its fullest plus the batch a ``get`` is copying
-out.  ``put`` groups its batch by session with one stable argsort and
-appends the slots of each session present in one step — whenever
-admission is a prefix decision: the ``block`` policy (the prefix that
-fits, then a wait for room, then the rest) and ``drop-newest`` (the
-prefix that fits; the rest is clipped).  Where admission decides item by
-item — a rate limit or a shed ladder installed, or ``drop-oldest``
-(each overflow clips another queue's head) — ``put`` keeps its per-item
-loop, and copies the rows it admitted in one step at its end.  ``get``
-computes the round-robin per session, not per item: ``r`` full turns,
-found from the sorted depths, give each drainable session ``min(depth,
-r)`` items, the partial turn one more to the first sessions in order
-that still hold items.  ``lease`` takes each session's share of slots
-under the lock and leaves the rows in the store; ``get`` then, outside
-the lock, puts them in turn order (a stable sort by turn) into the batch
-with one ``np.take`` and gives the slots back.  The result is the
-item-at-a-time loop's, bit for bit.  A consumer that lays the rows out
-itself takes the ``Lease`` instead: the pipeline copies each share
+rows with a free list of its slots.  The queues are arrays, not one
+object per session.  Each session has a dense index (the known ids are
+kept sorted, and ``searchsorted`` finds an id's index) and, per index,
+the sequence numbers of its oldest queued item (head) and of its next
+(tail), and a round-robin key, set when its queue goes from empty to
+non-empty, so that a new or emptied session joins the round-robin last.
+Every queued item has an entry (store slot, session index, sequence
+number) in one log, appended a put at a time; an entry whose sequence
+number lies below its session's head is dead, and the log drops dead
+entries when it needs room.  So the work of both sides is a fixed number
+of array steps a call, whatever the number of sessions a put touches
+and whatever arrays the producers put; memory is the store, the log
+(at most twice the items queued plus a put) and a few numbers per
+session.  The store doubles when no slot is free and keeps its size: the
+buffer at its fullest plus the batch a ``get`` is copying out.  ``put``
+groups its batch by session with one stable argsort and appends it to
+the log in one step — whenever admission is a prefix decision: the
+``block`` policy (the prefix that fits, then a wait for room, then the
+rest) and ``drop-newest`` (the prefix that fits; the rest is clipped).
+Where admission decides item by item — a rate limit or a shed ladder
+installed, or ``drop-oldest`` (each overflow clips another queue's
+head) — ``put`` keeps its per-item decisions, writes each admitted item
+into the same arrays, and copies the rows it admitted in one step at its
+end.  ``get`` computes the round-robin per session, not per item: ``r``
+full turns, found from the sorted depths, give each drainable session
+``min(depth, r)`` items, the partial turn one more to the first sessions
+in order that still hold items.  ``lease`` takes each session's share of
+slots under the lock and leaves the rows in the store; ``get`` then,
+outside the lock, puts them in turn order (a stable sort by turn) into
+the batch with one ``np.take`` and gives the slots back.  The result is
+the item-at-a-time loop's, bit for bit.  A consumer that lays the rows
+out itself takes the ``Lease`` instead: the pipeline copies each share
 straight into its session's chunk of the device batch
 (``repro.ingest.pipeline``), and releases the slots after the copy.
 
@@ -87,7 +97,6 @@ is held.
 """
 from __future__ import annotations
 
-import collections
 import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
@@ -101,68 +110,24 @@ from .shedding import RateLimit, ShedPolicy, TokenBucket
 
 POLICIES = ("block", "drop-newest", "drop-oldest")
 PAD_SID = -1  # the pod's queue-padding sentinel
+_SCAN = 1 << 14  # log entries a lease or a clip reads a step, until done
 
 
-class _Fifo:
-    """One session's queue: the store slots of its items, oldest first,
-    in one int64 array (``idx[head:tail]``)."""
-
-    __slots__ = ("idx", "head", "tail")
-
-    def __init__(self):
-        self.idx = np.empty(16, np.int64)
-        self.head = self.tail = 0
-
-    def __len__(self) -> int:
-        return self.tail - self.head
-
-    def append(self, slots: np.ndarray) -> None:
-        m = len(slots)
-        if self.tail + m > len(self.idx):
-            n = self.tail - self.head
-            idx = np.empty(2 * (n + m), np.int64)
-            idx[:n] = self.idx[self.head:self.tail]
-            self.idx, self.head, self.tail = idx, 0, n
-        self.idx[self.tail:self.tail + m] = slots
-        self.tail += m
-
-    def push(self, slot) -> None:
-        if self.tail == len(self.idx):
-            self.append(np.array([slot]))
-        else:
-            self.idx[self.tail] = slot
-            self.tail += 1
-
-    def take(self, k: int) -> np.ndarray:
-        """The first ``k`` slots, off the queue (a view, valid until the
-        next ``append``)."""
-        self.head += k
-        return self.idx[self.head - k:self.head]
-
-    def slots(self) -> np.ndarray:
-        return self.idx[self.head:self.tail]
-
-
-def _round_robin(depth: list, max_items: int) -> list:
+def _round_robin(depth: np.ndarray, max_items: int) -> np.ndarray:
     """Items each queue gives to a batch of at most ``max_items`` taken
     one per queue per turn, queues in order: ``r`` full turns, then one
     more item from each of the first queues still holding one."""
-    left = max_items
-    for i, c in enumerate(sorted(depth)):
-        if c * (len(depth) - i) > left:  # ``r`` turns end inside queue i
-            r = left // (len(depth) - i)
-            break
-        left -= c  # queue i empties within the ``r`` turns
-    else:
+    if depth.sum() <= max_items:
         return depth
-    take = [min(c, r) for c in depth]
-    rest = max_items - sum(take)
-    for i, c in enumerate(depth):
-        if not rest:
-            break
-        if c > r:
-            take[i] += 1
-            rest -= 1
+    srt = np.sort(depth)
+    # at sorted queue i: the items left once the shallower queues are
+    # empty, and the queues left to share them
+    left = max_items - (np.cumsum(srt) - srt)
+    queues = np.arange(len(srt), 0, -1)
+    i = np.argmax(srt * queues > left)  # ``r`` turns end inside queue i
+    r = left[i] // queues[i]
+    take = np.minimum(depth, r)
+    take[np.flatnonzero(depth > r)[:max_items - take.sum()]] += 1
     return take
 
 
@@ -188,8 +153,27 @@ class TaggedBuffer:
         self.rate_limit = rate_limit
         self.shed = shed
         self._clock = clock
-        self._q: "collections.OrderedDict[int, _Fifo]" = \
-            collections.OrderedDict()  # sid -> FIFO of store slots
+        # sessions, by a dense index given as ids are first seen: ``_ids``
+        # the known ids sorted, ``_at`` the index of each; per index the
+        # id, the sequence numbers of the oldest queued item (``_head``)
+        # and of the next (``_tail``), and the round-robin key (``_turn``)
+        self._ids = np.empty(0, np.int32)
+        self._at = np.empty(0, np.int64)
+        self._sid = np.empty(0, np.int32)
+        self._head = np.empty(0, np.int64)
+        self._tail = np.empty(0, np.int64)
+        self._turn = np.empty(0, np.int64)
+        self._nsess = 0
+        self._nlive = 0  # sessions with queued items
+        self._next_turn = 0
+        # the log: (store slot, session index, sequence number) of every
+        # queued item in ``[_lo, _hi)``, a put after another, each grouped
+        # by session, so a session's entries lie in sequence order; dead
+        # entries (sequence number below their session's head) among them
+        self._slot = np.empty(0, np.int64)
+        self._sess = np.empty(0, np.int64)
+        self._seq = np.empty(0, np.int64)
+        self._lo = self._hi = 0
         # the store: every buffered row, and the rows a ``get`` still
         # copies out, in one array; ``_free[:_nfree]`` the other slots
         self._rows: Optional[np.ndarray] = None
@@ -213,7 +197,7 @@ class TaggedBuffer:
         self._rate_overrides: Dict[int, RateLimit] = {}
         self._wait_s = {"put": 0.0, "get": 0.0}  # side -> seconds waited
         self._admitted = 0  # items put admitted, lifetime
-        self._block_items = 0  # of them, admitted session by session
+        self._block_items = 0  # of them, admitted as a prefix
         self._get_blocks = 0  # sessions' shares get took, lifetime
 
     # ------------------------------------------------------------- properties
@@ -299,10 +283,9 @@ class TaggedBuffer:
             return self._admitted
 
     def block_counts(self) -> Dict[str, int]:
-        """Lifetime items ``put`` admitted through the block path
-        (``put_block_items``) and the sessions' shares ``get`` took
-        into batches, one slice of store slots each (``get_blocks``):
-        the storage format at work."""
+        """Lifetime items ``put`` admitted as a prefix, by array steps
+        (``put_block_items``), and the sessions' shares ``get`` took
+        into batches (``get_blocks``): the storage format at work."""
         with self._lock:
             return {"put_block_items": self._block_items,
                     "get_blocks": self._get_blocks}
@@ -326,7 +309,9 @@ class TaggedBuffer:
         """Per-session queue depth — the autoscaler's load signal (and
         the ``largest-queue`` victim policy's ranking key)."""
         with self._lock:
-            return {sid: len(dq) for sid, dq in self._q.items()}
+            live = self._live()
+            return dict(zip(self._sid[live].tolist(),
+                            (self._tail[live] - self._head[live]).tolist()))
 
     def quiesced(self) -> set:
         with self._lock:
@@ -334,8 +319,11 @@ class TaggedBuffer:
 
     def _avail(self) -> int:
         """Drainable items (excludes quiesced sessions' backlogs)."""
-        return self._size - sum(
-            len(self._q[s]) for s in self._quiesced if s in self._q)
+        if not self._quiesced:
+            return self._size
+        parked = self._parked()
+        return self._size - int((self._tail[parked]
+                                 - self._head[parked]).sum())
 
     # ---------------------------------------------------------------- quiesce
     def quiesce(self, sids) -> None:
@@ -360,12 +348,12 @@ class TaggedBuffer:
         capacity) at the source pod — re-admitting it at the target
         must neither block, drop, nor fail because the stream happened
         to close mid-handoff.  Not for producers; ``put`` is."""
-        tags = np.asarray(sids).ravel()
+        tags = np.asarray(sids, np.int32).ravel()
         n = min(len(tags), len(rows))
         X = np.asarray(rows[:n], np.float32)
         with self._lock:
             if n:
-                self._append_blocks(tags[:n], X)
+                self._enqueue(tags[:n], X)
                 self._size += n
             self._not_empty.notify_all()
 
@@ -374,20 +362,22 @@ class TaggedBuffer:
         (per-session FIFO order) — the backlog-migration half of
         ``release``: the caller forwards it to the target pod's buffer.
         Also un-parks the sids here.  -> (sids (M,), [rows])."""
-        out_s: list = []
-        out_x: list = []
+        tags = np.asarray(sids).ravel()
         with self._lock:
-            for sid in (int(s) for s in np.asarray(sids).ravel()):
-                self._quiesced.discard(sid)
-                q = self._q.pop(sid, None)
-                if q:
-                    out_s.extend([sid] * len(q))
-                    out_x.extend(self._rows[q.slots()])
-                    self._release(q.slots())
-                    self._size -= len(q)
-            if out_s:
-                self._not_full.notify_all()
-        return np.asarray(out_s, np.int32), out_x
+            self._quiesced.difference_update(int(s) for s in tags)
+            who = self._find(tags)  # a session's first mention, if queued
+            who = who[np.sort(np.unique(who, return_index=True)[1])]
+            who = who[who >= 0]
+            who = who[self._tail[who] > self._head[who]]
+            if not len(who):
+                return np.empty(0, np.int32), []
+            k = self._tail[who] - self._head[who]
+            slots = self._take(who, k, self._tail[who])
+            out_x = list(self._rows[slots])
+            self._release(slots)
+            self._size -= len(slots)
+            self._not_full.notify_all()
+        return self._sid[who].repeat(k), out_x
 
     def _fit(self, X: np.ndarray) -> None:
         """Make the store hold rows shaped as ``X``'s (under the lock)."""
@@ -434,27 +424,161 @@ class TaggedBuffer:
         self._free[self._nfree:self._nfree + k] = slots
         self._nfree += k
 
-    def _append_blocks(self, sids: np.ndarray, X: np.ndarray) -> None:
-        """Queue rows ``X`` as their sessions' next items, session by
-        session; sessions new to the buffer join the round-robin in
-        order of first appearance, as item-by-item appends would have
-        them (under the lock; the caller counts them)."""
-        order = np.argsort(sids, kind="stable")
-        tags = sids[order]
-        starts = np.flatnonzero(np.r_[True, tags[1:] != tags[:-1]])
-        ends = np.r_[starts[1:], len(tags)]
-        slots = self._store(X)[order]
-        first = np.argsort(order[starts])
-        for sid, a, b in zip(tags[starts[first]].tolist(),
-                             starts[first].tolist(), ends[first].tolist()):
-            self._queue(sid).append(slots[a:b])
+    # ---------------------------------------------------------------- queues
+    def _find(self, sids: np.ndarray) -> np.ndarray:
+        """The index of each of ``sids``, -1 where the buffer has not
+        seen the id (under the lock)."""
+        if not len(self._ids):
+            return np.full(len(sids), -1, np.int64)
+        pos = np.searchsorted(self._ids, sids)
+        np.minimum(pos, len(self._ids) - 1, out=pos)
+        return np.where(self._ids[pos] == sids, self._at[pos], -1)
 
-    def _queue(self, sid: int) -> _Fifo:
-        """``sid``'s queue, made (last in the round-robin) if new."""
-        q = self._q.get(sid)
-        if q is None:
-            q = self._q[sid] = _Fifo()
-        return q
+    def _index(self, sids: np.ndarray) -> np.ndarray:
+        """The index of each of ``sids``, new ids given the next ones
+        (under the lock)."""
+        idx = self._find(sids)
+        if (idx >= 0).all():
+            return idx
+        new = np.unique(sids[idx < 0]).astype(np.int32)
+        a, b = self._nsess, self._nsess + len(new)
+        if b > len(self._sid):
+            size = max(2 * len(self._sid), b, 64)
+            for name in ("_sid", "_head", "_tail", "_turn"):
+                old = getattr(self, name)
+                arr = np.zeros(size, old.dtype)
+                arr[:a] = old[:a]
+                setattr(self, name, arr)
+        self._sid[a:b] = new
+        self._head[a:b] = self._tail[a:b] = 0
+        at = np.searchsorted(self._ids, new)
+        self._ids = np.insert(self._ids, at, new)
+        self._at = np.insert(self._at, at, np.arange(a, b))
+        self._nsess = b
+        return self._find(sids)
+
+    def _live(self) -> np.ndarray:
+        """The indices of the sessions with queued items, in round-robin
+        order (under the lock)."""
+        n = self._nsess
+        live = np.flatnonzero(self._tail[:n] > self._head[:n])
+        return live[np.argsort(self._turn[live])]
+
+    def _parked(self) -> np.ndarray:
+        """The indices of the quiesced sessions the buffer has seen
+        (under the lock)."""
+        idx = self._find(np.fromiter(self._quiesced, np.int64,
+                                     len(self._quiesced)))
+        return idx[idx >= 0]
+
+    def _room(self, m: int) -> None:
+        """Make room for ``m`` more log entries (under the lock): the
+        live entries move to the front, in the log's order, into an
+        array of twice what they and the ``m`` need, if the one there is
+        smaller."""
+        if self._hi + m <= len(self._slot):
+            return
+        lo, hi = self._lo, self._hi
+        keep = lo + np.flatnonzero(
+            self._seq[lo:hi] >= self._head[self._sess[lo:hi]])
+        n = len(keep)
+        size = max(2 * (n + m), 1024)
+        for name in ("_slot", "_sess", "_seq"):
+            old = getattr(self, name)
+            arr = np.empty(size, old.dtype) if size > len(old) else old
+            arr[:n] = old[keep]
+            setattr(self, name, arr)
+        self._lo, self._hi = 0, n
+
+    def _enqueue(self, sids: np.ndarray, X: np.ndarray) -> None:
+        """Queue rows ``X`` as their sessions' next items: a fixed number
+        of array steps, however many sessions the batch touches.  The
+        batch goes into the log grouped by session (one stable argsort),
+        an item's sequence number its session's tail plus its rank in
+        its group; sessions whose queue was empty join the round-robin in
+        order of first appearance, as item-by-item appends would have
+        them (under the lock; the caller counts the items)."""
+        n = len(sids)
+        slots = self._store(X)
+        sess = self._index(sids)
+        order = np.argsort(sess, kind="stable")
+        sess = sess[order]
+        starts = np.flatnonzero(np.r_[True, sess[1:] != sess[:-1]])
+        who = sess[starts]
+        k = np.diff(np.r_[starts, n])
+        tail = self._tail[who]
+        joins = tail == self._head[who]
+        if joins.any():
+            first = order[starts[joins]]  # each joiner's first item
+            j = who[joins][np.argsort(first)]
+            self._turn[j] = np.arange(self._next_turn,
+                                      self._next_turn + len(j))
+            self._next_turn += len(j)
+            self._nlive += len(j)
+        self._tail[who] = tail + k
+        self._room(n)
+        lo, hi = self._hi, self._hi + n
+        self._slot[lo:hi] = slots[order]
+        self._sess[lo:hi] = sess
+        self._seq[lo:hi] = (tail - starts).repeat(k) + np.arange(n)
+        self._hi = hi
+
+    def _push(self, s: int, slot: int) -> None:
+        """Queue store slot ``slot`` as session index ``s``'s next item
+        (under the lock; the caller counts it)."""
+        self._room(1)
+        t = int(self._tail[s])
+        if t == self._head[s]:  # joins the round-robin, last
+            self._turn[s] = self._next_turn
+            self._next_turn += 1
+            self._nlive += 1
+        self._slot[self._hi] = slot
+        self._sess[self._hi] = s
+        self._seq[self._hi] = t
+        self._hi += 1
+        self._tail[s] = t + 1
+
+    def _take(self, who: np.ndarray, k: np.ndarray,
+              head: np.ndarray) -> np.ndarray:
+        """Take the first ``k`` queued items of each of the sessions
+        ``who`` off their queues, their heads set to ``head`` -> their
+        store slots, session by session in ``who``'s order, each oldest
+        first (under the lock).  The log is read from its front a step
+        at a time, until every item is found: a session's items lie in
+        the log in sequence order, so the wanted ones are the first live
+        entries of their sessions, and the entries before the first
+        entry still live afterwards are all dead and trimmed off."""
+        n = int(k.sum())
+        want = np.zeros(self._nsess, np.int64)
+        want[who] = k
+        off = np.zeros(self._nsess, np.int64)
+        off[who] = k.cumsum() - k - self._head[who]
+        out = np.empty(n, np.int64)
+        got, i, trim = 0, self._lo, None
+        while got < n and i < self._hi:
+            j = min(i + _SCAN, self._hi)
+            sess, seq = self._sess[i:j], self._seq[i:j]
+            rank = seq - self._head[sess]  # negative: dead
+            w = want[sess]
+            hit = rank.view(np.uint64) < w.view(np.uint64)
+            at = off[sess] + seq
+            m = np.count_nonzero(hit)
+            if m == j - i:
+                out[at] = self._slot[i:j]
+            else:
+                out[at[hit]] = self._slot[i:j][hit]
+                if trim is None:
+                    alive = rank >= w
+                    if alive.any():
+                        trim = i + int(alive.argmax())
+            got += m
+            i = j
+        if got != n:
+            raise RuntimeError("TaggedBuffer: the log lost a queued item")
+        self._lo = i if trim is None else trim
+        self._nlive -= int((head == self._tail[who]).sum())
+        self._head[who] = head
+        return out
 
     # --------------------------------------------------------------- producer
     def _admit_rate(self, sid: int, now: float) -> bool:
@@ -467,13 +591,13 @@ class TaggedBuffer:
             bucket = self._buckets[sid] = TokenBucket(limit, now)
         return bucket.allow(now)
 
-    def _admit_shed(self, sid: int) -> bool:
-        """Watermark-ladder check for one arriving item (under the
-        lock); counts the shed and the rung transition if any."""
+    def _admit_shed(self, sid: int, s: int) -> bool:
+        """Watermark-ladder check for one arriving item of session index
+        ``s`` (under the lock); counts the shed and the rung transition
+        if any."""
         ok, rung = self.shed.decide(
             size=self._size, capacity=self.capacity,
-            depth=len(self._q[sid]) if sid in self._q else 0,
-            n_live=len(self._q))
+            depth=int(self._tail[s] - self._head[s]), n_live=self._nlive)
         if rung != self._rung:
             self._rung = rung
             self._rung_changes += 1
@@ -483,15 +607,49 @@ class TaggedBuffer:
                 self._shed_by_policy.get(rung, 0) + 1
         return ok
 
-    def _admit_items(self, tags: list, X: np.ndarray, lo: int,
+    def _clip_oldest(self) -> None:
+        """Drop the head of the longest queue, the first in round-robin
+        order among equals (under the lock).  Quiesced sessions are
+        mid-migration: clipping them breaks the handoff's zero-drop
+        contract, so they only pay when no one else can."""
+        n = self._nsess
+        depth = self._tail[:n] - self._head[:n]
+        pool = depth > 0
+        if self._quiesced:
+            spared = pool.copy()
+            spared[self._parked()] = False
+            if spared.any():
+                pool = spared
+        longest = np.flatnonzero(pool & (depth == depth[pool].max()))
+        v = int(longest[np.argmin(self._turn[longest])])
+        i = self._lo  # its head entry, read from the log's front a step
+        while i < self._hi:  # at a time: the oldest items lie there
+            j = min(i + _SCAN, self._hi)
+            at = np.flatnonzero((self._sess[i:j] == v)
+                                & (self._seq[i:j] == self._head[v]))
+            if len(at):
+                i += int(at[0])
+                break
+            i = j
+        else:
+            raise RuntimeError("TaggedBuffer: the log lost a queued item")
+        self._release(self._slot[i:i + 1])
+        self._head[v] += 1
+        if self._head[v] == self._tail[v]:
+            self._nlive -= 1
+        self._size -= 1
+        sid = int(self._sid[v])
+        self.drops[sid] = self.drops.get(sid, 0) + 1
+
+    def _admit_items(self, tags: list, sess: list, X: np.ndarray, lo: int,
                      now: float) -> Tuple[int, int, bool]:
         """Per-item admission from item ``lo`` on (under the lock): each
-        item (row ``X[j]``) meets the token bucket, the shed ladder and
-        the capacity in turn and, admitted, takes a slot and its place
-        in its queue at once; the rows are copied in one step on the
-        way out.  -> (the item it stopped at, items not admitted,
-        whether that item passed its checks and waits for room under
-        ``block``)."""
+        item (row ``X[j]``, session index ``sess[j]``) meets the token
+        bucket, the shed ladder and the capacity in turn and, admitted,
+        takes a slot and its place in its queue at once; the rows are
+        copied in one step on the way out.  -> (the item it stopped at,
+        items not admitted, whether that item passed its checks and
+        waits for room under ``block``)."""
         dropped = 0
         self._fit(X)
         # slot -> item; a slot clipped and taken again holds its last item
@@ -503,7 +661,8 @@ class TaggedBuffer:
                     self.throttled[sid] = self.throttled.get(sid, 0) + 1
                     dropped += 1
                     continue
-                if self.shed is not None and not self._admit_shed(sid):
+                if self.shed is not None and \
+                        not self._admit_shed(sid, sess[j]):
                     dropped += 1
                     continue
                 if self._size >= self.capacity:
@@ -513,26 +672,14 @@ class TaggedBuffer:
                         self.drops[sid] = self.drops.get(sid, 0) + 1
                         dropped += 1
                         continue
-                    # drop-oldest: clip the longest queue's head; quiesced
-                    # sessions are mid-migration: clipping them breaks the
-                    # handoff's zero-drop contract, so they only pay when
-                    # no one else can
-                    pool = [s for s in self._q if s not in
-                            self._quiesced] or list(self._q)
-                    victim = max(pool, key=lambda s: len(self._q[s]))
-                    self._release(self._q[victim].take(1))
-                    if not self._q[victim]:
-                        del self._q[victim]
-                    self._size -= 1
-                    self.drops[victim] = self.drops.get(victim, 0) + 1
+                    self._clip_oldest()
                     dropped += 1
                 if not self._nfree:
                     self._grow(1)
                 self._nfree -= 1
                 slot = int(self._free[self._nfree])
                 held[slot] = j
-                q = self._q.get(sid)
-                (self._queue(sid) if q is None else q).push(slot)
+                self._push(sess[j], slot)
                 self._size += 1
                 self._admitted += 1
             return len(tags), dropped, False
@@ -544,14 +691,14 @@ class TaggedBuffer:
     def _admit_prefix(self, sids: np.ndarray, X: np.ndarray,
                       lo: int) -> Tuple[int, int, bool]:
         """Block admission from item ``lo`` on (under the lock): the
-        prefix that fits joins the queues session by session; the rest
+        prefix that fits joins the queues in one step; the rest
         waits for room under ``block`` or is clipped under
         ``drop-newest``.  -> as ``_admit_items``."""
         n = len(sids)
         room = self.capacity - self._size
         if room > 0:
             hi = min(n, lo + room)
-            self._append_blocks(sids[lo:hi], X[lo:hi])
+            self._enqueue(sids[lo:hi], X[lo:hi])
             self._size += hi - lo
             self._admitted += hi - lo
             self._block_items += hi - lo
@@ -577,7 +724,7 @@ class TaggedBuffer:
         the drop policies never wait.  Raises ``ValueError`` after
         ``close()``.  Without a rate limit or a shed ladder, and under
         ``block`` or ``drop-newest``, admission is a prefix decision
-        and the batch is queued session by session (module docstring).
+        and the batch is queued in one step (module docstring).
         """
         sids = np.asarray(sids, np.int32).ravel()
         X = np.asarray(X, np.float32)
@@ -594,14 +741,16 @@ class TaggedBuffer:
                         or self.rate_limit is not None
                         or any(v is not None
                                for v in self._rate_overrides.values()))
-            tags = sids.tolist() if per_item else None
+            if per_item:
+                tags, sess = sids.tolist(), self._index(sids).tolist()
             admitted = self._admitted
             lo = 0
             while lo < n:
                 if self._closed:
                     raise ValueError("put() on a closed TaggedBuffer")
                 if per_item:
-                    lo, lost, full = self._admit_items(tags, X, lo, now)
+                    lo, lost, full = self._admit_items(tags, sess, X, lo,
+                                                       now)
                 else:
                     lo, lost, full = self._admit_prefix(sids, X, lo)
                 dropped += lost
@@ -620,7 +769,7 @@ class TaggedBuffer:
                 if self._closed:
                     raise ValueError("put() on a closed TaggedBuffer")
                 if per_item:  # the stalled item passed its checks
-                    self._append_blocks(sids[lo:lo + 1], X[lo:lo + 1])
+                    self._enqueue(sids[lo:lo + 1], X[lo:lo + 1])
                     self._size += 1
                     self._admitted += 1
                     lo += 1
@@ -644,7 +793,16 @@ class TaggedBuffer:
         sessions' shares, or ``None`` once the buffer is closed and
         drained.  The consumer step under ``get`` (which see for
         ``timeout``, ``min_items`` and ``per_session``); a caller that
-        copies the rows itself releases the lease when done."""
+        copies the rows itself releases the lease when done.
+
+        The shares come from ``_round_robin`` over the depths (tail less
+        head) of the drainable sessions in round-robin order.  Then one
+        pass over the front of the log, with no sort: an entry is taken
+        when its rank in its queue (sequence number less head) is below
+        its session's share, and lands in the lease at its session's
+        offset plus that rank.  The pass stops once every share is
+        found, and the dead entries it leaves at the log's front are
+        trimmed off (``_take``)."""
         need = max(1, min(min_items, max_items))
         waiting = self._lock_wait("get")
         with self._lock:
@@ -663,25 +821,19 @@ class TaggedBuffer:
                         f"TaggedBuffer below {need} items for {timeout}s")
             if self._avail() == 0:  # closed and drained (of drainables)
                 return None
-            live = [(sid, q) for sid, q in self._q.items()
-                    if sid not in self._quiesced]
-            who, ks, parts = [], [], []  # each session's share, in order
-            depth = [len(q) for _, q in live]
+            live = self._live()
+            if self._quiesced:
+                live = live[~np.isin(live, self._parked())]
+            depth = self._tail[live] - self._head[live]
             if per_session is not None:
-                depth = [min(c, per_session) for c in depth]
-            for (sid, q), k in zip(live, _round_robin(depth, max_items)):
-                if k:
-                    who.append(sid)
-                    ks.append(k)
-                    parts.append(q.take(k))
-                    if not q:
-                        del self._q[sid]
-            at = np.concatenate(parts)  # their slots, session by session
+                np.minimum(depth, per_session, out=depth)
+            k = _round_robin(depth, max_items)
+            who, k = live[k > 0], k[k > 0]  # each session's share, in order
+            at = self._take(who, k, self._head[who] + k)
             # a put that grows the store leaves these rows in this array
-            lease = Lease(self, np.array(who, np.int32),
-                          np.array(ks, np.int64), at, self._rows)
+            lease = Lease(self, self._sid[who], k, at, self._rows)
             self._size -= len(at)
-            self._get_blocks += len(parts)
+            self._get_blocks += len(who)
             self._not_full.notify_all()
         # as unsigned, a negative slot is out of range too
         if at.view(np.uint64).max() >= len(lease.store):

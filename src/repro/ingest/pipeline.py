@@ -312,7 +312,7 @@ class IngestPipeline:
         reused chunk array held and had zeroed), the items producers put
         and the seconds they waited in ``put`` since the last run:
         ``buffer_put_items``, ``buffer_put_wait_s``; of those items, the
-        ones admitted session by session, ``buffer_put_block_items``;
+        ones admitted as a prefix, ``buffer_put_block_items``;
         the sessions' shares taken, ``buffer_get_blocks``) split into
         the stages ``ingest_slot_table``, ``ingest_get`` (the lease,
         with the buffer's ``buffer_get_wait_*`` inside it),

@@ -548,23 +548,25 @@ class _ItemBuffer:
         return dropped
 
     def get(self, max_items, *, pad_to=None, d=None, min_items=1,
-            timeout=None):
+            timeout=None, per_session=None):
         need = max(1, min(min_items, max_items))
         if not (self._avail() >= need or self._closed):
             raise TimeoutError("buffer below min_items")
         if self._avail() == 0:
             return None
         out_s, out_x = [], []
+        gave = collections.Counter()  # a session's turns this batch
         while len(out_s) < max_items and self._q:
             took = 0
             for sid in list(self._q):
                 if len(out_s) >= max_items:
                     break
-                if sid in self._quiesced:
+                if sid in self._quiesced or gave[sid] == per_session:
                     continue
                 dq = self._q[sid]
                 out_s.append(sid)
                 out_x.append(dq.popleft())
+                gave[sid] += 1
                 took += 1
                 if not dq:
                     del self._q[sid]
@@ -600,10 +602,30 @@ def _same_batch(got, want):
     assert gs.tobytes() == ws.tobytes() and gx.tobytes() == wx.tobytes()
 
 
+def _queued(buf):
+    """Each session's queued items read from the buffer's log: sid -> the
+    log positions of its live entries, oldest first.  A session's live
+    entries hold the sequence numbers from its head to its tail, in that
+    order."""
+    lo, hi = buf._lo, buf._hi
+    sess, seq = buf._sess[lo:hi], buf._seq[lo:hi]
+    live = seq >= buf._head[sess]
+    out = {}
+    for s in np.unique(sess[live]).tolist():
+        at = lo + np.flatnonzero(live & (sess == s))
+        np.testing.assert_array_equal(
+            buf._seq[at], np.arange(buf._head[s], buf._tail[s]))
+        out[int(buf._sid[s])] = at
+    return out
+
+
 def _same_state(buf, ref):
+    queued = _queued(buf)
+    assert set(queued) == set(buf.depths()) and buf._nlive == len(queued)
     # every slot of the store is held by one queued item or free, once
     if buf._rows is not None:
-        held = [buf._free[:buf._nfree]] + [q.slots() for q in buf._q.values()]
+        held = [buf._free[:buf._nfree]] + [buf._slot[at]
+                                           for at in queued.values()]
         np.testing.assert_array_equal(np.sort(np.concatenate(held)),
                                       np.arange(len(buf._rows)))
     # the dicts in order: the order is the round-robin's
@@ -701,6 +723,132 @@ def test_buffer_blocks_bit_equal_to_item_reference(admission, seed):
         _same_state(buf, ref)
         if want is None:
             break
+
+
+def _sparse_ids(rng, n):
+    """``n`` distinct session ids that are not 0..n-1: spread over int32,
+    unsorted, and a few of them negative (none the padding id)."""
+    ids = np.unique(rng.randint(0, 2**31 - 1, 2 * n)).astype(np.int32)
+    ids = ids[rng.permutation(len(ids))[:n]]
+    ids[:16] = -np.arange(2, 18)
+    return ids
+
+
+MANY = {  # case -> (policy, capacity, the gets' per_session caps, handoffs)
+    "rejoin": ("block", 1 << 15, [None], False),
+    "capped": ("block", 1 << 15, [1, 3, 7, None], False),
+    "handoff": ("block", 1 << 15, [None, 4], True),
+    "drop-oldest": ("drop-oldest", 4500, [None, 5], False),
+    "drop-newest": ("drop-newest", 4500, [None, 5], False),
+    "drop-oldest-handoff": ("drop-oldest", 4500, [None], True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MANY))
+def test_buffer_many_sessions_bit_equal_to_item_reference(case):
+    """At 4096 sessions with 4096-item puts, over ids that are not
+    0..S-1 (large, unsorted, some negative), the queues give the
+    item-at-a-time reference's batches, depths (in round-robin order)
+    and ledgers bit for bit: queues that empty and join the round-robin
+    again, ``per_session`` caps, quiesce, release and extract -> inject
+    mid-stream, and overflow under either drop policy."""
+    policy, capacity, caps, handoff = MANY[case]
+    rng = np.random.RandomState(sorted(MANY).index(case))
+    S = n = 4096
+    d = 2
+    ids = _sparse_ids(rng, S)
+    hot = rng.choice(ids, 64, replace=False)
+    bufs = [TaggedBuffer(capacity, policy), _ItemBuffer(capacity, policy)]
+    buf, ref = bufs
+    parked = np.empty(0, np.int32)
+    for step in range(12 if handoff else 8):
+        # every session, a quarter of the items on 64 hot ones
+        s = np.where(rng.rand(n) < 0.25, rng.choice(hot, n),
+                     rng.choice(ids, n)).astype(np.int32)
+        X = rng.randn(n, d).astype(np.float32)
+        X[:, 0] = step * 10_000 + np.arange(n)  # a fingerprint
+        got, want = (_outcome(b.put, s, X, timeout=0.0) for b in bufs)
+        assert got == want
+        _same_state(buf, ref)
+        if handoff and step % 3 == 0:
+            parked = np.unique(rng.choice(s, 48))
+            for b in bufs:
+                b.quiesce(parked)
+        elif handoff and step % 3 == 2:  # release half, move the rest
+            for b in bufs:
+                b.release(parked[::2])
+            (gs, gx), (ws, wx) = (b.extract(parked[1::2]) for b in bufs)
+            assert gs.tobytes() == ws.tobytes() and len(gx) == len(wx) > 0
+            assert all(g.tobytes() == w.tobytes() for g, w in zip(gx, wx))
+            buf.inject(gs, gx)
+            ref.inject(ws, wx)
+            _same_state(buf, ref)
+        if policy == "block":
+            m = int(rng.choice([n // 2, n, 2 * n]))
+        else:  # a few hundred items over capacity a put
+            m = n - int(rng.randint(0, 400))
+        cap = caps[step % len(caps)]
+        _same_batch(*(_outcome(b.get, m, pad_to=m, per_session=cap,
+                               timeout=0.0) for b in bufs))
+        _same_state(buf, ref)
+    assert buf._next_turn > len(np.unique(buf._sid[:buf._nsess]))  # rejoins
+    if policy != "block":
+        assert sum(ref.drops.values()) > 0
+    for b in bufs:
+        b.release(parked)
+        b.close()
+    while True:
+        got, want = (b.get(3000, pad_to=3000) for b in bufs)
+        _same_batch(got, want)
+        _same_state(buf, ref)
+        if want is None:
+            break
+
+
+ENGAGE = {  # name -> (policy, constructor keywords, whole puts by array)
+    "block": ("block", {}, True),
+    "rate-limit": ("block", {"rate_limit": RateLimit(rate=1e9)}, False),
+    "shed": ("block", {"shed": ShedPolicy(lo=0.9, hi=0.95)}, False),
+    "drop-oldest": ("drop-oldest", {}, False),
+}
+
+
+@pytest.mark.parametrize("admission", sorted(ENGAGE))
+def test_buffer_put_over_many_sessions_counts_the_array_path(admission):
+    """A block put over 4096 distinct sessions is queued by array steps
+    and counts every item in ``put_block_items``; a rate limit, a shed
+    ladder or ``drop-oldest`` decides item by item and counts none
+    there.  Either way each session gives one item, in order of first
+    appearance."""
+    policy, kw, whole = ENGAGE[admission]
+    rng = np.random.RandomState(0)
+    ids = _sparse_ids(rng, 4096)
+    buf = TaggedBuffer(capacity=8192, policy=policy, **kw)
+    buf.put(ids, rng.randn(4096, 3).astype(np.float32))
+    assert buf.admitted() == 4096
+    assert buf.block_counts() == {"put_block_items": 4096 if whole else 0,
+                                  "get_blocks": 0}
+    sids, _ = buf.get(8192)
+    np.testing.assert_array_equal(sids, ids)
+    assert buf.block_counts()["get_blocks"] == 4096
+
+
+def test_buffer_queue_memory_follows_capacity_and_sessions():
+    """A hot session's backlog, capped per batch, leaves dead entries
+    between live ones in the log; the log still stays within twice the
+    items queued plus a put, and the per-session arrays within the
+    sessions seen, however many items pass through."""
+    buf = TaggedBuffer(capacity=8192, policy="drop-newest")
+    rng = np.random.RandomState(0)
+    ids = _sparse_ids(rng, 300)
+    for _ in range(200):
+        s = np.where(rng.rand(4096) < 0.5, ids[0],
+                     rng.choice(ids, 4096)).astype(np.int32)
+        buf.put(s, np.zeros((4096, 2), np.float32))
+        buf.get(4096, per_session=32)
+        assert buf._hi - buf._lo <= len(buf._slot) <= 2 * (8192 + 4096)
+    assert buf._nsess == 300 and len(buf._sid) <= 2 * 300
+    assert buf.total_drops() > 0 and buf.depths()[int(ids[0])] > 4096
 
 
 def _drain_concurrent_producers(take):
@@ -804,7 +952,7 @@ def _round_robin_puts(buf, puts, sliced=False, sessions=256, n=4096, d=2):
 
 @pytest.mark.parametrize("sliced", [False, True])
 def test_buffer_block_path_counts_blocks_not_items(sliced):
-    """4096-item puts under ``block`` are queued session by session, and
+    """4096-item puts under ``block`` are queued by array steps, and
     a batch of 131072 takes one share of slots per session, whether each
     put is a fresh array or a row slice of one array."""
     buf = TaggedBuffer(capacity=2 * 131072, policy="block")
@@ -853,8 +1001,7 @@ def test_buffer_get_refuses_a_slot_outside_the_store():
     raise, where the gather would otherwise clamp it to the last row."""
     buf = TaggedBuffer(capacity=64, policy="block")
     buf.put(np.int32([5, 5, 6]), np.ones((3, 2), np.float32))
-    q = buf._q[5]
-    q.idx[q.head] = len(buf._rows) + 3
+    buf._slot[_queued(buf)[5][0]] = len(buf._rows) + 3
     with pytest.raises(RuntimeError, match="outside the store"):
         buf.get(3)
 
